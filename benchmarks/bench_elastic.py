@@ -1,9 +1,12 @@
 """Elastic pod-loss drill accounting — the BENCH_PR4 rows.
 
-Runs the `launch.train` shrink/re-grow drill in a SUBPROCESS (the drill
-mesh needs `--xla_force_host_platform_device_count` host devices, which
-must be set before jax imports; the bench process itself stays at one
-device) and reports what the elastic transition cost:
+Runs the `launch.train` shrink/re-grow drill in a SUBPROCESS on 8 HOST
+(CPU) devices (`JAX_PLATFORMS=cpu` plus
+`--xla_force_host_platform_device_count`, set before jax imports): the
+bench process itself holds the accelerator, if there is one, and a child
+must not reach for it.  Every row is labelled ``devices=8xcpu-host`` — a
+host-device drill, not a chip measurement.  It reports what the elastic
+transition cost:
 
     elastic/shrink_reshard_wall   us to restore + re-place the state onto
                                   the survivor mesh (bytes moved derived)
@@ -29,6 +32,7 @@ def _drill_report() -> dict:
     root = Path(__file__).resolve().parents[1]
     env = dict(os.environ)
     env["PYTHONPATH"] = str(root / "src")
+    env["JAX_PLATFORMS"] = "cpu"
     env["XLA_FLAGS"] = (
         f"--xla_force_host_platform_device_count={2 * 2 * 2}")
     with tempfile.TemporaryDirectory() as d:
@@ -73,4 +77,5 @@ def run():
          f"params_bitwise_equal={parity['params_bitwise_equal']} "
          f"window={parity['window']}"),
     ]
-    return lines
+    return [(name, us, f"{derived} devices=8xcpu-host")
+            for name, us, derived in lines]

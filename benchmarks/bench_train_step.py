@@ -23,12 +23,13 @@ def run():
     import jax.numpy as jnp
     from repro.configs.base import ShapeConfig, smoke_config
     from repro.data.pipeline import DataConfig, synthetic_batch
+    from repro.dist.sharding import make_mesh
     from repro.train.optimizer import AdamWConfig
     from repro.train.step import StepOptions, build_train_step, init_state
     from repro.ckpt.diskless import DisklessCheckpoint
 
     lines = []
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     shape = ShapeConfig("b", 128, 8, "train")
     for arch in ("qwen2-0.5b", "qwen3-moe-30b-a3b", "xlstm-350m"):
         cfg = smoke_config(arch)

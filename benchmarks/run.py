@@ -35,6 +35,8 @@ def main(argv=None) -> None:
                         help="also write rows as JSON {name: {us, derived}}")
     args = parser.parse_args(argv)
 
+    from repro.launch import compile_cache
+    compile_cache.enable()
     from benchmarks import (bench_chaos, bench_elastic, bench_kernels,
                             bench_overhead, bench_serving,
                             bench_strong_scaling, bench_traffic,

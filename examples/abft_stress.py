@@ -21,6 +21,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.core as core
+from repro.dist.sharding import make_mesh
 
 
 def residual_check(C, A, B, x):
@@ -41,7 +42,7 @@ def main():
     g, nb = args.grid, args.block
     pr = g - 1
     n = pr * nb
-    mesh = jax.make_mesh((g, g), ("rows", "cols"))
+    mesh = make_mesh((g, g), ("rows", "cols"))
     spec = core.make_spec(1, pr, pr)
     rs = np.random.RandomState(0)
     failures = 0

@@ -16,6 +16,7 @@ import jax.numpy as jnp
 import numpy as np
 
 import repro.core as core
+from repro.dist.sharding import make_mesh
 
 
 def main():
@@ -31,7 +32,7 @@ def main():
     print(f"encoded A: {A.shape} -> {a_enc.shape} (checksum block-rows)")
 
     # --- 2. distributed ABFT SUMMA ------------------------------------------
-    mesh = jax.make_mesh((4, 4), ("rows", "cols"))
+    mesh = make_mesh((4, 4), ("rows", "cols"))
     c_enc = core.abft_summa(a_enc, b_enc, mesh, spec=spec)
     err = float(jnp.max(jnp.abs(core.strip(c_enc, 32, 32) - A @ B)))
     print(f"SUMMA (no failure): max|C - AB| = {err:.2e}")

@@ -3,6 +3,4 @@
 Reproduction + extension of Bosilca, Delmas, Dongarra, Langou (2008),
 "Algorithmic Based Fault Tolerance Applied to High Performance Computing".
 """
-from repro import compat  # noqa: F401  (jax version shims, must run first)
-
 __version__ = "1.0.0"

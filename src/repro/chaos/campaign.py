@@ -28,9 +28,9 @@ records which recovery rung fired (`abft_inflight`, `diskless`,
 Multi-pod faults need a ``(pod, data, model)`` mesh (8 host devices for
 the default 2x2x2); with fewer devices those specs are reported as
 ``skipped`` — visible in the artifact, never silently dropped.  The
-train-side SDC drill runs on a single-device mesh because the pinned XLA
-cannot lower the deferred-reduction family multi-device (see ROADMAP
-"jax uprev"); the serve-side SDC drill is mesh-sharded.
+train-side SDC drill runs on a single-device mesh because the deferred-
+reduction family is kept single-device (see `train.step.StepOptions`;
+ROADMAP Queue 3, item 2); the serve-side SDC drill is mesh-sharded.
 """
 from __future__ import annotations
 
@@ -45,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from repro import obs
+from repro.dist import sharding as shd
 from repro.chaos.faults import (Episode, FailureInjector, FaultSpace,
                                 FaultSpec, SDCInjector, SDCPlan,
                                 ensure_registered, flip_bit, get_surface)
@@ -412,8 +413,8 @@ class CampaignRunner:
 
         Topology/erasure faults run on the multi-pod mesh; SDC and DRAM
         faults run single-device under the fully protected step (deferred
-        reduction + abft_reduce="correct"), which the pinned XLA cannot
-        lower multi-device — see the module docstring.
+        reduction + abft_reduce="correct"), which is kept single-device —
+        see the module docstring.
         """
         if spec.kind in ("pod_loss", "slow_pod", "shard_loss"):
             need = math.prod(self.train.pod_mesh)
@@ -435,9 +436,8 @@ class CampaignRunner:
         return StepOptions(remat=False)
 
     def _make_mesh(self, shape, names):
-        n = math.prod(shape)
-        devs = np.array(jax.devices()[:n]).reshape(shape)
-        return jax.sharding.Mesh(devs, names)
+        return shd.make_mesh(shape, names,
+                             devices=jax.devices()[:math.prod(shape)])
 
     def _train_runtime(self, mesh_shape, names, tag, *, policy=None,
                        injector=None, with_disk=False):
@@ -960,6 +960,7 @@ class CampaignRunner:
         V-column checksum riding the accumulator and the MXU-path rowsum
         duplicate — must flag the q-tile, and the detect-and-recompute
         path must patch it back to the clean output."""
+        from repro.kernels import ops
         from repro.kernels.flash_attention import (flash_attention_checked,
                                                    flash_attention_pallas)
 
@@ -972,14 +973,15 @@ class CampaignRunner:
                    for _ in range(3))
         scale = 1.0 / math.sqrt(d)
         target = "l" if spec.variant == "l" else "acc"
+        interpret = not ops.on_tpu()
         t0 = time.perf_counter()
         clean = flash_attention_pallas(q, k, v, scale=scale, causal=True,
-                                       bq=bq, bk=bk, interpret=True)
+                                       bq=bq, bk=bk, interpret=interpret)
         clean_wall = time.perf_counter() - t0
         t0 = time.perf_counter()
         o, rep = flash_attention_checked(
-            q, k, v, scale=scale, causal=True, bq=bq, bk=bk, interpret=True,
-            inject=(1, spec.step, spec.delta, target))
+            q, k, v, scale=scale, causal=True, bq=bq, bk=bk,
+            interpret=interpret, inject=(1, spec.step, spec.delta, target))
         drill_wall = time.perf_counter() - t0
         end_state, diff = _compare_trees(_host(o), _host(clean),
                                          self.train.tol)
@@ -1760,9 +1762,9 @@ class CampaignRunner:
         needs_pod = "pod_loss" in kinds
         if needs_sdc and needs_pod:
             raise _Skip(
-                "pinned XLA cannot lower the protected step on the pod "
-                "mesh (ROADMAP 'jax uprev'): sdc_collective and pod_loss "
-                "cannot share one train episode")
+                "the protected step is kept single-device, so "
+                "sdc_collective and pod_loss cannot share one train "
+                "episode on the pod mesh")
         if needs_pod:
             need = math.prod(self.train.pod_mesh)
             if len(jax.devices()) < need:

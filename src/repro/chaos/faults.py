@@ -247,12 +247,13 @@ _KIND_INFO = {
 }
 
 # The kinds a Poisson-rate schedule may draw, per workload.  Constraint
-# (train): rate episodes thread ONE live runtime, and the pinned XLA can
-# only lower the protected step (defer_grad_reduce + abft_reduce — needed
-# for sdc_collective) single-device, while pod-topology kinds need the
-# 8-device pod mesh — so train rate schedules draw from the single-device
-# compatible set and topology kinds drill at rate in the solver family,
-# which simulates its pod fleet host-side (see ROADMAP "jax uprev").
+# (train): rate episodes thread ONE live runtime, and the protected step
+# (defer_grad_reduce + abft_reduce — needed for sdc_collective) is kept
+# single-device (a partitioner limit of an earlier toolchain, not yet
+# re-tested on jax 0.9: ROADMAP Queue 3, item 2), while pod-topology
+# kinds need the 8-device pod mesh — so train rate schedules draw from
+# the single-device compatible set and topology kinds drill at rate in the
+# solver family, which simulates its pod fleet host-side.
 RATE_KINDS = {
     "train": ("sdc_collective", "dram_params", "dram_opt_state",
               "shard_loss"),
@@ -776,11 +777,11 @@ def flip_bit(x, flat_index: int, bit: int = 30):
 
 def scatter_delta(extent: int, shard, delta) -> jax.Array:
     """``[extent]`` fp32 vector carrying `delta` at index `shard`, zero
-    elsewhere — the caller-side shard selection for drills into manual
-    regions where `lax.axis_index` cannot lower (pinned jax 0.4.37
-    rejects PartitionId in partial-manual shard_map; see ROADMAP "jax
-    uprev").  `shard`/`delta` may be traced scalars, so one compiled
-    drill program serves every planned event."""
+    elsewhere — the caller-side shard selection for drills into partial-
+    manual regions, kept because an earlier toolchain would not lower
+    `lax.axis_index` there (not yet re-tested on jax 0.9: ROADMAP Queue 3,
+    item 2).  `shard`/`delta` may be traced scalars, so one compiled drill
+    program serves every planned event."""
     return jnp.zeros((extent,), jnp.float32).at[shard].add(
         jnp.asarray(delta, jnp.float32))
 

@@ -61,15 +61,15 @@ def survivor_mesh(failed_pods: int = 1, total_pods: int = 2, mesh=None):
         rest_axes = tuple(a for a in mesh.axis_names if a != "pod")
         rest_shape = tuple(mesh.shape[a] for a in rest_axes)
         if remaining == 1:
-            return jax.make_mesh(rest_shape, rest_axes)
-        return jax.make_mesh((remaining,) + rest_shape, ("pod",) + rest_axes)
+            return shd.make_mesh(rest_shape, rest_axes)
+        return shd.make_mesh((remaining,) + rest_shape, ("pod",) + rest_axes)
     from repro.launch.mesh import make_production_mesh
     remaining = total_pods - failed_pods
     if remaining <= 0:
         raise ValueError("no survivors")
     if remaining == 1:
         return make_production_mesh(multi_pod=False)
-    return jax.make_mesh((remaining, 16, 16), ("pod", "data", "model"))
+    return shd.make_mesh((remaining, 16, 16), ("pod", "data", "model"))
 
 
 # ---------------------------------------------------------------------------
@@ -201,10 +201,10 @@ def reshard_state(state, new_mesh, opts: Optional[StepOptions] = None,
     Used by the planned re-grow (the pod "returns": nothing was lost, no
     rollback — the survivor state simply spreads back over the full mesh)
     and by the rung-3a shrink whose state survived disklessly.  Goes
-    device -> host -> device deliberately: a cross-mesh `device_put` of a
-    sharded array is not portable on the pinned jax, and the host hop is
-    the honest cost a real pod-to-pod transfer pays anyway (it is what the
-    reshard wall-clock in BENCH_PR4.json measures)."""
+    device -> host -> device deliberately: the host hop works between any
+    two meshes, and it is the honest cost a real pod-to-pod transfer pays
+    anyway (it is what the reshard wall-clock in BENCH_PR4.json
+    measures)."""
     opts = opts or StepOptions()
     state_like = jax.eval_shape(lambda: state)
     specs = state_specs(state_like, new_mesh, opts, cfg)

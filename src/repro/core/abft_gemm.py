@@ -40,6 +40,7 @@ from typing import Optional, Tuple
 import jax
 import jax.numpy as jnp
 
+from repro import obs
 from repro.core.checksum import checkpoint_matrix
 
 __all__ = ["ABFTConfig", "encode_weight", "abft_matmul", "verify_output",
@@ -133,6 +134,11 @@ def _fused_forward(x: jax.Array, w_enc: jax.Array, cfg: ABFTConfig):
     plan = ktune.best_plan(m, k, n_enc, in_dtype=x.dtype,
                            out_dtype=jnp.float32, f=cfg.f)
     if plan is None or (not force and plan.waste > 0.25):
+        # padding would cost more than it saves (e.g. decode: m = slots):
+        # the XLA reference runs — counted, so a run can see it
+        obs.counter("repro_kernel_traces_total",
+                    "kernel dispatcher traces (≈ compiles)").inc(
+            op="abft_matmul", backend="ref")
         return None
     wr = _weights(n, cfg.f, cfg.seed, jnp.float32)             # [n, f]
     wn_res = jnp.concatenate(

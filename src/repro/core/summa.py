@@ -108,8 +108,9 @@ def encode_operands(a: jax.Array, b: jax.Array, spec: EncodingSpec):
 def _solve_static(a: jax.Array, b: jax.Array) -> jax.Array:
     """Solve a @ x = b for a tiny static-k system in pure jnp.
 
-    Runs inside shard_map, where jnp.linalg.solve's custom-call lowering is
-    unavailable on older jax.  k is the number of simultaneously failed
+    Runs inside shard_map, where an earlier toolchain could not lower
+    jnp.linalg.solve's custom call (not yet re-tested on jax 0.9:
+    ROADMAP Queue 3, item 2).  k is the number of simultaneously failed
     lines (<= f, i.e. 1-2 in practice); closed forms for k<=2, unrolled
     Gauss-Jordan with partial pivoting beyond.
     """
@@ -196,11 +197,12 @@ def _local_summa(
             ).astype(c_blk.dtype)
         return (a_blk, b_blk, c_blk, state)
 
-    c_blk = lax.pvary(jnp.zeros((mb, nb), dtype=jnp.float32), (row_axis, col_axis))
+    c_blk = lax.pcast(jnp.zeros((mb, nb), dtype=jnp.float32),
+                      (row_axis, col_axis), to="varying")
     state = ()
     if fused:
         state = jax.tree.map(
-            lambda x: lax.pvary(x, (row_axis, col_axis)),
+            lambda x: lax.pcast(x, (row_axis, col_axis), to="varying"),
             kops.acc_state_zeros(fused_plan))
     carry = (a_blk, b_blk, c_blk, state)
 
@@ -417,10 +419,12 @@ def abft_summa(
         mesh=mesh,
         in_specs=(P(row_axis, col_axis), P(row_axis, col_axis)),
         out_specs=P(row_axis, col_axis),
-        # pallas_call has no replication/VMA rule on this jax
+        # VMA checking off around the fused Pallas update (pallas_call had
+        # no VMA rule on an earlier toolchain; ROADMAP Queue 3, item 2)
         check_vma=fused_plan is None,
     )
-    return fn(a_enc, b_enc)
+    # jit: a shard_map called outside jit runs its body op by op
+    return jax.jit(fn)(a_enc, b_enc)
 
 
 def summa(
@@ -456,4 +460,4 @@ def summa(
         out_specs=P(row_axis, col_axis),
         check_vma=fused_plan is None,
     )
-    return fn(a, b)
+    return jax.jit(fn)(a, b)

@@ -8,12 +8,12 @@ all-reduce and a Huang-Abraham checksum-verified psum (`abft_psum`), which
 `train.step` threads through the gradient reduction and `serve.engine`
 through the decode path's logits reduction.
 
-Pinned-toolchain note (jax 0.4.37, see ROADMAP "jax uprev"): inside
-PARTIAL-manual shard_map regions the XLA SPMD partitioner rejects
-scan-over-stacked-params, the gather-family collectives, and
-`lax.axis_index` — everything in this package therefore lowers to plain
-psum in such regions (or is opt-in where it cannot, e.g.
-``ef_psum_tree(wire="int8")``).
+Inside PARTIAL-manual shard_map regions everything in this package lowers
+to plain psum (or is opt-in where it cannot, e.g.
+``ef_psum_tree(wire="int8")``): an earlier toolchain's SPMD partitioner
+rejected scan-over-stacked-params, the gather-family collectives and
+`lax.axis_index` there, and these limits are not yet re-tested on jax 0.9
+(ROADMAP Queue 3, item 2).
 """
 from repro.dist import collectives, sharding  # noqa: F401
 from repro.dist.collectives import abft_psum, abft_psum_tree, ef_psum_tree

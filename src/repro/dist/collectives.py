@@ -83,10 +83,10 @@ def ef_psum_tree(grads, residual, dp_axes, ndp: int, *, wire: str = "psum"):
       * "psum" (default) — the dequantized values ride a plain psum.  The
         gradient still passes through the int8 bottleneck (EF semantics,
         convergence behavior, residual dynamics all identical) but the
-        bytes on the wire stay f32.  This is the only realization that
-        lowers inside a PARTIAL-manual shard_map (auto model axis) on the
-        pinned jax/XLA, whose SPMD partitioner hard-crashes on
-        all_gather/all_to_all in manual-subgroup regions.
+        bytes on the wire stay f32.  It lowers inside a PARTIAL-manual
+        shard_map (auto model axis), where an earlier toolchain's SPMD
+        partitioner crashed on all_gather/all_to_all (not yet re-tested
+        on jax 0.9: ROADMAP Queue 3, item 2).
       * "int8" — true compressed exchange: an all_to_all hands every
         device its 1/ndp segment of all shards' int8 payloads
         (reduce-scatter shape), the segment is dequantized + averaged
@@ -179,17 +179,14 @@ def abft_psum(x, axes, *, f: int = 2, mode: str = "correct",
     components may be traced scalars, so one compiled drill program serves
     every planned (shard, delta).  ``inject_local`` is the same drill with
     the shard selection done by the CALLER: a per-shard additive delta
-    (0.0 on unaffected shards), for regions where `lax.axis_index` cannot
-    lower — on the pinned jax 0.4.37 it becomes a PartitionId instruction
-    the SPMD partitioner rejects inside partial-manual shard_map regions,
-    so serve.engine pre-scatters the delta into a model-axis-sharded
-    vector and passes this shard's slice here.
+    (0.0 on unaffected shards): serve.engine pre-scatters the delta into a
+    model-axis-sharded vector and passes this shard's slice here, so the
+    partial-manual region needs no `lax.axis_index` (see
+    `chaos.faults.scatter_delta`).
 
     Runs inside any manual-collective region over `axes` — fully-manual or
-    partial-manual shard_map, or vmap(axis_name=...) in tests.  Pinned-jax
-    caveat (jax 0.4.37): safe in PARTIAL-manual regions because it lowers
-    to a single psum — unlike the gather-family collectives, which abort in
-    the pinned XLA's SPMD partitioner there (see ROADMAP "jax uprev").
+    partial-manual shard_map, or vmap(axis_name=...) in tests; it lowers
+    to a single psum.
 
     Returns ``(y, ok)`` where y = psum(x) (repaired when possible) and ok
     is a scalar bool (True = checksums consistent, no fault seen).  With
@@ -234,12 +231,17 @@ def abft_psum(x, axes, *, f: int = 2, mode: str = "correct",
     checks = [v2.sum(axis=1)]                       # row sums [R]
     if f >= 2:
         checks.append(v2.sum(axis=0))               # col sums [C]
+    # element updates are iota-masked adds, not scatters: inside a partial-
+    # manual region the auto axes may shard the flattened leaf, and the
+    # SPMD partitioner misplaces a scatter into it (4x2 mesh: the repair
+    # landed one slot over)
+    flat = jax.lax.broadcasted_iota(jnp.int32, (n,), 0)
     if inject is not None:
         shard, delta = inject
         hit = _linear_axis_index(axes) == shard
-        v = v.at[n // 2].add(jnp.where(hit, jnp.float32(delta), 0.0))
+        v = v + jnp.where(hit & (flat == n // 2), jnp.float32(delta), 0.0)
     elif inject_local is not None:
-        v = v.at[n // 2].add(jnp.float32(inject_local))
+        v = v + jnp.where(flat == n // 2, jnp.float32(inject_local), 0.0)
     packed = jnp.concatenate([v] + checks)
     total = jax.lax.psum(packed, axes)
     y = total[:n]
@@ -268,7 +270,7 @@ def abft_psum(x, axes, *, f: int = 2, mode: str = "correct",
         info["index"] = jnp.where(located, idx.astype(jnp.int32), neg1)
         info["magnitude"] = jnp.where(located, row_res[rr], 0.0)
         if mode == "correct":                                      # f >= 2
-            y = jnp.where(located, y.at[idx].add(-row_res[rr]), y)
+            y = y - jnp.where(located & (flat == idx), row_res[rr], 0.0)
             info["corrected"] = located
     y = y.reshape(shape).astype(dtype)
     return (y, ok, info) if with_info else (y, ok)
@@ -300,11 +302,9 @@ def abft_psum_tree(grads, dp_axes, ndp: int, *, mode: str = "verify",
     Returns ``(mean_grads, all_ok)``.
 
     Opt-in via ``train.step.StepOptions.abft_reduce`` on the deferred-
-    reduction path; pinned-jax caveat: that path's shard_map region also
-    scans over stacked params, which the jax 0.4.37 SPMD partitioner
-    rejects multi-device — the vmap collective semantics and the
-    single-device SPMD path are what tests exercise until the uprev
-    (ROADMAP "jax uprev").
+    reduction path, which is kept single-device (see
+    ``StepOptions.defer_grad_reduce``); tests exercise the vmap collective
+    semantics and the single-device SPMD path.
     """
     leaves, treedef = jax.tree.flatten(grads)
     events = _normalize_events(inject)
@@ -342,8 +342,7 @@ def ef_wire_bytes(param_shapes, ndp: int) -> dict:
     all_gather of the requantized segments) plus one fp32 scale per leaf
     per phase — the ~4x the ROADMAP's roofline tables want visible.  Used
     by `launch.dryrun` to annotate train cells without compiling the
-    int8 path (the pinned XLA cannot lower it multi-device; see
-    `ef_psum_tree`).
+    int8 path (see `ef_psum_tree`).
     """
     leaves = jax.tree.leaves(param_shapes)
     n_elems = sum(int(math.prod(x.shape)) for x in leaves)

@@ -24,10 +24,10 @@ import math
 from typing import Any, Optional
 
 import jax
-from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
+from jax.sharding import AxisType, Mesh, NamedSharding, PartitionSpec as P
 
 __all__ = [
-    "MODEL_AXIS", "dp_axes", "batch_specs", "infer_param_specs",
+    "MODEL_AXIS", "make_mesh", "dp_axes", "batch_specs", "infer_param_specs",
     "zero1_spec", "zero_dim", "cache_specs", "to_shardings",
 ]
 
@@ -53,17 +53,28 @@ _ROW_PARALLEL = {"wo", "down", "out_proj", "wout"}
 # ---------------------------------------------------------------------------
 
 
+def make_mesh(shape, axes, *, devices=None) -> Mesh:
+    """Every mesh of this codebase: `jax.make_mesh` with all axes `Auto`.
+
+    `jax.make_mesh` defaults to `Explicit` axes, where shardings are part of
+    the array types and `with_sharding_constraint` / gathers on a sharded
+    operand become type errors.  This code places arrays with explicit
+    NamedShardings and lets the partitioner propagate the rest — the
+    `Auto` contract."""
+    axes = tuple(axes)
+    return jax.make_mesh(tuple(shape), axes,
+                         axis_types=(AxisType.Auto,) * len(axes),
+                         devices=devices)
+
+
 def dp_axes(mesh: Mesh) -> tuple:
     """All mesh axes that are not the model axis, in mesh order.
 
     Everything non-"model" is data-parallel by convention (a multi-pod
     mesh's leading "pod" axis included), so this tuple is what gradient
-    reductions reduce over and what ZeRO/FSDP shard over.  Pinned-jax
-    caveat: passing these axes as the *manual* axes of a partial-manual
-    shard_map (`axis_names=frozenset(dp_axes(mesh))`) is how train.step
-    defers its gradient reduction, but on jax 0.4.37 such regions reject
-    scan-over-stacked-params, so the defer family is single-device-only
-    until the toolchain uprev (ROADMAP "jax uprev")."""
+    reductions reduce over and what ZeRO/FSDP shard over, and what
+    train.step passes as the *manual* axes of the partial-manual shard_map
+    that defers its gradient reduction."""
     return tuple(a for a in mesh.axis_names if a != MODEL_AXIS)
 
 
@@ -276,8 +287,6 @@ def to_shardings(spec_tree, mesh: Mesh):
     The bridge from this module's mesh-agnostic specs to the explicit
     NamedShardings that `jax.jit(in_shardings=...)`/`jax.device_put`
     consume.  Every sharding in this codebase is an explicit NamedSharding
-    (never ambient-mesh-dependent) — that is what lets `repro.compat`'s
-    `jax.set_mesh` shim be lexical-only on the pinned jax 0.4.37, which has
-    no ambient-mesh concept (see compat.py)."""
+    (never ambient-mesh-dependent)."""
     return jax.tree.map(lambda s: NamedSharding(mesh, s), spec_tree,
                         is_leaf=lambda x: isinstance(x, P))

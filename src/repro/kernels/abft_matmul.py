@@ -93,6 +93,21 @@ __all__ = ["abft_matmul_pallas", "abft_matmul_acc_pallas", "STATS_WIDTH"]
 #   4: residual magnitude (col direction)      5: residual magnitude (row dir)
 #   6: detection threshold (col direction)     7: |C_in| scale used for tol
 STATS_WIDTH = 8
+# In HBM each tile's stats are one (8, 128) block — the TPU lowering wants a
+# block's last two dims to be multiples of (8, 128) or the whole array, so a
+# (1, 1, STATS_WIDTH) block is refused once the grid has more than one tile.
+# Every row holds the STATS_WIDTH values in its first lanes; the wrapper
+# slices them back out.
+_STATS_TILE = (8, 128)
+
+
+def _stats_tile(values):
+    """Broadcast STATS_WIDTH scalars into a lane-dense (8, 128) tile."""
+    lane = lax.broadcasted_iota(jnp.int32, _STATS_TILE, 1)
+    tile = jnp.zeros(_STATS_TILE, jnp.float32)
+    for s, v in enumerate(values):
+        tile = jnp.where(lane == s, v, tile)
+    return tile
 
 
 def _tile_checksums(c32, wm, wn):
@@ -114,7 +129,7 @@ def _verify_correct(cin, wm, wn, ccol_c, crow_c, *, tol_factor, eps_c, bm, bn,
     (masked re-sum), which avoids the catastrophic cancellation of the naive
     ``x -= residual`` fix for large (exponent-bit) flips.  Two passes: the
     second is a no-op on clean data and mops up any residual left by the
-    first.  Returns (fixed_tile, stats[STATS_WIDTH]).
+    first.  Returns (fixed_tile, STATS_WIDTH stats scalars).
     """
     row_iota = lax.broadcasted_iota(jnp.int32, (bm, bn), 0)
     col_iota = lax.broadcasted_iota(jnp.int32, (bm, bn), 1)
@@ -163,13 +178,13 @@ def _verify_correct(cin, wm, wn, ccol_c, crow_c, *, tol_factor, eps_c, bm, bn,
         x_new = (carried - others) / (w0r + 1e-30)
         fixed = jnp.where(single & mask, x_new, fixed)
         if it == 0:
-            stats = jnp.stack([
+            stats = (
                 detected.astype(jnp.float32),
                 single.astype(jnp.float32),
                 jnp.where(single, (i * bm + ridx).astype(jnp.float32), -1.0),
                 jnp.where(single, (j * bn + cidx).astype(jnp.float32), -1.0),
                 cmax, rmax, tol_c, scale,
-            ])
+            )
     return fixed, stats
 
 
@@ -212,12 +227,11 @@ def _kernel(*refs, k_steps, carry_in, verify, tol_factor, eps_c, pipeline):
                 tol_factor=tol_factor, eps_c=eps_c,
                 bm=bm, bn=bn, i=i, j=j,
             )
-            stats_ref[...] = stats.reshape(1, 1, STATS_WIDTH)
+            stats_ref[0, 0] = _stats_tile(stats)
             acc_ref[...] = _to_acc(fixed)
         else:
             # -1 location sentinels (slots 2:4), matching the verified path
-            sw = lax.broadcasted_iota(jnp.int32, (1, 1, STATS_WIDTH), 2)
-            stats_ref[...] = jnp.where((sw == 2) | (sw == 3), -1.0, 0.0)
+            stats_ref[0, 0] = _stats_tile((0.0, 0.0, -1.0, -1.0))
             acc_ref[...] = _to_acc(cin)
 
     @pl.when((k >= dot_lo) & (k <= dot_hi))
@@ -405,7 +419,7 @@ def abft_matmul_acc_pallas(
         pl.BlockSpec((1, bm, f), lambda i, j, kk: (j, i, 0)),  # carried row
     ]
     out_specs = out_specs + [
-        pl.BlockSpec((1, 1, STATS_WIDTH), lambda i, j, kk: (i, j, 0)),
+        pl.BlockSpec((1, 1) + _STATS_TILE, lambda i, j, kk: (i, j, 0, 0)),
     ]
     c, ccol, crow, stats = pl.pallas_call(
         kernel,
@@ -416,10 +430,10 @@ def abft_matmul_acc_pallas(
             jax.ShapeDtypeStruct((m, n), out_dtype),
             jax.ShapeDtypeStruct((m // bm, f, n), jnp.float32),
             jax.ShapeDtypeStruct((n // bn, m, f), jnp.float32),
-            jax.ShapeDtypeStruct((m // bm, n // bn, STATS_WIDTH),
+            jax.ShapeDtypeStruct((m // bm, n // bn) + _STATS_TILE,
                                  jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((bm, bn), acc_dtype)],
         interpret=interpret,
     )(a, b, wm, wn, c_in, ccol_in, crow_in)
-    return c, ccol, crow, stats
+    return c, ccol, crow, stats[:, :, 0, :STATS_WIDTH]

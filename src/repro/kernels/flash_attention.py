@@ -60,7 +60,10 @@ __all__ = ["flash_attention_pallas", "flash_attention_checked",
 
 NEG_INF = -1e30
 FLASH_CHECK_TOL = 1e-3
-_STATS_LANES = 128   # stats row padded to a full TPU lane tile
+# per-tile stats block: one full (8, 128) TPU tile, every row the same
+# (r_pv, r_l, 0, ...) lane vector — a (1, 128) block is refused by the TPU
+# lowering once there is more than one q-tile
+_STATS_TILE = (8, 128)
 
 register_surface(
     "kernels.flash_attention", owner=__name__, protected=True,
@@ -168,10 +171,10 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, *rest,
                 jnp.abs(jnp.sum(o, axis=-1, keepdims=True) - want) /
                 (jnp.abs(want) + 1.0), 0.0)
             r_l = jnp.where(live, jnp.abs(l2_ref[...] / l_safe - 1.0), 0.0)
-            lane = jax.lax.broadcasted_iota(jnp.int32, (1, _STATS_LANES), 1)
-            row = jnp.where(lane == 0, jnp.max(r_pv),
-                            jnp.where(lane == 1, jnp.max(r_l), 0.0))
-            stats_ref[0] = row
+            lane = jax.lax.broadcasted_iota(jnp.int32, _STATS_TILE, 1)
+            stats_ref[0, 0] = jnp.where(
+                lane == 0, jnp.max(r_pv),
+                jnp.where(lane == 1, jnp.max(r_l), 0.0))
 
 
 @functools.partial(
@@ -200,14 +203,14 @@ def _flash_call(q, k, v, *, scale, causal, window, softcap, bq, bk,
     ]
     if checksum:
         out_specs = [out_specs, pl.BlockSpec(
-            (1, 1, _STATS_LANES), lambda b, i, kk: (b, i, 0))]
+            (1, 1) + _STATS_TILE, lambda b, i, kk: (b, i, 0, 0))]
         out_shape = [out_shape, jax.ShapeDtypeStruct(
-            (bh, sq // bq, _STATS_LANES), jnp.float32)]
+            (bh, sq // bq) + _STATS_TILE, jnp.float32)]
         scratch += [
             pltpu.VMEM((bq, 1), jnp.float32),    # cs  (Σ_d acc shadow)
             pltpu.VMEM((bq, 1), jnp.float32),    # l2  (MXU-path l)
         ]
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kernel,
         grid=grid,
         in_specs=[
@@ -220,6 +223,10 @@ def _flash_call(q, k, v, *, scale, causal, window, softcap, bq, bk,
         scratch_shapes=scratch,
         interpret=interpret,
     )(q, k, v)
+    if checksum:
+        o, stats = out
+        return o, stats[:, :, 0, :]     # [BH, Sq/bq, 128] stats rows
+    return out
 
 
 def flash_attention_pallas(

@@ -31,6 +31,8 @@ import argparse
 import json
 import sys
 
+from repro.launch import compile_cache
+
 SHAPE_SETS = {
     "smoke": [(256, 256, 256)],
     "bench": [(256, 256, 256), (256, 512, 384), (512, 512, 512),
@@ -48,6 +50,7 @@ def _parse_shape(s: str):
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(
         description="pre-warm the ABFT-GEMM autotune cache")
     ap.add_argument("--shapes", choices=sorted(SHAPE_SETS), default=None,

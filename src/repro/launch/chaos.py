@@ -36,6 +36,7 @@ import sys
 
 from repro.chaos.campaign import CampaignRunner, TrainConfig
 from repro.chaos.faults import Episode, FaultSpace, FaultSpec
+from repro.launch import compile_cache
 
 WORKLOAD_SETS = {
     "train": ("train",),
@@ -75,6 +76,7 @@ def space_from_artifact(d: dict) -> FaultSpace:
 
 
 def main(argv=None) -> int:
+    compile_cache.enable()
     ap = argparse.ArgumentParser(description=__doc__)
     ap.add_argument("--space", default="default",
                     choices=("default", "smoke", "cartesian",

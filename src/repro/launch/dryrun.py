@@ -28,6 +28,7 @@ from pathlib import Path
 import jax
 
 from repro.configs.base import SHAPES, get_config, list_configs, valid_cells
+from repro.launch import compile_cache
 from repro.launch.mesh import make_production_mesh
 from repro.train.step import (StepOptions, build_prefill_step,
                               build_serve_step, build_train_step, init_state,
@@ -113,8 +114,6 @@ def dryrun_cell(arch: str, shape_name: str, mesh, *, opts=None, verbose=True,
 
         mem = compiled.memory_analysis()
         cost = compiled.cost_analysis()
-        if isinstance(cost, list):    # older jax: one dict per partition
-            cost = cost[0] if cost else {}
         hlo = compiled.as_text()
         coll = collective_bytes(hlo)
         # trip-count-aware accounting (XLA cost_analysis counts loop bodies
@@ -126,11 +125,10 @@ def dryrun_cell(arch: str, shape_name: str, mesh, *, opts=None, verbose=True,
         if shape.kind == "train":
             # gradient-reduction wire accounting: fp32 ring all-reduce vs
             # the int8-EF exchange (`dist.collectives.ef_psum_tree`,
-            # wire="int8").  Analytic, not compiled — the pinned XLA cannot
-            # lower the int8 collectives multi-device (ROADMAP "jax
-            # uprev"), but the wire bytes are a pure function of the param
-            # tree and the DP extent, so the 4x shows up in the roofline
-            # tables either way.
+            # wire="int8").  Analytic, not compiled — the int8 collectives
+            # are opt-in (see `ef_psum_tree`), but the wire bytes are a
+            # pure function of the param tree and the DP extent, so the 4x
+            # shows up in the roofline tables either way.
             from repro.dist import sharding as shd
             from repro.dist.collectives import ef_wire_bytes
             from repro.models import transformer as tf
@@ -182,6 +180,7 @@ def dryrun_cell(arch: str, shape_name: str, mesh, *, opts=None, verbose=True,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default=None)
     ap.add_argument("--shape", default=None)

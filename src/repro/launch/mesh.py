@@ -5,17 +5,12 @@ touches jax device state (required so smoke tests / benches see 1 device).
 """
 from __future__ import annotations
 
-import jax
+from repro.dist.sharding import make_mesh
 
-__all__ = ["make_production_mesh", "make_mesh"]
+__all__ = ["make_production_mesh"]
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return jax.make_mesh(shape, axes)
-
-
-def make_mesh(shape, axes):
-    """Arbitrary mesh (tests, elastic re-meshing after failures)."""
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    return make_mesh(shape, axes)

@@ -30,6 +30,7 @@ import sys
 import time
 
 from repro import obs
+from repro.launch import compile_cache
 
 SCHEMA = "repro.obs.pr10/v1"
 
@@ -235,6 +236,7 @@ def _summary(evs, doc) -> str:
 
 
 def main(argv=None):
+    compile_cache.enable()
     parser = argparse.ArgumentParser(description=__doc__)
     sub = parser.add_subparsers(dest="cmd", required=True)
 

@@ -23,7 +23,9 @@ import jax
 import numpy as np
 
 from repro.configs.base import get_config, smoke_config
+from repro.dist.sharding import make_mesh
 from repro.ft.failures import SDCInjector, SDCPlan
+from repro.launch import compile_cache
 from repro.models import transformer as tf
 from repro.serve.engine import Request, ServeEngine
 
@@ -44,7 +46,7 @@ def run(arch: str, *, smoke: bool = True, requests: int = 6, slots: int = 2,
     params = tf.init_params(jax.random.PRNGKey(seed), cfg)
     mesh = None
     if mesh_shape is not None:
-        mesh = jax.make_mesh(tuple(mesh_shape), ("data", "model"))
+        mesh = make_mesh(mesh_shape, ("data", "model"))
     engine = ServeEngine(
         cfg, params, slots=slots, max_len=prompt_len + gen + 8,
         abft_mode=abft_mode, abft_reduce=abft_reduce, mesh=mesh,
@@ -77,9 +79,13 @@ def run(arch: str, *, smoke: bool = True, requests: int = 6, slots: int = 2,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true", default=True)
+    ap.add_argument("--full", dest="smoke", action="store_false",
+                    help="serve the published config instead of the "
+                         "reduced smoke config")
     ap.add_argument("--requests", type=int, default=6)
     ap.add_argument("--slots", type=int, default=2)
     ap.add_argument("--prompt-len", type=int, default=8)
@@ -105,7 +111,7 @@ def main():
             ap.error("--drill-step needs --reduce verify|correct")
         drill = SDCPlan(((args.drill_step, args.drill_shard,
                           args.drill_delta),))
-    run(args.arch, requests=args.requests, slots=args.slots,
+    run(args.arch, smoke=args.smoke, requests=args.requests, slots=args.slots,
         prompt_len=args.prompt_len, gen=args.gen, abft_mode=args.abft,
         abft_reduce=args.reduce, mesh_shape=mesh_shape, drill=drill)
 

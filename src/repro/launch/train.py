@@ -38,10 +38,12 @@ import numpy as np
 from repro.configs.base import ShapeConfig, get_config, smoke_config
 from repro.data.pipeline import DataConfig, DataPipeline
 from repro.data.pipeline import synthetic_batch as synthetic
+from repro.dist.sharding import make_mesh
 from repro.ckpt.disk import CheckpointManager
 from repro.ft.failures import FailureInjector, FailurePlan
 from repro.ft.runtime import (ElasticRuntime, FTPolicy, FTRuntime,
                               stack_view, unstack_view)
+from repro.launch import compile_cache
 from repro.train.optimizer import AdamWConfig
 from repro.train.step import StepOptions, build_train_step, init_state, make_inputs
 
@@ -54,7 +56,7 @@ def run(arch: str, *, smoke: bool = True, steps: int = 100, batch: int = 16,
     cfg = smoke_config(arch) if smoke else get_config(arch)
     shape = ShapeConfig("cli", seq, batch, "train")
     if mesh is None:
-        mesh = jax.make_mesh((1, 1), ("data", "model"))
+        mesh = make_mesh((1, 1), ("data", "model"))
     opts = StepOptions(microbatches=microbatches, abft_mode=abft_mode,
                        remat=False if smoke else True)
     total = total_steps or steps  # schedule horizon (resume consistency)
@@ -93,15 +95,19 @@ def run(arch: str, *, smoke: bool = True, steps: int = 100, batch: int = 16,
         i = start_step
         done_steps = 0
         while i < steps:
-            # diskless/disk checkpoint cadence (views are p-stacked splits)
-            stacked = _stack_view(state, p_logical)
-            ft.maybe_checkpoint(i, stacked, aux={"data_step": i})
+            # diskless/disk checkpoint cadence (views are p-stacked splits).
+            # A view is a copy of the state: build one per use and never
+            # hold it across the step — at full width on one chip, state +
+            # view + diskless snapshot + step temporaries do not fit.
+            ft.maybe_checkpoint(i, _stack_view(state, p_logical),
+                                aux={"data_step": i})
 
             failed = ft.injector.check(i) if ft.injector else None
             if failed is not None:
-                stacked = FailureInjector.damage(stacked, failed, p_logical)
-                stacked = ft.recover(stacked, [failed])
-                state = _unstack_view(stacked, state)
+                stacked = FailureInjector.damage(
+                    _stack_view(state, p_logical), failed, p_logical)
+                state = _unstack_view(ft.recover(stacked, [failed]), state)
+                del stacked
                 rollback = ft.diskless.step if ft.diskless.step is not None else i
                 print(f"[train] step {i}: shard {failed} lost; diskless "
                       f"recovery -> rollback to step {rollback}")
@@ -178,7 +184,7 @@ def run_elastic_drill(arch: str = "qwen2-0.5b", *, steps: int = 10,
         tmp = tempfile.TemporaryDirectory()
         ckpt_dir = tmp.name
 
-    mesh = jax.make_mesh(tuple(mesh_shape), ("pod", "data", "model"))
+    mesh = make_mesh(mesh_shape, ("pod", "data", "model"))
     # keep every step: the parity reference re-restores the rollback ckpt
     rt = ElasticRuntime(cfg, shape, mesh, adamw=adamw, opts=opts,
                         policy=policy,
@@ -249,9 +255,8 @@ def run_elastic_drill(arch: str = "qwen2-0.5b", *, steps: int = 10,
     params_max_abs_diff = None
     if killed:
         from repro.ckpt.elastic import reshard_restore
-        ref_mesh = jax.make_mesh(
-            tuple(shrink_rep.mesh_to.values()),
-            tuple(shrink_rep.mesh_to.keys()))
+        ref_mesh = make_mesh(shrink_rep.mesh_to.values(),
+                             shrink_rep.mesh_to.keys())
         ref_rt = ElasticRuntime(cfg, shape, ref_mesh, adamw=adamw,
                                 opts=opts, policy=policy)
         manager = CheckpointManager(str(Path(ckpt_dir) / "ref"))
@@ -310,6 +315,7 @@ def run_elastic_drill(arch: str = "qwen2-0.5b", *, steps: int = 10,
 
 
 def main():
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--arch", default="qwen2-0.5b")
     ap.add_argument("--smoke", action="store_true", default=True)

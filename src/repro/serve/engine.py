@@ -38,11 +38,9 @@ Fault tolerance while serving:
     locates, corrects in-flight and records the event in `EngineStats`
     (detections, corrections, recovery latency, per-request TTFT / tok/s).
 
-Pinned-jax caveat (0.4.37): the verified-unembed shard_map region is
-partial-manual over {"model"} and contains only a matmul + one psum, which
-lowers everywhere — unlike scan-over-stacked-params or gather-family
-collectives in such regions (see ROADMAP "jax uprev"); the layer scans stay
-in the auto-sharded body for exactly that reason.
+The verified-unembed shard_map region is partial-manual over {"model"} and
+contains only a matmul + one psum; the layer scans stay in the auto-sharded
+body (see `repro.dist` on partial-manual regions).
 """
 from __future__ import annotations
 
@@ -241,7 +239,7 @@ class ServeEngine:
             # the protected reduction needs a mesh axis to reduce over; a
             # 1-device mesh keeps one code path (psum over extent 1) and
             # still drills detection/correction end-to-end
-            mesh = jax.make_mesh((1, 1), ("data", shd.MODEL_AXIS))
+            mesh = shd.make_mesh((1, 1), ("data", shd.MODEL_AXIS))
         self.mesh = mesh
         if self._protected:
             m_ext = mesh.shape.get(shd.MODEL_AXIS, 1)
@@ -664,7 +662,7 @@ class ServeEngine:
                               x_l.astype(jnp.float32), wl)
             # inj, when present, is this shard's [1] slice of the delta
             # vector — shard selection happened OUTSIDE the region, so no
-            # axis_index is needed (it cannot lower here on jax 0.4.37)
+            # axis_index is needed (see chaos.faults.scatter_delta)
             return abft_psum(part, (shd.MODEL_AXIS,), f=f, mode=mode,
                              inject_local=inj[0][0] if inj else None,
                              with_info=True)

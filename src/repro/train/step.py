@@ -52,12 +52,12 @@ class StepOptions:
     # defer the DP gradient all-reduce to AFTER microbatch accumulation
     # (shard_map manual-DP region: one psum instead of one per microbatch —
     # cuts grad collective bytes by the microbatch count).
-    # NOTE pinned-toolchain limit: on jax 0.4.37 the XLA SPMD partitioner
-    # aborts (Check failed: IsManualSubgroup) on lax.scan-over-stacked-
-    # params inside a PARTIAL-manual region, so the defer family (defer /
-    # zero2 / int8_ef / abft_reduce) lowers multi-device only on the newer
-    # toolchain this codebase targets; single-device SPMD and the vmap
-    # collective semantics are exercised by tests either way.
+    # The defer family (defer / zero2 / int8_ef / abft_reduce) is driven
+    # single-device: an earlier toolchain's SPMD partitioner aborted
+    # (IsManualSubgroup) on lax.scan-over-stacked-params inside a PARTIAL-
+    # manual region, and multi-device is not yet re-tested on jax 0.9
+    # (ROADMAP Queue 3, item 2); tests exercise single-device SPMD and the
+    # vmap collective semantics.
     defer_grad_reduce: bool = False
     # ZeRO-2: reduce-SCATTER the deferred gradients over DP (each device
     # holds 1/ndp of the fp32 grads, matching the ZeRO-1 opt-state shards;

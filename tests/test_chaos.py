@@ -316,8 +316,9 @@ def test_invariant_checks_wire_through_train_step():
     from repro.data.pipeline import DataConfig, synthetic_batch
     from repro.train.optimizer import AdamWConfig
     from repro.train.step import StepOptions, build_train_step, init_state
+    from repro.dist.sharding import make_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = smoke_config("qwen2-0.5b")
     shape = ShapeConfig("t", 32, 4, "train")
     opts = StepOptions(microbatches=2, remat=True, invariant_checks=True)
@@ -342,8 +343,9 @@ def test_invariant_checks_reject_deferred_grad_reduce():
     from repro.configs.base import ShapeConfig, smoke_config
     from repro.train.optimizer import AdamWConfig
     from repro.train.step import StepOptions, build_train_step
+    from repro.dist.sharding import make_mesh
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     with pytest.raises(ValueError, match="invariant_checks"):
         build_train_step(
             smoke_config("qwen2-0.5b"), mesh, ShapeConfig("t", 32, 4,

@@ -14,6 +14,7 @@ import pytest
 
 from repro.dist.collectives import (abft_psum, abft_psum_tree, ef_psum_tree,
                                     ef_wire_bytes)
+from repro.dist.sharding import make_mesh
 from repro.ft.failures import SDCInjector, SDCPlan, flip_bit
 
 NDP = 4
@@ -263,7 +264,7 @@ def _train_pair():
     from repro.train.optimizer import AdamWConfig
     from repro.train.step import StepOptions, build_train_step, init_state
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = smoke_config("qwen2-0.5b")
     shape = ShapeConfig("t", 32, 4, "train")
     dc = DataConfig(cfg.vocab_size, 32, 4)
@@ -295,7 +296,7 @@ def test_abft_reduce_option_conflicts_raise():
     from repro.configs.base import ShapeConfig, smoke_config
     from repro.train.step import StepOptions, build_train_step
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = smoke_config("qwen2-0.5b")
     shape = ShapeConfig("t", 32, 4, "train")
     for bad in (StepOptions(abft_reduce="correct"),               # no defer
@@ -357,7 +358,7 @@ def test_int8_ef_convergence_1k_steps():
     from repro.train.optimizer import AdamWConfig
     from repro.train.step import StepOptions, build_train_step, init_state
 
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = make_mesh((1, 1), ("data", "model"))
     cfg = smoke_config("qwen2-0.5b")
     steps = 1000
     shape = ShapeConfig("t", 16, 4, "train")

@@ -16,9 +16,9 @@ from repro.dist import sharding as shd
 from repro.models import transformer as tf
 
 MESHES = {
-    "1x1": AbstractMesh((("data", 1), ("model", 1))),
-    "2x2": AbstractMesh((("data", 2), ("model", 2))),
-    "pod": AbstractMesh((("pod", 2), ("data", 16), ("model", 16))),
+    "1x1": AbstractMesh((1, 1), ("data", "model")),
+    "2x2": AbstractMesh((2, 2), ("data", "model")),
+    "pod": AbstractMesh((2, 16, 16), ("pod", "data", "model")),
 }
 
 
@@ -65,7 +65,7 @@ def test_rules_are_name_based_not_mesh_based():
     cfg, shapes = _param_shapes("gemma2-2b")
     a = shd.infer_param_specs(shapes, MESHES["2x2"], cfg)
     b = shd.infer_param_specs(
-        shapes, AbstractMesh((("data", 2), ("model", 2))), cfg)
+        shapes, AbstractMesh((2, 2), ("data", "model")), cfg)
     assert all(jax.tree.leaves(
         jax.tree.map(lambda x, y: x == y, a, b,
                      is_leaf=lambda x: isinstance(x, P))))
@@ -140,7 +140,7 @@ def test_cache_specs_batch_and_sequence_sharding():
 
 
 def test_to_shardings_on_real_mesh():
-    mesh = jax.make_mesh((1, 1), ("data", "model"))
+    mesh = shd.make_mesh((1, 1), ("data", "model"))
     cfg, shapes = _param_shapes("qwen2-0.5b")
     specs = shd.infer_param_specs(shapes, mesh, cfg)
     sh = shd.to_shardings(specs, mesh)

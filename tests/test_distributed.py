@@ -11,12 +11,13 @@ TRAIN_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.dist.sharding import make_mesh
 from repro.configs.base import ShapeConfig, smoke_config
 from repro.data.pipeline import DataConfig, synthetic_batch
 from repro.train.step import StepOptions, build_train_step, init_state
 from repro.train.optimizer import AdamWConfig
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = smoke_config("qwen3-moe-30b-a3b")      # MoE exercises EP dispatch
 shape = ShapeConfig("t", 32, 8, "train")
 opts = StepOptions(microbatches=2, remat=True, zero1=True)
@@ -38,7 +39,7 @@ with jax.set_mesh(mesh):
 print("losses", losses)
 # single-device reference: same loss trajectory (sharding-invariance is the
 # meaningful assertion; 3-step loss direction is batch noise)
-mesh1 = jax.make_mesh((1, 1), ("data", "model"))
+mesh1 = make_mesh((1, 1), ("data", "model"))
 with jax.set_mesh(mesh1):
     fn1, in_sh1, out_sh1 = build_train_step(cfg, mesh1, shape,
                                       AdamWConfig(lr=1e-3, total_steps=10), opts)
@@ -61,11 +62,12 @@ SERVE_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp
+from repro.dist.sharding import make_mesh
 from repro.configs.base import ShapeConfig, smoke_config
 from repro.models import transformer as tf
 from repro.train.step import build_serve_step, build_prefill_step, make_inputs
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = smoke_config("gemma2-2b")
 shape = ShapeConfig("d", 64, 8, "decode")
 with jax.set_mesh(mesh):
@@ -101,12 +103,13 @@ DISKLESS_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.dist.sharding import make_mesh
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.ckpt.diskless import DisklessCheckpoint
 
 # state stacked over the DP axis and SHARDED over it: the encode/recover
 # algebra must hold on distributed arrays (placement = rotation)
-mesh = jax.make_mesh((8,), ("data",))
+mesh = make_mesh((8,), ("data",))
 p = 8
 sh = NamedSharding(mesh, P("data"))
 x = jax.device_put(np.random.RandomState(0).standard_normal(
@@ -133,12 +136,13 @@ FSDP_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.dist.sharding import make_mesh
 from repro.configs.base import ShapeConfig, smoke_config
 from repro.data.pipeline import DataConfig, synthetic_batch
 from repro.train.step import StepOptions, build_train_step, init_state
 from repro.train.optimizer import AdamWConfig
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = smoke_config("qwen2-0.5b")
 shape = ShapeConfig("t", 32, 8, "train")
 dc = DataConfig(cfg.vocab_size, 32, 8)

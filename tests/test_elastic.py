@@ -14,6 +14,7 @@ SCRIPT = r"""
 import os, tempfile
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, jax.numpy as jnp, numpy as np
+from repro.dist.sharding import make_mesh
 from repro.configs.base import ShapeConfig, smoke_config
 from repro.ckpt.disk import CheckpointManager
 from repro.ckpt.elastic import reshard_restore
@@ -27,7 +28,7 @@ opts = StepOptions(microbatches=1, remat=False)
 dc = DataConfig(cfg.vocab_size, 32, 8)
 
 # "2-pod" mesh: (pod=2, data=2, model=2)
-mesh2 = jax.make_mesh((2, 2, 2), ("pod", "data", "model"))
+mesh2 = make_mesh((2, 2, 2), ("pod", "data", "model"))
 with jax.set_mesh(mesh2):
     fn, in_sh, out_sh = build_train_step(cfg, mesh2, shape,
                                          AdamWConfig(total_steps=10), opts)
@@ -45,7 +46,7 @@ with tempfile.TemporaryDirectory() as d:
     mgr.save(2, state, blocking=True)
 
     # pod lost -> survivors form a (data=2, model=2) mesh
-    mesh1 = jax.make_mesh((2, 2), ("data", "model"))
+    mesh1 = make_mesh((2, 2), ("data", "model"))
     with jax.set_mesh(mesh1):
         like = jax.eval_shape(lambda: state)
         state1 = reshard_restore(mgr, 2, like, mesh1, opts, cfg)

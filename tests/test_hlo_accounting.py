@@ -62,8 +62,6 @@ def test_xla_cost_analysis_undercounts():
     def scanned(a):
         return lax.scan(lambda x, _: (x @ a, None), a, None, length=8)[0]
     ca = jax.jit(scanned).lower(A).compile().cost_analysis()
-    if isinstance(ca, list):          # older jax: one dict per partition
-        ca = ca[0]
     # ~1/8 of the truth (one loop body + the s32 counter add)
     assert ca["flops"] < 2 * M ** 3 + 16
 

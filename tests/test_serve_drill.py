@@ -23,12 +23,13 @@ DRILL_SCRIPT = r"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=8"
 import jax, numpy as np
+from repro.dist.sharding import make_mesh
 from repro.configs.base import smoke_config
 from repro.ft.failures import SDCInjector, SDCPlan
 from repro.models import transformer as tf
 from repro.serve.engine import Request, ServeEngine
 
-mesh = jax.make_mesh((4, 2), ("data", "model"))
+mesh = make_mesh((4, 2), ("data", "model"))
 cfg = smoke_config("qwen2-0.5b")
 params = tf.init_params(jax.random.PRNGKey(0), cfg)
 rs = np.random.RandomState(0)

@@ -12,6 +12,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=25"
 import numpy as np, jax, jax.numpy as jnp
 import repro.core as core
+from repro.dist.sharding import make_mesh
 
 failures = []
 
@@ -25,7 +26,7 @@ rs = np.random.RandomState(0)
 for grid, f in [(4, 1), (5, 2)]:
     pr = grid - f
     mb = 8
-    mesh = jax.make_mesh((grid, grid), ("rows", "cols"))
+    mesh = make_mesh((grid, grid), ("rows", "cols"))
     spec = core.make_spec(f, pr, pr)
     A = jnp.asarray(rs.standard_normal((pr * mb, grid * mb)), jnp.float32)
     B = jnp.asarray(rs.standard_normal((grid * mb, pr * mb)), jnp.float32)
@@ -59,7 +60,7 @@ for grid, f in [(4, 1), (5, 2)]:
 # simultaneous multi-device failures (f=2 grid from the loop above)
 grid, f = 5, 2
 pr, mb = grid - f, 8
-mesh = jax.make_mesh((grid, grid), ("rows", "cols"))
+mesh = make_mesh((grid, grid), ("rows", "cols"))
 spec = core.make_spec(f, pr, pr)
 A = jnp.asarray(rs.standard_normal((pr*mb, grid*mb)), jnp.float32)
 B = jnp.asarray(rs.standard_normal((grid*mb, pr*mb)), jnp.float32)
@@ -79,7 +80,7 @@ except ValueError:
     pass
 
 # plain (non-FT) SUMMA == matmul
-mesh = jax.make_mesh((4, 4), ("rows", "cols"))
+mesh = make_mesh((4, 4), ("rows", "cols"))
 A = jnp.asarray(rs.standard_normal((32, 32)), jnp.float32)
 B = jnp.asarray(rs.standard_normal((32, 32)), jnp.float32)
 check("plain summa", float(jnp.max(jnp.abs(core.summa(A, B, mesh) - A @ B))))
@@ -105,6 +106,7 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
 import numpy as np, jax, jax.numpy as jnp
 import repro.core as core
+from repro.dist.sharding import make_mesh
 
 failures = []
 
@@ -119,7 +121,7 @@ def check(name, err, tol=1e-3):
 rs = np.random.RandomState(0)
 grid, f, mb = 2, 1, 128
 pr = grid - f
-mesh = jax.make_mesh((grid, grid), ("rows", "cols"))
+mesh = make_mesh((grid, grid), ("rows", "cols"))
 spec = core.make_spec(f, pr, pr)
 A = jnp.asarray(rs.standard_normal((pr * mb, grid * mb)), jnp.float32)
 B = jnp.asarray(rs.standard_normal((grid * mb, pr * mb)), jnp.float32)

@@ -1,5 +1,10 @@
 """End-to-end behaviour tests for the paper's system: train through
 failures, resume exactly, ABFT-on training parity, serving."""
+import os
+import subprocess
+import sys
+from pathlib import Path
+
 import jax
 import jax.numpy as jnp
 import numpy as np
@@ -72,3 +77,25 @@ def test_serving_drill_corrects_in_flight():
     assert e1.stats.detections == 1 and e1.stats.corrections == 1
     assert {r.rid: r.output for r in clean} == \
         {r.rid: r.output for r in drilled}
+
+
+@pytest.mark.parametrize("cli, args, expect", [
+    ("repro.launch.serve",
+     ["--requests", "2", "--slots", "2", "--prompt-len", "8", "--gen", "4",
+      "--abft", "verify", "--reduce", "correct"],
+     "protected reduce: detections=0 corrections=0"),
+    ("repro.launch.train",
+     ["--steps", "3", "--batch", "8", "--seq", "32", "--abft", "verify"],
+     "[train] done"),
+])
+def test_cli_runs_at_smoke_size(tmp_path, cli, args, expect):
+    """Both CLIs, as a user runs them, end to end on the CPU: ABFT-verified
+    projections (plus the protected logits reduction when serving) on the
+    1x1 mesh the launchers build through `dist.sharding.make_mesh`."""
+    env = dict(os.environ, JAX_PLATFORMS="cpu",
+               JAX_COMPILATION_CACHE_DIR=str(tmp_path),
+               PYTHONPATH=str(Path(__file__).resolve().parents[1] / "src"))
+    r = subprocess.run([sys.executable, "-m", cli, *args], env=env,
+                       capture_output=True, text=True, timeout=300)
+    assert r.returncode == 0, r.stderr[-3000:]
+    assert expect in r.stdout, r.stdout
