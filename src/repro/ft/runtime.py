@@ -49,6 +49,7 @@ import jax
 import jax.numpy as jnp
 
 from repro import obs
+from repro.obs import profiler as obs_profiler
 from repro.chaos.faults import register_surface
 from repro.ckpt.diskless import DisklessCheckpoint
 from repro.ft.failures import FailureInjector, SDCInjector
@@ -399,6 +400,7 @@ class ElasticRuntime(FTRuntime):
         from repro.train.optimizer import AdamWConfig
         from repro.train.step import StepOptions
 
+        obs_profiler.install()
         self.cfg = cfg
         self.shape = shape
         self.adamw = adamw or AdamWConfig()

@@ -460,9 +460,6 @@ def abft_matmul(
     obs.counter("repro_kernel_traces_total",
                 "kernel dispatcher traces (≈ compiles)").inc(
         op="abft_matmul", backend="pallas" if use_pallas else "ref")
-    obs.event("kernel/trace", op="abft_matmul",
-              backend="pallas" if use_pallas else "ref",
-              m=m, k=k, n=n, dtype=str(jnp.dtype(a.dtype)))
     if use_pallas:
         return _fused_mm(plan, jnp.dtype(out_dtype), not on_tpu(),
                          a, b, wm, wn)
@@ -689,9 +686,6 @@ def abft_matmul_acc(
     obs.counter("repro_kernel_traces_total",
                 "kernel dispatcher traces (≈ compiles)").inc(
         op="abft_matmul_acc", backend="pallas" if use_pallas else "jnp")
-    obs.event("kernel/trace", op="abft_matmul_acc",
-              backend="pallas" if use_pallas else "jnp",
-              m=m, n=n, verify=verify, dtype=str(jnp.dtype(a.dtype)))
     if use_pallas:
         interpret = not on_tpu() if interpret is None else interpret
         c, ccol, crow, stats = abft_matmul_acc_pallas(

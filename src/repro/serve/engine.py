@@ -55,6 +55,7 @@ import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro import obs
+from repro.obs import profiler as obs_profiler
 from repro.chaos.faults import SDCInjector, register_surface, scatter_delta
 from repro.configs.base import ModelConfig
 from repro.dist import sharding as shd
@@ -104,6 +105,7 @@ class Request:
     done: bool = False
     # host-side latency timeline (filled by the engine)
     t_submit: float = 0.0
+    t_admit: float = 0.0     # admission started (queue wait = t_admit - t_submit)
     t_first: float = 0.0     # first token available (prefill done)
     t_done: float = 0.0
 
@@ -159,8 +161,8 @@ class EngineStats:
     prefills: int = 0
     detections: int = 0
     corrections: int = 0
-    prefill_s: float = 0.0           # total wall time in prefill program
-    decode_s: float = 0.0            # total wall time in decode program
+    prefill_s: float = 0.0           # total of the serve/prefill(_chunk) spans
+    decode_s: float = 0.0            # total of the serve/decode_step spans
     decode_step_s: List[float] = dataclasses.field(default_factory=list)
     drilled_step_s: List[float] = dataclasses.field(default_factory=list)
     ttft_s: List[float] = dataclasses.field(default_factory=list)
@@ -215,6 +217,7 @@ class ServeEngine:
             raise ValueError("sdc drills corrupt the protected logits "
                              "reduction — set abft_reduce to 'verify' or "
                              "'correct'")
+        obs_profiler.install()
         self.cfg = cfg
         self.slots = slots
         self.max_len = max_len
@@ -306,14 +309,15 @@ class ServeEngine:
                      self._cache_sh)
             out_sh = (self._rep, self._cache_sh, self._rep,
                       {k: self._rep for k in _INFO0})
-            self._decode = jax.jit(self._decode_impl, in_shardings=in_sh,
+            self._decode = jax.jit(self.serve_decode, in_shardings=in_sh,
                                    out_shardings=out_sh)
             self._decode_drill = jax.jit(
-                self._drill_impl, in_shardings=in_sh + (self._rep, self._rep),
+                self.serve_decode_drill,
+                in_shardings=in_sh + (self._rep, self._rep),
                 out_shardings=out_sh)
         else:
-            self._decode = jax.jit(self._decode_impl)
-            self._decode_drill = jax.jit(self._drill_impl)
+            self._decode = jax.jit(self.serve_decode)
+            self._decode_drill = jax.jit(self.serve_decode_drill)
         self._prefill = {}  # len -> jitted prefill (bucketed)
 
     # -- public ---------------------------------------------------------------
@@ -422,15 +426,16 @@ class ServeEngine:
 
     def _get_prefill(self, bucket: int):
         if bucket not in self._prefill:
-            fn = (lambda pr, tok, ln, _b=bucket:
-                  self._prefill_impl(pr, tok, ln, _b))
+            def serve_prefill(pr, tok, ln, _b=bucket):
+                return self._prefill_impl(pr, tok, ln, _b)
             if self.mesh is not None:
                 small_sh = self._cache_shardings(1)
                 self._prefill[bucket] = jax.jit(
-                    fn, in_shardings=(self._param_sh, self._rep, self._rep),
+                    serve_prefill,
+                    in_shardings=(self._param_sh, self._rep, self._rep),
                     out_shardings=(self._rep, small_sh))
             else:
-                self._prefill[bucket] = jax.jit(fn)
+                self._prefill[bucket] = jax.jit(serve_prefill)
         return self._prefill[bucket]
 
     # -- at-rest scrub ---------------------------------------------------------
@@ -462,13 +467,15 @@ class ServeEngine:
         """Verify-on-read: recompute KV and params fingerprints against the
         armed values (split into `_scrub_kv` / `_scrub_params` so the paged
         engine can swap in its page-granular unit)."""
-        t0 = time.perf_counter()
-        self.stats.scrub_checks += 1
         step = self.stats.decode_steps
         events: List[ScrubEvent] = []
-        self._scrub_kv(step, events)
-        self._scrub_params(step, events)
-        wall = time.perf_counter() - t0
+        with obs.span("serve/scrub", step=step) as sp:
+            self.stats.scrub_checks += 1
+            with obs.span("serve/scrub_kv", step=step):
+                self._scrub_kv(step, events)
+            with obs.span("serve/scrub_params", step=step):
+                self._scrub_params(step, events)
+        wall = sp.dur_s
         obs.histogram("repro_checksum_verify_seconds",
                       "at-rest scrub verify+repair wall").observe(
             wall, domain="serve")
@@ -573,32 +580,66 @@ class ServeEngine:
     def _retire_slot(self, s: int):
         """A slot's request just finished (paged engine frees its pages)."""
 
+    def _writeback_pages(self, n_active: int) -> int:
+        """One-token page writes `_post_decode` makes for ``n_active``
+        slots (the contiguous engine writes no pages: it re-arms the whole
+        cache)."""
+        return 0
+
+    def _write_pages(self, s: int, cache_tree, start: int, end: int):
+        """Persist positions [start, end) of a 1-slot prefill cache (paged
+        engine; the contiguous engine's slot scatter already holds them)."""
+
     def _admit(self):
         admitted = False
         for s in range(self.slots):
             if self.active[s] is not None or not self.queue:
                 continue
-            req = self.queue.popleft()
-            t0 = time.perf_counter()
-            plen = len(req.prompt)
-            bucket = self._bucket(plen)
+            self._start_admission(s, self.queue.popleft())
+            admitted = True
+        if admitted and self.scrub_every and not self._warming:
+            self._arm_kv()  # re-arm after the admission scatter
+
+    def _admission(self, req: Request, prefix_tokens: int = 0):
+        """The ``serve/admit`` span of one admission, which starts now;
+        observes the request's queue wait (submit -> admission)."""
+        req.t_admit = time.perf_counter()
+        wait = req.t_admit - req.t_submit
+        if not self._warming:
+            obs.histogram("repro_queue_wait_seconds",
+                          "queue wait from submit to admission").observe(wait)
+        return obs.span("serve/admit", rid=req.rid, queue_wait_ms=1e3 * wait,
+                        prompt_len=len(req.prompt),
+                        prefix_tokens=prefix_tokens)
+
+    def _start_admission(self, s: int, req: Request):
+        with self._admission(req):
+            tok = self._prefill_whole(s, req)
+            self._finish_admission(s, req, tok, len(req.prompt))
+
+    def _prefill_whole(self, s: int, req: Request) -> int:
+        """Prefill the whole prompt in one compiled program into slot
+        ``s``; returns the first token."""
+        plen = len(req.prompt)
+        bucket = self._bucket(plen)
+        with obs.span("serve/prefill", rid=req.rid, bucket=bucket) as sp:
             prompt = jnp.zeros((1, bucket), jnp.int32).at[0, :plen].set(
                 jnp.asarray(req.prompt, jnp.int32))
             logits, small_cache = self._get_prefill(bucket)(
                 self.params, prompt, jnp.asarray(plen, jnp.int32))
             self._scatter_slot(s, small_cache, plen)
+            self._write_pages(s, small_cache, 0, plen)
             tok = int(jnp.argmax(logits[0, plen - 1]))
-            t1 = time.perf_counter()
-            req.output.append(tok)
-            req.t_first = t1
-            self.stats.prefills += 1
-            self.stats.prefill_s += t1 - t0
-            self.tokens = self.tokens.at[s, 0].set(tok)
-            self.pos = self.pos.at[s].set(plen)
-            self.active[s] = req
-            admitted = True
-        if admitted and self.scrub_every and not self._warming:
-            self._arm_kv()  # re-arm after the admission scatter
+        self.stats.prefill_s += sp.dur_s
+        return tok
+
+    def _finish_admission(self, s: int, req: Request, tok: int, plen: int):
+        req.output.append(tok)
+        req.t_first = time.perf_counter()
+        self.stats.prefills += 1
+        self.tokens = self.tokens.at[s, 0].set(tok)
+        self.pos = self.pos.at[s].set(plen)
+        self.active[s] = req
 
     def _prefill_impl(self, params, prompt, plen, bucket):
         cache = tf.init_cache(self.cfg, 1, self.max_len)
@@ -618,10 +659,12 @@ class ServeEngine:
             lambda p, b, sm: put(p, b, sm), self.cache, small_cache)
 
     # -- decode programs -------------------------------------------------------
-    def _decode_impl(self, params, tokens, pos, cache):
+    # the jitted programs take these names (the trace's "XLA Modules" line
+    # shows jit_serve_decode, jit_serve_decode_drill)
+    def serve_decode(self, params, tokens, pos, cache):
         return self._decode_core(params, tokens, pos, cache, None)
 
-    def _drill_impl(self, params, tokens, pos, cache, shard, delta):
+    def serve_decode_drill(self, params, tokens, pos, cache, shard, delta):
         return self._decode_core(params, tokens, pos, cache, (shard, delta))
 
     def _decode_core(self, params, tokens, pos, cache, inject):
@@ -689,27 +732,29 @@ class ServeEngine:
         if (self.scrub_every and not self._warming
                 and self.stats.decode_steps % self.scrub_every == 0):
             self._scrub_check()
-        self._pre_decode()
-        t0 = time.perf_counter()
+        n_active = sum(r is not None for r in self.active)
+        with obs.span("serve/kv_gather", active=n_active):
+            self._pre_decode()
         ev: Optional[SDCEvent] = None
-        if self.sdc is not None and not self._warming:
-            fired = self.sdc.check(self.stats.decode_steps)
-            if fired is not None:
-                shard, delta = fired
-                ev = SDCEvent(step=self.stats.decode_steps, shard=shard,
-                              delta=delta)
-        tokens, pos, cache = self._place()
-        if ev is not None:
-            logits, self.cache, ok, info = self._decode_drill(
-                self.params, tokens, pos, cache,
-                jnp.asarray(ev.shard, jnp.int32),
-                jnp.asarray(ev.delta, jnp.float32))
-        else:
-            logits, self.cache, ok, info = self._decode(
-                self.params, tokens, pos, cache)
-        next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
-        jax.block_until_ready(next_tok)
-        wall = time.perf_counter() - t0
+        with obs.span("serve/decode_step", active=n_active) as sp:
+            if self.sdc is not None and not self._warming:
+                fired = self.sdc.check(self.stats.decode_steps)
+                if fired is not None:
+                    shard, delta = fired
+                    ev = SDCEvent(step=self.stats.decode_steps, shard=shard,
+                                  delta=delta)
+            tokens, pos, cache = self._place()
+            if ev is not None:
+                logits, self.cache, ok, info = self._decode_drill(
+                    self.params, tokens, pos, cache,
+                    jnp.asarray(ev.shard, jnp.int32),
+                    jnp.asarray(ev.delta, jnp.float32))
+            else:
+                logits, self.cache, ok, info = self._decode(
+                    self.params, tokens, pos, cache)
+            next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
+            jax.block_until_ready(next_tok)
+        wall = sp.dur_s
 
         detected = self._protected and not bool(ok)
         step = self.stats.decode_steps
@@ -752,8 +797,16 @@ class ServeEngine:
             # program, so even the first detection's wall is compile-free
             obs.recovery("abft_inflight", rec, step=step, warm_s=rec,
                          compile_s=0.0, corrected=bool(info["corrected"]))
-        self._post_decode()
+        pages = self._writeback_pages(n_active)
+        with obs.span("serve/kv_writeback", pages=pages,
+                      host_reads=2 * pages):
+            self._post_decode()
+        with obs.span("serve/emit", active=n_active):
+            self._emit(next_tok, finished)
 
+    def _emit(self, next_tok, finished: List[Request]):
+        """Append each live slot's token, advance positions and retire the
+        finished requests."""
         self.pos = self.pos + jnp.asarray(
             [1 if r is not None else 0 for r in self.active], jnp.int32)
         self.tokens = next_tok[:, None]
@@ -774,9 +827,6 @@ class ServeEngine:
                                   "time to first token").observe(req.ttft_s)
                 if req.decode_tok_s is not None:
                     self.stats.tok_s.append(req.decode_tok_s)
-                    obs.gauge("repro_tokens_per_s",
-                              "per-request decode throughput").set(
-                        req.decode_tok_s)
                 obs.counter("repro_requests_total",
                             "retired serve requests").inc()
                 finished.append(req)
@@ -880,6 +930,9 @@ class PagedServeEngine(ServeEngine):
             for s, p in writes:
                 self.kv.write_token(key, s, p, x[:, s, p])
 
+    def _writeback_pages(self, n_active: int) -> int:
+        return n_active * len(self.kv.pools)
+
     def _retire_slot(self, s: int):
         self.kv.free_slot(s)
 
@@ -931,33 +984,21 @@ class PagedServeEngine(ServeEngine):
     def _start_admission(self, s: int, req: Request):
         plen = len(req.prompt)
         need = min(plen + req.max_new_tokens, self.max_len)
-        prompt = req.prompt if (self.prefix_cache
-                                and not self._warming) else None
-        start = self.kv.alloc_slot(s, need, prompt=prompt)
-        if start or (self.chunk_prefill and plen > self.chunk_prefill):
-            self._prefilling = {"slot": s, "req": req, "start": start}
-            return
-        # no prefix hit, no chunking: the parent's compiled prefill program
-        # verbatim — bit-identical admission vs the contiguous engine
-        t0 = time.perf_counter()
-        bucket = self._bucket(plen)
-        prompt_a = jnp.zeros((1, bucket), jnp.int32).at[0, :plen].set(
-            jnp.asarray(req.prompt, jnp.int32))
-        logits, small_cache = self._get_prefill(bucket)(
-            self.params, prompt_a, jnp.asarray(plen, jnp.int32))
-        self._scatter_slot(s, small_cache, plen)
-        self._write_pages(s, small_cache, 0, plen)
-        tok = int(jnp.argmax(logits[0, plen - 1]))
-        self.stats.prefill_s += time.perf_counter() - t0
-        self._finish_admission(s, req, tok, plen)
+        shared = (self.kv.lookup_prefix(req.prompt)
+                  if self.prefix_cache and not self._warming else [])
+        with self._admission(req, prefix_tokens=len(shared) * self.page_size):
+            start = self.kv.alloc_slot(s, need, shared=shared)
+            if start or (self.chunk_prefill and plen > self.chunk_prefill):
+                self._prefilling = {"slot": s, "req": req, "start": start}
+                return
+            # no prefix hit, no chunking: the parent's compiled prefill
+            # program verbatim — bit-identical admission vs the contiguous
+            # engine
+            tok = self._prefill_whole(s, req)
+            self._finish_admission(s, req, tok, plen)
 
     def _finish_admission(self, s: int, req: Request, tok: int, plen: int):
-        req.output.append(tok)
-        req.t_first = time.perf_counter()
-        self.stats.prefills += 1
-        self.tokens = self.tokens.at[s, 0].set(tok)
-        self.pos = self.pos.at[s].set(plen)
-        self.active[s] = req
+        super()._finish_admission(s, req, tok, plen)
         if self.prefix_cache and not self._warming:
             self.kv.register_prefix(s, req.prompt)
 
@@ -965,11 +1006,15 @@ class PagedServeEngine(ServeEngine):
         """Persist positions [start, end) of a 1-slot cache into pages."""
         if end <= start:
             return
-        self.kv.begin_mutation()
-        for path, x in jax.tree_util.tree_flatten_with_path(cache_tree)[0]:
-            key = jax.tree_util.keystr(path)
-            if key in self.kv.pools:
-                self.kv.write(key, s, start, x[:, 0, start:end])
+        ps = self.page_size
+        pages = len(self.kv.pools) * (-(-end // ps) - start // ps)
+        with obs.span("serve/kv_write", pages=pages, host_reads=2 * pages):
+            self.kv.begin_mutation()
+            for path, x in jax.tree_util.tree_flatten_with_path(
+                    cache_tree)[0]:
+                key = jax.tree_util.keystr(path)
+                if key in self.kv.pools:
+                    self.kv.write(key, s, start, x[:, 0, start:end])
 
     # -- chunked / prefix-shared prefill --------------------------------------
     def _slot_cache(self, s: int, start: int):
@@ -992,13 +1037,13 @@ class PagedServeEngine(ServeEngine):
 
     def _get_chunk(self, bucket: int):
         if bucket not in self._chunk_progs:
-            def fn(pr, tok, start, cache, _b=bucket):
+            def serve_prefill_chunk(pr, tok, start, cache, _b=bucket):
                 positions = start + jnp.arange(_b)
                 logits, new_cache, _ = tf.forward(
                     pr, tok, self.cfg, positions=positions, cache=cache,
                     abft=self.abft)
                 return logits, new_cache
-            self._chunk_progs[bucket] = jax.jit(fn)
+            self._chunk_progs[bucket] = jax.jit(serve_prefill_chunk)
         return self._chunk_progs[bucket]
 
     def _advance_prefill(self):
@@ -1010,18 +1055,20 @@ class PagedServeEngine(ServeEngine):
             s, req, start = pf["slot"], pf["req"], pf["start"]
             plen = len(req.prompt)
             n = min(self.chunk_prefill or plen - start, plen - start)
-            t0 = time.perf_counter()
             bucket = self._bucket(n)
-            toks = jnp.zeros((1, bucket), jnp.int32).at[0, :n].set(
-                jnp.asarray(req.prompt[start:start + n], jnp.int32))
-            cache = self._slot_cache(s, start)
-            logits, new_cache = self._get_chunk(bucket)(
-                self.params, toks, jnp.asarray(start, jnp.int32), cache)
-            # carry non-paged leaves (recurrent state) + index across chunks
-            self._scatter_slot(s, new_cache, start + n)
-            self._write_pages(s, new_cache, start, start + n)
-            pf["start"] = start + n
-            self.stats.prefill_s += time.perf_counter() - t0
+            with obs.span("serve/prefill_chunk", rid=req.rid, start=start,
+                          n=n) as sp:
+                toks = jnp.zeros((1, bucket), jnp.int32).at[0, :n].set(
+                    jnp.asarray(req.prompt[start:start + n], jnp.int32))
+                cache = self._slot_cache(s, start)
+                logits, new_cache = self._get_chunk(bucket)(
+                    self.params, toks, jnp.asarray(start, jnp.int32), cache)
+                # carry non-paged leaves (recurrent state) + index across
+                # chunks
+                self._scatter_slot(s, new_cache, start + n)
+                self._write_pages(s, new_cache, start, start + n)
+                pf["start"] = start + n
+            self.stats.prefill_s += sp.dur_s
             if pf["start"] >= plen:
                 tok = int(jnp.argmax(logits[0, n - 1]))
                 self._prefilling = None

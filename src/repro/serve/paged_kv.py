@@ -187,39 +187,39 @@ class PagedKVCache:
         self.stats.frees += 1
 
     # -- slot lifecycle --------------------------------------------------------
-    def _prefix_lookup(self, prompt: Sequence[int]) -> Tuple[tuple, List[int]]:
-        """Longest registered full-page prefix of ``prompt`` that leaves at
-        least one suffix token to prefill; ((), []) on miss."""
+    def lookup_prefix(self, prompt: Sequence[int]) -> List[int]:
+        """Physical pages of the longest registered full-page prefix of
+        ``prompt`` that leaves at least one suffix token to prefill ([] on
+        a miss); counts the hit or miss and refreshes the entry's LRU
+        place."""
         plen = len(prompt)
         for k in range((plen - 1) // self.page_size, 0, -1):
             key = tuple(prompt[:k * self.page_size])
             pages = self.prefixes.get(key)
             if pages is not None:
                 self.prefixes.move_to_end(key)
-                return key, pages
-        return (), []
-
-    def alloc_slot(self, slot: int, need_len: int,
-                   prompt: Optional[Sequence[int]] = None) -> int:
-        """Map slot ``slot`` for a sequence of up to ``need_len`` tokens:
-        shared prefix pages first (when ``prompt`` is given and hits the
-        registry), fresh pages for the rest.  Returns the shared prefix
-        length in tokens (0 on miss) — the caller prefills ``[shared, plen)``
-        only."""
-        if np.any(self.table[slot]):
-            raise RuntimeError(f"slot {slot} still holds pages — free it "
-                               "before re-admitting")
-        shared: List[int] = []
-        if prompt is not None:
-            _, shared = self._prefix_lookup(prompt)
-            if shared:
                 self.stats.prefix_hits += 1
                 obs.counter("repro_prefix_hits_total",
                             "prefix-cache page-share hits").inc()
-            else:
-                self.stats.prefix_misses += 1
-                obs.counter("repro_prefix_misses_total",
-                            "prefix-cache lookup misses").inc()
+                return pages
+        self.stats.prefix_misses += 1
+        obs.counter("repro_prefix_misses_total",
+                    "prefix-cache lookup misses").inc()
+        return []
+
+    def alloc_slot(self, slot: int, need_len: int,
+                   prompt: Optional[Sequence[int]] = None,
+                   shared: Optional[List[int]] = None) -> int:
+        """Map slot ``slot`` for a sequence of up to ``need_len`` tokens:
+        shared prefix pages first (``shared``, the pages `lookup_prefix`
+        gave the caller, else looked up here when ``prompt`` is given),
+        fresh pages for the rest.  Returns the shared prefix length in
+        tokens (0 on miss) — the caller prefills ``[shared, plen)`` only."""
+        if np.any(self.table[slot]):
+            raise RuntimeError(f"slot {slot} still holds pages — free it "
+                               "before re-admitting")
+        if shared is None:
+            shared = self.lookup_prefix(prompt) if prompt is not None else []
         need_len = min(need_len, self.max_len)
         n_logical = -(-need_len // self.page_size)  # ceil
         for i, phys in enumerate(shared[:n_logical]):

@@ -140,8 +140,6 @@ class SLOScheduler:
         self.stats.max_wait_s = max(self.stats.max_wait_s, wait)
         obs.counter("repro_sched_popped_total",
                     "requests admitted from the queue").inc()
-        obs.histogram("repro_queue_wait_seconds",
-                      "queue wait from submit to admission").observe(wait)
         obs.gauge("repro_queue_depth",
                   "scheduler queue depth").set(len(self._items))
         return e.req

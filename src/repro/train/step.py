@@ -19,7 +19,6 @@ import jax.numpy as jnp
 from jax import lax
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro import obs
 from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.abft_gemm import ABFTConfig
 from repro.dist import sharding as shd
@@ -241,11 +240,6 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
                          "the deferred manual-DP region does not thread "
                          "the invariant flags")
     cfg = _moe_cfg(cfg, mesh)
-    # build runs once per generation/compile — the obs bus pairs this
-    # stamp with the elastic runtime's measured build_s/compile_s split
-    obs.event("train/build_step", arch=cfg.name,
-              mesh={k: int(v) for k, v in mesh.shape.items()},
-              abft_mode=opts.abft_mode, abft_reduce=opts.abft_reduce)
     m = opts.microbatches
     assert shape.global_batch % max(m, 1) == 0
     bspec = shd.batch_specs(mesh, shape.global_batch // max(m, 1))

@@ -110,6 +110,105 @@ def test_subscribers_fire_even_while_disabled():
         obs.unsubscribe(sub)
 
 
+class _Recorder:
+    """An annotator that logs each annotation's opening and closing."""
+
+    def __init__(self):
+        self.log = []
+
+    def __call__(self, name, **attrs):
+        rec = self
+
+        class _Ann:
+            def __enter__(self):
+                rec.log.append(("enter", name, attrs))
+
+            def __exit__(self, exc_type, exc, tb):
+                rec.log.append(("exit", name, exc_type))
+        return _Ann()
+
+
+def test_annotator_wraps_each_span_with_name_and_attrs():
+    tr, rec = Tracer(), _Recorder()
+    tr.annotator = rec
+    with tr.span("serve/admit", rid=3, queue_wait_ms=1.5):
+        with tr.span("serve/prefill", step=4, bucket=16):
+            pass
+    assert rec.log == [
+        ("enter", "serve/admit", {"rid": 3, "queue_wait_ms": 1.5}),
+        ("enter", "serve/prefill", {"bucket": 16, "step": 4}),
+        ("exit", "serve/prefill", None),
+        ("exit", "serve/admit", None)]
+
+
+def test_annotator_exits_on_exception():
+    tr, rec = Tracer(), _Recorder()
+    tr.annotator = rec
+    with pytest.raises(RuntimeError, match="boom"):
+        with tr.span("outer", a=1):
+            raise RuntimeError("boom")
+    assert rec.log == [("enter", "outer", {"a": 1}),
+                       ("exit", "outer", RuntimeError)]
+    (ev,) = tr.events()
+    assert not ev.ok
+
+
+def test_no_annotator_calls_nothing_and_instants_stay_on_the_bus():
+    tr, rec = Tracer(), _Recorder()
+    with tr.span("x", a=1):
+        tr.event("fault/detect")
+    assert tr.annotator is None and rec.log == []
+    tr.annotator = rec
+    tr.event("fault/inject")
+    tr.recovery("scrub:restore", 0.01)
+    assert rec.log == []
+    tr.reset()
+    assert tr.annotator is rec                   # survives a reset
+
+
+def test_span_dur_s_set_on_exit_even_when_disabled():
+    tr = Tracer()
+    tr.enable(False)
+    sp = tr.span("x")
+    assert sp.dur_s is None
+    with sp:
+        pass
+    assert sp.dur_s is not None and sp.dur_s >= 0.0
+    assert tr.events() == []
+    tr.enable(True)
+    with tr.span("y") as sp:
+        pass
+    assert tr.events()[0].dur_s == sp.dur_s
+
+
+def test_profiler_install_sets_trace_annotation():
+    import jax
+    from repro.obs import profiler
+    saved = obs.TRACER.annotator
+    try:
+        profiler.install()
+        profiler.install()
+        assert obs.TRACER.annotator is jax.profiler.TraceAnnotation
+        with obs.span("serve/emit", active=2) as sp:
+            pass
+        assert sp.dur_s is not None
+    finally:
+        obs.TRACER.annotator = saved
+
+
+def test_trace_module_imports_only_the_stdlib():
+    import ast
+    import sys
+    from repro.obs import trace
+    tree = ast.parse(open(trace.__file__).read())
+    mods = {a.name.split(".")[0] for n in ast.walk(tree)
+            if isinstance(n, ast.Import) for a in n.names}
+    mods |= {n.module.split(".")[0] for n in ast.walk(tree)
+             if isinstance(n, ast.ImportFrom) and n.level == 0}
+    mods.discard("__future__")
+    assert mods <= set(sys.stdlib_module_names), mods
+
+
 def test_bounded_buffer_counts_drops():
     tr = Tracer(max_events=3)
     for i in range(5):
