@@ -590,6 +590,12 @@ class ServeEngine:
         """Persist positions [start, end) of a 1-slot prefill cache (paged
         engine; the contiguous engine's slot scatter already holds them)."""
 
+    def _paged_leaf(self, path) -> bool:
+        """Is this cache leaf stored in pages (which `_pre_decode` gathers
+        into the dense cache before every decode)?  The contiguous engine
+        pages nothing."""
+        return False
+
     def _admit(self):
         admitted = False
         for s in range(self.slots):
@@ -652,6 +658,8 @@ class ServeEngine:
             key = getattr(path[-1], "key", None)
             if key == "index":
                 return big.at[..., s].set(plen)
+            if self._paged_leaf(path):
+                return big
             # leading dims: [repeats, B(slots), ...] <- [repeats, 1, ...]
             return big.at[:, s].set(small[:, 0].astype(big.dtype))
 
@@ -799,7 +807,7 @@ class ServeEngine:
                          compile_s=0.0, corrected=bool(info["corrected"]))
         pages = self._writeback_pages(n_active)
         with obs.span("serve/kv_writeback", pages=pages,
-                      host_reads=2 * pages):
+                      host_reads=min(pages, 1)):
             self._post_decode()
         with obs.span("serve/emit", active=n_active):
             self._emit(next_tok, finished)
@@ -810,15 +818,17 @@ class ServeEngine:
         self.pos = self.pos + jnp.asarray(
             [1 if r is not None else 0 for r in self.active], jnp.int32)
         self.tokens = next_tok[:, None]
+        # one device-to-host read for every slot's token and position
+        toks, pos = jax.device_get((next_tok, self.pos))
         now = time.perf_counter()
         for s, req in enumerate(self.active):
             if req is None:
                 continue
-            tok = int(next_tok[s])
+            tok = int(toks[s])
             req.output.append(tok)
             hit_eos = req.eos_id is not None and tok == req.eos_id
             if len(req.output) >= req.max_new_tokens or hit_eos \
-                    or int(self.pos[s]) >= self.max_len - 1:
+                    or pos[s] >= self.max_len - 1:
                 req.done = True
                 req.t_done = now
                 if req.ttft_s is not None:
@@ -914,21 +924,26 @@ class PagedServeEngine(ServeEngine):
             leaves.append(self.kv.gather(key) if key in self.kv.pools else x)
         self.cache = jax.tree_util.tree_unflatten(treedef, leaves)
 
+    def _paged_leaf(self, path) -> bool:
+        return jax.tree_util.keystr(path) in self.kv.pools
+
+    def _paged_leaves(self, tree) -> Dict[str, jax.Array]:
+        return {jax.tree_util.keystr(path): x for path, x in
+                jax.tree_util.tree_flatten_with_path(tree)[0]
+                if self._paged_leaf(path)}
+
     def _post_decode(self):
         # page-granular write-back + re-arm: ONE page per leaf per active
-        # slot (the decode wrote exactly position pos[s])
-        writes = [(s, int(p)) for s, (r, p) in
-                  enumerate(zip(self.active, np.asarray(self.pos)))
-                  if r is not None]
+        # slot (the decode wrote exactly position pos[s] = prompt + output
+        # - 1), all slots and leaves in one batch padded to `slots`
+        writes = [(s, p, 1, s, p) for s, p in
+                  ((s, len(r.prompt) + len(r.output) - 1)
+                   for s, r in enumerate(self.active) if r is not None)]
         if not writes:
             return
         self.kv.begin_mutation()
-        for path, x in jax.tree_util.tree_flatten_with_path(self.cache)[0]:
-            key = jax.tree_util.keystr(path)
-            if key not in self.kv.pools:
-                continue
-            for s, p in writes:
-                self.kv.write_token(key, s, p, x[:, s, p])
+        self.kv.write_batch(self._paged_leaves(self.cache), writes,
+                            kind="decode", pad_to=self.slots)
 
     def _writeback_pages(self, n_active: int) -> int:
         return n_active * len(self.kv.pools)
@@ -1008,13 +1023,13 @@ class PagedServeEngine(ServeEngine):
             return
         ps = self.page_size
         pages = len(self.kv.pools) * (-(-end // ps) - start // ps)
-        with obs.span("serve/kv_write", pages=pages, host_reads=2 * pages):
+        with obs.span("serve/kv_write", pages=pages, host_reads=1):
             self.kv.begin_mutation()
-            for path, x in jax.tree_util.tree_flatten_with_path(
-                    cache_tree)[0]:
-                key = jax.tree_util.keystr(path)
-                if key in self.kv.pools:
-                    self.kv.write(key, s, start, x[:, 0, start:end])
+            # padded to the prefill's own bucket: warmed with its program
+            self.kv.write_batch(self._paged_leaves(cache_tree),
+                                [(s, start, end - start, 0, start)],
+                                kind="prefill",
+                                pad_to=self._bucket(end - start))
 
     # -- chunked / prefix-shared prefill --------------------------------------
     def _slot_cache(self, s: int, start: int):

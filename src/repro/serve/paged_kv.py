@@ -1,7 +1,8 @@
 """Paged (block) KV cache: fixed-size pages as the unified protection unit.
 
 vLLM-style memory layout for the serving engines: every float KV-cache
-leaf gets a page pool ``[repeats, n_pages, page_size, *tail]`` and all
+leaf gets a page pool ``[n_pages, page_size, repeats, features]`` (the
+leaf's tail flattened into features) and all
 leaves share ONE host-side page table ``[slots, pages_per_slot]`` mapping
 each slot's logical pages onto physical pages.  Physical page 0 is the
 reserved immutable **zero page** — unallocated table entries point at it,
@@ -14,7 +15,7 @@ per-slot fingerprints from PR 6 whose scrub unit was the whole slot:
   * **checksum-on-write, page granular** — every mutation re-arms exactly
     the pages it touched: a per-(leaf, page) float64 scalar fingerprint
     (detect + locate) and a per-leaf float64 *elementwise* page sum
-    ``esum[r, o, *tail] = sum_p pool[r, p, o, *tail]`` (the erasure row
+    ``esum[o, r, f] = sum_p pool[p, o, r, f]`` (the erasure row
     that repairs).  The engine's decode writes ONE token per slot per
     step, so the incremental update is always ``+= new`` — the write-once
     invariant (cells are zero until their first and only write between
@@ -36,6 +37,25 @@ already consistent) and the pool's free list + live refcounts conserve
 the pool exactly — `tests/test_paged_kv.py` drives random
 admit/decode/evict/free traces against these invariants.
 
+Writes and frees are batched: the page-table work (allocation,
+copy-on-write, the re-arm ledger) runs on the host first, then ONE
+compiled program (`serve_kv_write`, `serve_kv_zero`) with the pools
+donated updates every leaf in place and returns the written and replaced
+values, which come home in ONE device-to-host read for the float64
+checksum update.  Batches are padded to a fixed size per caller (decode:
+slots, prefill: the prompt bucket, free: pages per slot) so that a warmed
+engine compiles nothing new; padding entries name an out-of-range page,
+which the gathers fill with 0 and the scatters drop.
+
+The pools are page-major with the tail flattened because of the TPU's
+tiled layouts: in the dense order ``[repeats, n_pages, page_size, 2, 64]``
+the compiler lays the page axis innermost (a 64-wide minor dimension
+would be padded to 128), and every page update, zeroing or gather then
+re-lays the whole pool, twice (on a v5e, about 1 GB of copies per 0.5 GB
+pool).  ``[n_pages, page_size, repeats, features]`` keeps whole pages
+contiguous, so a write is an in-place scatter of ``[repeats, features]``
+rows.
+
 Decode parity: the dense cache `gather()` materializes differs from the
 contiguous engine's only at causally-masked positions (zeros here, prefill
 pad garbage there); `_sdpa_dense` masks with ``where(mask, s, NEG_INF)``
@@ -46,7 +66,10 @@ way and the paged engine's decode logits are bit-identical
 from __future__ import annotations
 
 import dataclasses
+import functools
+import os
 from collections import OrderedDict
+from concurrent.futures import ThreadPoolExecutor
 from typing import Dict, List, Optional, Sequence, Tuple
 
 import jax
@@ -85,6 +108,85 @@ class PagedStats:
     checksum_rearms: int = 0      # one per (leaf, page) checksum update
     verifies: int = 0
     repairs: int = 0
+    # compiled write programs run, by kind
+    write_batches: Dict[str, int] = dataclasses.field(
+        default_factory=lambda: {"decode": 0, "prefill": 0, "free": 0})
+
+
+_PAD = np.iinfo(np.int32).max   # index of a padding entry: out of range
+
+
+def _rows_at(src, pos):
+    """``src[:, s, pos[s]]`` for every slot s, ``[repeats, B, *tail]``: the
+    bit pattern at each slot's position, selected over the whole sequence
+    axis.  This reads the cache in the layout the decode program left it
+    in, where a gather would first re-lay all of it."""
+    uint = jnp.dtype(f"uint{8 * src.dtype.itemsize}")
+    hit = jnp.arange(src.shape[2])[None, None, :] == pos[None, :, None]
+    hit = hit.reshape(hit.shape + (1,) * (src.ndim - 3))
+    bits = jnp.where(hit, jax.lax.bitcast_convert_type(src, uint), 0)
+    return jax.lax.bitcast_convert_type(jnp.max(bits, axis=2), src.dtype)
+
+
+def _page_sums(pages: np.ndarray, ex: ThreadPoolExecutor) -> np.ndarray:
+    """Float64 sum of each page of a host pool ``[n_pages, ...]``: values
+    widened exactly to float32, summed in float64, blocks of pages on the
+    executor's threads (NumPy leaves the interpreter lock in its loops)."""
+    rows = pages.reshape(len(pages), -1)
+    cuts = np.linspace(0, len(rows), 32 + 1).astype(int)    # 32 blocks
+    return np.concatenate(list(ex.map(
+        lambda a, b: rows[a:b].astype(np.float32, copy=False).sum(
+            axis=1, dtype=np.float64), cuts[:-1], cuts[1:])))
+
+
+# the jitted programs take these names (the trace's "XLA Modules" line
+# shows jit_serve_kv_write, jit_serve_kv_zero, jit_serve_kv_gather)
+@functools.partial(jax.jit, donate_argnums=0)
+def serve_kv_write(pools, srcs, idx):
+    """One batch of token writes into every pool, in place.
+
+    ``idx`` is ``[4, N]`` int32: token i goes from ``src[:, idx[0, i],
+    idx[1, i]]`` (a dense ``[repeats, B, len, *tail]`` source; when B > 1,
+    at most one token per slot) to ``pool[idx[2, i], idx[3, i]]``
+    (physical page, offset).  Returns the pools, the values written and
+    the values they replaced, each ``[N, repeats, features]``."""
+    src_slot, src_pos, phys, off = idx
+    new_pools, news, olds = [], [], []
+    for pool, src in zip(pools, srcs):
+        if src.shape[1] == 1:
+            rows = src[:, 0, src_pos]
+        else:
+            pos = jnp.zeros(src.shape[1], jnp.int32).at[src_slot].set(
+                src_pos, mode="drop")
+            rows = jnp.take(_rows_at(src, pos), src_slot, axis=1,
+                            mode="fill", fill_value=0)
+        new = jnp.moveaxis(rows, 1, 0).reshape(
+            (len(phys),) + pool.shape[2:]).astype(pool.dtype)
+        olds.append(pool.at[phys, off].get(mode="fill", fill_value=0))
+        new_pools.append(pool.at[phys, off].set(new, mode="drop"))
+        news.append(new)
+    return tuple(new_pools), tuple(news), tuple(olds)
+
+
+@functools.partial(jax.jit, donate_argnums=0)
+def serve_kv_zero(pools, phys):
+    """Zero pages ``phys`` ``[P]`` of every pool in place; returns the
+    pools and the pages' previous contents ``[P, page, repeats,
+    features]``."""
+    olds = tuple(pool.at[phys].get(mode="fill", fill_value=0)
+                 for pool in pools)
+    return tuple(pool.at[phys].set(0, mode="drop")
+                 for pool in pools), olds
+
+
+@functools.partial(jax.jit, static_argnums=2)
+def serve_kv_gather(pool, table, tail):
+    """Dense ``[repeats, B, len, *tail]`` view of the pages ``table``
+    ``[B, pages]`` maps."""
+    b, n = table.shape
+    _, ps, r, _ = pool.shape
+    dense = pool[table.reshape(-1)].reshape(b, n * ps, r, -1)
+    return jnp.moveaxis(dense, 2, 0).reshape((r, b, n * ps) + tail)
 
 
 class PagedKVCache:
@@ -92,7 +194,8 @@ class PagedKVCache:
 
     ``leaf_shapes`` maps a leaf key (the engine uses jax keystr paths) to
     ``(dense_shape, dtype)`` where dense_shape is the contiguous layout
-    ``[repeats, slots, max_len, *tail]`` the leaf would occupy.
+    ``[repeats, slots, max_len, *tail]`` the leaf would occupy; its pages
+    live in ``pools[key]`` ``[n_pages, page_size, repeats, features]``.
     """
 
     def __init__(self, leaf_shapes: Dict[str, Tuple[Sequence[int], object]],
@@ -120,7 +223,8 @@ class PagedKVCache:
                     f"*tail], got {shape}")
             repeats, tail = shape[0], shape[3:]
             self.pools[key] = jnp.zeros(
-                (repeats, self.n_pages, page_size) + tail, dtype)
+                (self.n_pages, page_size, repeats, int(np.prod(tail))),
+                dtype)
             self._tails[key] = tail
 
         # ONE table shared by every leaf: logical -> physical page ids
@@ -136,8 +240,8 @@ class PagedKVCache:
         self.page_fp: Dict[str, np.ndarray] = {
             key: np.zeros((self.n_pages,), np.float64) for key in self.pools}
         self.esum: Dict[str, np.ndarray] = {
-            key: np.zeros((p.shape[0], page_size) + self._tails[key],
-                          np.float64) for key, p in self.pools.items()}
+            key: np.zeros(p.shape[1:], np.float64)
+            for key, p in self.pools.items()}
         self.last_rearmed: List[Tuple[str, int]] = []
 
     # -- bookkeeping helpers ---------------------------------------------------
@@ -158,8 +262,7 @@ class PagedKVCache:
         while not self.free and self.prefixes:
             key, pages = self.prefixes.popitem(last=False)
             self.stats.prefix_evictions += 1
-            for p in pages:
-                self._deref(p)
+            self._release(pages)
         if not self.free:
             raise RuntimeError("page pool exhausted (no free or evictable "
                                "pages) — admission control must defer")
@@ -168,23 +271,41 @@ class PagedKVCache:
         self.stats.allocs += 1
         return phys
 
-    def _deref(self, phys: int):
-        if phys == 0:
-            return  # the zero page is immortal
-        self.refcount[phys] -= 1
-        if self.refcount[phys] > 0:
+    def _release(self, pages: Sequence[int]):
+        """Drop one reference to each of ``pages``; those left unreferenced
+        are zeroed in one batch and return to the free list.  Zero-at-free
+        keeps "free page == zero page contents == zero checksum
+        contribution": allocation needs no work and a corrupted free page
+        is detectable (its fingerprint must stay 0).  A freed page's
+        contents leave ``esum`` as they stand, corrupted or not."""
+        dead = []
+        for phys in pages:
+            if phys == 0:
+                continue  # the zero page is immortal
+            self.refcount[phys] -= 1
+            if self.refcount[phys] == 0:
+                dead.append(phys)
+        if not dead:
             return
-        # zero-at-free keeps "free page == zero page contents == zero
-        # checksum contribution": allocation needs no work and a corrupted
-        # free page is detectable (its fingerprint must stay 0)
-        for key, pool in self.pools.items():
-            page64 = np.asarray(pool[:, phys], np.float64)
-            if np.any(page64):
-                self.esum[key] -= page64
-                self.pools[key] = pool.at[:, phys].set(0)
-            self.page_fp[key][phys] = 0.0
-        self.free.append(phys)
-        self.stats.frees += 1
+        n = len(dead)
+        idx = np.full(-(-n // self.pages_per_slot) * self.pages_per_slot,
+                      _PAD, np.int32)
+        idx[:n] = dead
+        keys = list(self.pools)
+        pools, olds = serve_kv_zero(tuple(self.pools.values()),
+                                    jnp.asarray(idx))
+        self.pools = dict(zip(keys, pools))
+        for key, old in zip(keys, jax.device_get(olds)):
+            self.esum[key] -= old[:n].astype(np.float64).sum(axis=0)
+            self.page_fp[key][dead] = 0.0
+        self.free.extend(dead)
+        self.stats.frees += n
+        self._count_batch("free")
+
+    def _count_batch(self, kind: str):
+        self.stats.write_batches[kind] += 1
+        obs.counter("repro_kv_write_batches_total",
+                    "compiled paged-KV write programs run").inc(kind=kind)
 
     # -- slot lifecycle --------------------------------------------------------
     def lookup_prefix(self, prompt: Sequence[int]) -> List[int]:
@@ -230,11 +351,9 @@ class PagedKVCache:
         return len(shared[:n_logical]) * self.page_size
 
     def free_slot(self, slot: int):
-        for i in range(self.pages_per_slot):
-            phys = int(self.table[slot, i])
-            if phys:
-                self.table[slot, i] = 0
-                self._deref(phys)
+        pages = [int(p) for p in self.table[slot] if p]
+        self.table[slot] = 0
+        self._release(pages)
 
     def register_prefix(self, slot: int, prompt: Sequence[int]):
         """After a slot's prompt is fully prefilled, publish its full pages
@@ -256,8 +375,7 @@ class PagedKVCache:
         while len(self.prefixes) > self.max_prefixes:
             _, old = self.prefixes.popitem(last=False)
             self.stats.prefix_evictions += 1
-            for p in old:
-                self._deref(p)
+            self._release(old)
 
     # -- writes (checksum-on-write, page granular) -----------------------------
     def _writable(self, slot: int, logical: int) -> int:
@@ -272,50 +390,80 @@ class PagedKVCache:
         if self.refcount[phys] > 1:
             new = self._alloc()
             for key, pool in self.pools.items():
-                page = pool[:, phys]
+                page = pool[phys]
                 page64 = np.asarray(page, np.float64)
-                self.pools[key] = pool.at[:, new].set(page)
+                self.pools[key] = pool.at[new].set(page)
                 self.esum[key] += page64
                 self.page_fp[key][new] = float(page64.sum())
                 self.last_rearmed.append((key, new))
                 self.stats.checksum_rearms += 1
             self.table[slot, logical] = new
-            self._deref(phys)
+            self._release([phys])
             self.stats.cow_copies += 1
             return new
         return phys
 
-    def write(self, key: str, slot: int, start: int, vals):
-        """Write ``vals`` ``[repeats, n, *tail]`` at positions
-        ``[start, start + n)`` of ``slot``, re-arming exactly the touched
-        pages' checksums.  The update is incremental and O(segment):
-        ``+= new - old`` (``old`` is zero on the engine's write-once path —
-        cells stay zero between free/zero cycles — but a copy-on-write
-        overwrite of copied prefix content stays consistent too)."""
-        pool = self.pools[key]
-        vals = jnp.asarray(vals, pool.dtype)
-        n = vals.shape[1]
-        ps = self.page_size
-        pos = start
-        while pos < start + n:
-            logical, off = pos // ps, pos % ps
-            seg_n = min(ps - off, start + n - pos)
-            phys = self._writable(slot, logical)
-            seg = vals[:, pos - start:pos - start + seg_n]
-            old64 = np.asarray(
-                self.pools[key][:, phys, off:off + seg_n], np.float64)
-            self.pools[key] = self.pools[key].at[
-                :, phys, off:off + seg_n].set(seg)
-            seg64 = np.asarray(seg, np.float64)
-            self.page_fp[key][phys] += float(seg64.sum() - old64.sum())
-            self.esum[key][:, off:off + seg_n] += seg64 - old64
-            self.last_rearmed.append((key, phys))
-            self.stats.checksum_rearms += 1
-            pos += seg_n
+    def write_batch(self, srcs: Dict[str, jax.Array],
+                    writes: Sequence[Tuple[int, int, int, int, int]], *,
+                    kind: str, pad_to: int = 0):
+        """Write positions ``[start, start + n)`` of ``slot`` for every
+        ``(slot, start, n, src_slot, src_start)`` in ``writes`` and every
+        leaf in ``srcs`` (dense ``[repeats, B, len, *tail]`` sources; token
+        ``start + i`` comes from ``src[:, src_slot, src_start + i]``), as
+        one compiled scatter padded to ``pad_to`` tokens.
 
-    def write_token(self, key: str, slot: int, pos: int, val):
-        """One decode token: ``val`` ``[repeats, *tail]`` at ``pos``."""
-        self.write(key, slot, pos, jnp.asarray(val)[:, None])
+        The page-table work runs first, in the order of a per-(leaf,
+        segment) loop: allocation on demand, copy-on-write, one re-arm per
+        (leaf, page) touched.  The checksum update is incremental,
+        ``+= new - old`` in float64, from one device-to-host read (``old``
+        is zero on the engine's write-once path — cells stay zero between
+        free/zero cycles — but a copy-on-write overwrite of copied prefix
+        content stays consistent too)."""
+        writes = [w for w in writes if w[2] > 0]
+        if not writes:
+            return
+        ps = self.page_size
+        keys = list(srcs)
+        if any(srcs[k].shape[1] > 1 for k in keys) and (
+                any(w[2] != 1 for w in writes)
+                or len({w[3] for w in writes}) < len(writes)):
+            raise ValueError("a source of more than one slot gives at most "
+                             "one token per slot")
+        for key in keys:
+            for slot, start, n, _, _ in writes:
+                for logical in range(start // ps, -(-(start + n) // ps)):
+                    phys = self._writable(slot, logical)
+                    self.last_rearmed.append((key, phys))
+                    self.stats.checksum_rearms += 1
+        slot = np.concatenate([np.full(w[2], w[0]) for w in writes])
+        pos = np.concatenate([np.arange(w[1], w[1] + w[2]) for w in writes])
+        n = len(pos)
+        idx = np.zeros((4, max(n, pad_to)), np.int32)
+        idx[(0, 2), n:] = _PAD
+        idx[0, :n] = np.concatenate([np.full(w[2], w[3]) for w in writes])
+        idx[1, :n] = np.concatenate([np.arange(w[4], w[4] + w[2])
+                                     for w in writes])
+        idx[2, :n] = phys = self.table[slot, pos // ps]
+        idx[3, :n] = off = pos % ps
+        pools, news, olds = serve_kv_write(
+            tuple(self.pools[k] for k in keys),
+            tuple(srcs[k] for k in keys), jnp.asarray(idx))
+        self.pools.update(zip(keys, pools))
+        for key, new, old in zip(keys, *jax.device_get((news, olds))):
+            d = new[:n].astype(np.float64) - old[:n].astype(np.float64)
+            self.page_fp[key] += np.bincount(
+                phys, d.reshape(n, -1).sum(axis=1), minlength=self.n_pages)
+            esum = self.esum[key]
+            for o in np.unique(off):
+                esum[o] += d[off == o].sum(axis=0)
+        self._count_batch(kind)
+
+    def write(self, key: str, slot: int, start: int, vals):
+        """Write ``vals`` ``[repeats, n, *tail]`` of leaf ``key`` at
+        positions ``[start, start + n)`` of ``slot`` (one batch)."""
+        vals = jnp.asarray(vals, self.pools[key].dtype)
+        self.write_batch({key: vals[:, None]},
+                         [(slot, start, vals.shape[1], 0, 0)], kind="prefill")
 
     def begin_mutation(self):
         """Reset the per-mutation re-arm ledger (test hook: asserts a
@@ -326,27 +474,21 @@ class PagedKVCache:
     def gather(self, key: str) -> jax.Array:
         """Dense ``[repeats, slots, max_len, *tail]`` view of every slot
         (zero beyond each write head — the zero page)."""
-        pool = self.pools[key]
-        flat = jnp.asarray(self.table.reshape(-1), jnp.int32)
-        dense = jnp.take(pool, flat, axis=1)
-        r, tail = pool.shape[0], pool.shape[3:]
-        return dense.reshape((r, self.slots, self.max_len) + tail)
+        return serve_kv_gather(self.pools[key], jnp.asarray(self.table),
+                               self._tails[key])
 
     def gather_slot(self, key: str, slot: int) -> jax.Array:
-        pool = self.pools[key]
-        flat = jnp.asarray(self.table[slot], jnp.int32)
-        dense = jnp.take(pool, flat, axis=1)
-        r, tail = pool.shape[0], pool.shape[3:]
-        return dense.reshape((r, 1, self.max_len) + tail)
+        return serve_kv_gather(self.pools[key],
+                               jnp.asarray(self.table[slot:slot + 1]),
+                               self._tails[key])
 
     # -- verify / repair (the scrub + DRAM-recovery unit) ----------------------
     def arm_all(self):
         """Full recompute of every checksum from the pools (init/reset)."""
         for key, pool in self.pools.items():
             p64 = np.asarray(pool, np.float64)
-            self.page_fp[key] = p64.sum(
-                axis=tuple(i for i in range(p64.ndim) if i != 1))
-            self.esum[key] = p64.sum(axis=1)
+            self.page_fp[key] = p64.reshape(self.n_pages, -1).sum(axis=1)
+            self.esum[key] = p64.sum(axis=0)
 
     def verify(self) -> List[Tuple[str, int]]:
         """Recompute page fingerprints; returns tripped (leaf, page) pairs.
@@ -354,9 +496,10 @@ class PagedKVCache:
         (fingerprint must be 0) trips too, protecting zero-at-free."""
         self.stats.verifies += 1
         tripped = []
-        for key, pool in self.pools.items():
-            p64 = np.asarray(pool, np.float64)
-            fp = p64.sum(axis=tuple(i for i in range(p64.ndim) if i != 1))
+        pools = jax.device_get(self.pools)       # one read for every leaf
+        with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as ex:
+            sums = {key: _page_sums(pages, ex) for key, pages in pools.items()}
+        for key, fp in sums.items():
             armed = self.page_fp[key]
             diff = np.abs(fp - armed)
             # a flip into the NaN pattern poisons the page sum; NaN
@@ -376,10 +519,10 @@ class PagedKVCache:
         others = [p for p in self.live_pages() if p != phys]
         recon = self.esum[key].copy()
         if others:
-            recon -= np.asarray(pool[:, np.asarray(others)],
-                                np.float64).sum(axis=1)
-        self.pools[key] = pool.at[:, phys].set(
-            jnp.asarray(recon.astype(np.asarray(pool).dtype)))
+            recon -= np.asarray(pool[np.asarray(others)],
+                                np.float64).sum(axis=0)
+        self.pools[key] = pool.at[phys].set(
+            jnp.asarray(recon.astype(pool.dtype)))
         self.page_fp[key][phys] = float(recon.sum())
         self.last_rearmed.append((key, phys))
         self.stats.checksum_rearms += 1
@@ -402,7 +545,7 @@ class PagedKVCache:
         """Fault-injection helper: flip one bit of page ``phys`` (float32
         pools; other dtypes get an additive 1e4 delta at ``index``)."""
         pool = self.pools[key]
-        page = pool[:, phys]
+        page = pool[phys]
         if page.dtype == jnp.float32:
             from repro.chaos.faults import flip_bit
             page = flip_bit(page, index, bit)
@@ -410,7 +553,7 @@ class PagedKVCache:
             flat = page.reshape(-1)
             page = flat.at[index].add(
                 jnp.asarray(1e4, page.dtype)).reshape(page.shape)
-        self.pools[key] = pool.at[:, phys].set(page)
+        self.pools[key] = pool.at[phys].set(page)
 
     # -- invariants (property-test hooks) --------------------------------------
     def check_invariants(self):
@@ -446,11 +589,10 @@ class PagedKVCache:
                 f"page {phys} referenced by slots {sorted(ss)} without a "
                 "prefix-registry entry (non-prefix sharing)")
         for key, pool in self.pools.items():
-            assert not np.any(np.asarray(pool[:, 0])), \
-                f"zero page of {key!r} was written"
-            for phys in free:
-                assert not np.any(np.asarray(pool[:, phys])), \
-                    f"free page {phys} of {key!r} is not zero"
+            pages = np.asarray(pool)
+            assert not np.any(pages[0]), f"zero page of {key!r} was written"
+            dirty = [p for p in sorted(free) if np.any(pages[p])]
+            assert not dirty, f"free pages {dirty} of {key!r} are not zero"
 
     def checksums_consistent(self, rtol: float = 1e-6) -> bool:
         """True when every armed checksum matches a recompute (every page
@@ -459,7 +601,7 @@ class PagedKVCache:
             return False
         for key, pool in self.pools.items():
             p64 = np.asarray(pool, np.float64)
-            if not np.allclose(p64.sum(axis=1), self.esum[key],
+            if not np.allclose(p64.sum(axis=0), self.esum[key],
                                rtol=rtol, atol=1e-8):
                 return False
         return True

@@ -13,6 +13,7 @@ The random-trace drivers below are always-on (seeded numpy); when
 hypothesis is installed the same state machine also runs under generated
 traces (guarded import — hypothesis is optional in this environment).
 """
+import jax.numpy as jnp
 import numpy as np
 import pytest
 
@@ -75,7 +76,7 @@ def test_single_page_write_dirties_exactly_one_checksum(rs):
     fp_before = {key: kv.page_fp[key].copy() for key in kv.pools}
 
     kv.begin_mutation()
-    kv.write_token("k", 0, 10, fill(rs, 1)[:, 0])
+    kv.write("k", 0, 10, fill(rs, 1))
     assert len(kv.last_rearmed) == 1, (
         f"single-page write re-armed {kv.last_rearmed}")
     (leaf, phys), = kv.last_rearmed
@@ -135,13 +136,13 @@ def test_copy_on_write_unshares(rs):
     kv.register_prefix(0, prompt)
     kv.alloc_slot(1, 2 * PS + 2, prompt=prompt)
     shared = kv.page_of(1, 0)
-    before = np.asarray(kv.pools["k"][:, kv.page_of(0, 0)]).copy()
+    before = np.asarray(kv.pools["k"][kv.page_of(0, 0)]).copy()
 
     kv.write("k", 1, 0, fill(rs, 2))    # write INTO the shared page
     assert kv.stats.cow_copies == 1
     assert kv.page_of(1, 0) != shared, "write must unshare first"
     np.testing.assert_array_equal(
-        np.asarray(kv.pools["k"][:, kv.page_of(0, 0)]), before,
+        np.asarray(kv.pools["k"][kv.page_of(0, 0)]), before,
         err_msg="slot 0's view of the shared page changed")
     ok(kv)
 
@@ -152,7 +153,7 @@ def test_corrupt_verify_locates_repair_exact(rs):
     kv.write("k", 0, 0, fill(rs, 24))
     kv.write("v", 0, 0, fill(rs, 24))
     target = kv.page_of(0, PS)          # a middle live page
-    golden = np.asarray(kv.pools["k"][:, target]).copy()
+    golden = np.asarray(kv.pools["k"][target]).copy()
 
     kv.corrupt_page("k", target, bit=30)
     tripped = kv.verify()
@@ -160,7 +161,7 @@ def test_corrupt_verify_locates_repair_exact(rs):
         f"verify must locate exactly the corrupted page, got {tripped}")
     assert kv.repair("k", target)
     np.testing.assert_array_equal(
-        np.asarray(kv.pools["k"][:, target]), golden,
+        np.asarray(kv.pools["k"][target]), golden,
         err_msg="erasure repair must rebuild the page exactly")
     ok(kv)
 
@@ -174,7 +175,7 @@ def test_corrupted_free_page_detected_and_rebuilt_to_zero(rs):
     assert ("k", free_page) in kv.verify(), \
         "zero-at-free: a corrupted free page must trip"
     kv.repair("k", free_page)
-    assert not np.any(np.asarray(kv.pools["k"][:, free_page]))
+    assert not np.any(np.asarray(kv.pools["k"][free_page]))
     ok(kv)
 
 
@@ -183,7 +184,7 @@ def test_nan_poisoned_page_trips(rs):
     kv.alloc_slot(0, 8)
     kv.write("k", 0, 0, fill(rs, 8))
     phys = kv.page_of(0, 0)
-    kv.pools["k"] = kv.pools["k"].at[0, phys, 0, 0].set(np.nan)
+    kv.pools["k"] = kv.pools["k"].at[phys, 0, 0, 0].set(np.nan)
     assert ("k", phys) in kv.verify(), "NaN must not compare as clean"
 
 
@@ -253,7 +254,7 @@ def _drive_trace(ops, rs):
                 continue
             kv.begin_mutation()
             for key in kv.pools:
-                kv.write_token(key, s, slot_pos[s], fill(rs, 1)[:, 0])
+                kv.write(key, s, slot_pos[s], fill(rs, 1))
             # one page checksum per leaf per token — the scrub-unit fix
             assert len(kv.last_rearmed) == len(kv.pools)
             assert len({k for k, _ in kv.last_rearmed}) == len(kv.pools)
@@ -304,3 +305,272 @@ if HAVE_HYPOTHESIS:
            st.integers(0, 2 ** 31 - 1))
     def test_hypothesis_trace_invariants(ops, seed):
         _drive_trace(ops, np.random.RandomState(seed))
+
+
+# ---------------------------------------------------------------------------
+# batched writes against the per-segment eager path
+# ---------------------------------------------------------------------------
+
+
+class EagerKV(PagedKVCache):
+    """The reference: every (leaf, page segment) written by its own eager
+    scatter with two blocking reads, every freed page zeroed on its own —
+    the write path before writes were batched into one program."""
+
+    def write_batch(self, srcs, writes, *, kind, pad_to=0):
+        ps = self.page_size
+        for key, src in srcs.items():
+            for slot, start, n, src_slot, src_start in writes:
+                pos = start
+                while pos < start + n:
+                    logical, off = pos // ps, pos % ps
+                    seg_n = min(ps - off, start + n - pos)
+                    phys = self._writable(slot, logical)
+                    a = src_start + pos - start
+                    pool = self.pools[key]
+                    seg = jnp.moveaxis(src[:, src_slot, a:a + seg_n], 1, 0)
+                    seg = seg.reshape((seg_n,) + pool.shape[2:]).astype(
+                        pool.dtype)
+                    old64 = np.asarray(pool[phys, off:off + seg_n],
+                                       np.float64)
+                    self.pools[key] = pool.at[phys, off:off + seg_n].set(seg)
+                    seg64 = np.asarray(seg, np.float64)
+                    self.page_fp[key][phys] += float(seg64.sum()
+                                                     - old64.sum())
+                    self.esum[key][off:off + seg_n] += seg64 - old64
+                    self.last_rearmed.append((key, phys))
+                    self.stats.checksum_rearms += 1
+                    pos += seg_n
+
+    def _release(self, pages):
+        for phys in pages:
+            if phys == 0:
+                continue
+            self.refcount[phys] -= 1
+            if self.refcount[phys] > 0:
+                continue
+            for key, pool in self.pools.items():
+                page64 = np.asarray(pool[phys], np.float64)
+                if np.any(page64):
+                    self.esum[key] -= page64
+                    self.pools[key] = pool.at[phys].set(0)
+                self.page_fp[key][phys] = 0.0
+            self.free.append(phys)
+            self.stats.frees += 1
+
+
+def _bits(x):
+    x = np.asarray(x)
+    return x.view(np.uint16 if x.itemsize == 2 else np.uint32)
+
+
+def _assert_same(batched, eager):
+    for key in batched.pools:
+        np.testing.assert_array_equal(_bits(batched.pools[key]),
+                                      _bits(eager.pools[key]), err_msg=key)
+        for name in ("page_fp", "esum"):
+            got, want = getattr(batched, name)[key], getattr(eager, name)[key]
+            scale = float(np.max(np.abs(want), initial=0.0))
+            np.testing.assert_allclose(got, want, rtol=1e-12,
+                                       atol=1e-12 * scale, err_msg=name)
+    assert batched.last_rearmed == eager.last_rearmed
+    for f in ("checksum_rearms", "allocs", "frees", "cow_copies",
+              "prefix_evictions"):
+        assert getattr(batched.stats, f) == getattr(eager.stats, f), f
+    np.testing.assert_array_equal(batched.table, eager.table)
+    np.testing.assert_array_equal(batched.refcount, eager.refcount)
+    assert batched.free == eager.free
+
+
+EQ_OPS = ("admit", "admit", "decode", "decode", "decode", "cow", "free",
+          "corrupt_free")
+
+
+def _payload(rs, shape, dtype):
+    if dtype == np.float32:
+        return rs.randint(1, 8, size=shape).astype(np.float32)
+    return rs.standard_normal(shape).astype(np.float32)
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("seed", [0, 1, 2])
+def test_batched_writes_match_eager_segments(seed, dtype):
+    """A random admit / chunked prefill / decode / copy-on-write / free /
+    prefix-eviction trace through both paths: pools bit-identical,
+    checksums equal to 1e-12, the same re-arm ledger and page table; the
+    chunked prefills start mid-page, cross page boundaries and end on
+    partial pages."""
+    dt = np.float32 if dtype == "float32" else jnp.bfloat16
+    shape = (2, SLOTS, MAX_LEN, 4)
+    kvs = [cls({"k": (shape, dt), "v": (shape, dt)}, slots=SLOTS,
+               max_len=MAX_LEN, page_size=PS, max_prefixes=2)
+           for cls in (PagedKVCache, EagerKV)]
+    rs = np.random.RandomState(seed)
+    heads = {}
+    seen = set()
+
+    def both(fn):
+        for kv in kvs:
+            kv.begin_mutation()
+            fn(kv)
+
+    def write(srcs, writes, pad_to):
+        srcs = {k: jnp.asarray(v, dt) for k, v in srcs.items()}
+        both(lambda kv: kv.write_batch(srcs, writes, kind="prefill",
+                                       pad_to=pad_to))
+
+    for _ in range(80):
+        op = EQ_OPS[rs.randint(len(EQ_OPS))]
+        live = sorted(heads)
+        if op == "admit" and len(live) < SLOTS:
+            s = min(set(range(SLOTS)) - set(live))
+            plen = 2 + rs.randint(MAX_LEN - 6)
+            prompt = [101 + (rs.randint(2) + i) % 5 for i in range(plen)]
+            need = min(plen + 4, MAX_LEN)
+            start = [kv.alloc_slot(s, need, prompt=prompt) for kv in kvs][0]
+            src = {k: _payload(rs, (2, 1, MAX_LEN, 4), dt) for k in "kv"}
+            while start < plen:
+                n = min(1 + rs.randint(2 * PS), plen - start)
+                seen.add((start % PS != 0, start // PS != (start + n - 1)
+                          // PS, (start + n) % PS != 0))
+                write(src, [(s, start, n, 0, start)], pad_to=2 * PS)
+                start += n
+            both(lambda kv: kv.register_prefix(s, prompt))
+            heads[s] = plen
+        elif op == "decode" and live:
+            src = {k: _payload(rs, (2, SLOTS, MAX_LEN, 4), dt) for k in "kv"}
+            writes = [(s, heads[s], 1, s, heads[s]) for s in live
+                      if heads[s] < MAX_LEN]
+            srcs = {k: jnp.asarray(v, dt) for k, v in src.items()}
+            both(lambda kv: kv.write_batch(srcs, writes, kind="decode",
+                                           pad_to=SLOTS))
+            for s, *_ in writes:
+                heads[s] += 1
+        elif op == "cow":
+            shared = [(s, i) for s in live for i in range(kvs[0].pages_per_slot)
+                      if kvs[0].refcount[kvs[0].table[s, i]] > 1]
+            if not shared or not kvs[0].n_free():
+                continue
+            s, i = shared[rs.randint(len(shared))]
+            cows = kvs[0].stats.cow_copies
+            n = 1 + rs.randint(PS)
+            src = {k: _payload(rs, (2, 1, MAX_LEN, 4), dt) for k in "kv"}
+            write(src, [(s, i * PS, n, 0, 0)], pad_to=PS)
+            assert kvs[0].stats.cow_copies == cows + 1
+        elif op in ("free", "corrupt_free") and live:
+            s = live[rs.randint(len(live))]
+            own = [int(p) for p in kvs[0].table[s]
+                   if p and kvs[0].refcount[p] == 1]
+            if op == "corrupt_free" and own:
+                # a live page corrupted and then freed: zero-at-free takes
+                # its contents out of esum as they stand
+                phys = own[rs.randint(len(own))]
+                seen.add("corrupt_free")
+                orig = float(np.asarray(kvs[0].pools["k"][phys, 1, 0, 2]))
+                for kv in kvs:
+                    kv.pools["k"] = kv.pools["k"].at[phys, 1, 0, 2].add(4)
+                delta = float(np.asarray(
+                    kvs[0].pools["k"][phys, 1, 0, 2])) - orig
+                both(lambda kv: kv.free_slot(s))
+                _assert_same(*kvs)
+                for kv in kvs:
+                    kv.check_invariants()
+                    assert not kv.verify()     # the page is zero again
+                    gap = (np.asarray(kv.pools["k"], np.float64).sum(axis=0)
+                           - kv.esum["k"])
+                    want = np.zeros_like(gap)
+                    want[1, 0, 2] = delta
+                    np.testing.assert_allclose(gap, want, atol=1e-9)
+                    kv.arm_all()
+            else:
+                both(lambda kv: kv.free_slot(s))
+            del heads[s]
+        else:
+            continue
+        _assert_same(*kvs)
+        for kv in kvs:
+            kv.check_invariants()
+            assert kv.checksums_consistent(), op
+    assert kvs[0].stats.prefix_evictions and kvs[0].stats.cow_copies
+    assert (True, True, True) in seen and "corrupt_free" in seen
+
+
+def test_write_programs_trace_once_per_padded_shape(rs):
+    """Writes of every length within one prefill bucket, decode batches of
+    1..slots live slots and frees of any page count reuse one compiled
+    program each; one batch is counted per call."""
+    from repro.serve.paged_kv import serve_kv_write, serve_kv_zero
+    shape = (2, SLOTS, MAX_LEN, 5)      # a tail no other test uses
+    kv = PagedKVCache({"k": (shape, np.float32), "v": (shape, np.float32)},
+                      slots=SLOTS, max_len=MAX_LEN, page_size=PS)
+    writes0, zeros0 = serve_kv_write._cache_size(), serve_kv_zero._cache_size()
+    src1 = {k: jnp.asarray(rs.randn(2, 1, MAX_LEN, 5), jnp.float32)
+            for k in "kv"}
+    srcB = {k: jnp.asarray(rs.randn(2, SLOTS, MAX_LEN, 5), jnp.float32)
+            for k in "kv"}
+    frees = 0
+    for n in range(PS + 1, 2 * PS + 1):            # one bucket: 2 * PS
+        for s in range(SLOTS):
+            kv.alloc_slot(s, n + SLOTS)
+            kv.write_batch(src1, [(s, 0, n, 0, 0)], kind="prefill",
+                           pad_to=2 * PS)
+        for live in range(1, SLOTS + 1):
+            kv.write_batch(srcB, [(s, n + live - 1, 1, s, 0)
+                                  for s in range(live)],
+                           kind="decode", pad_to=SLOTS)
+        for s in range(SLOTS):
+            kv.free_slot(s)
+            frees += 1
+        ok(kv)
+    assert serve_kv_write._cache_size() - writes0 == 2
+    assert serve_kv_zero._cache_size() - zeros0 == 1
+    assert kv.stats.write_batches == {
+        "prefill": PS * SLOTS, "decode": PS * SLOTS, "free": frees}
+
+
+def test_engine_counts_one_write_batch_per_step_prefill_and_retirement():
+    """The paged engine runs one write program per decode step, one per
+    prefill or chunk and one per retiring request, each with one read."""
+    import jax
+    from repro import obs
+    from repro.configs.base import smoke_config
+    from repro.models import transformer as tf
+    from repro.serve.engine import PagedServeEngine, Request
+
+    cfg = smoke_config("qwen2-0.5b")
+    eng = PagedServeEngine(cfg, tf.init_params(jax.random.PRNGKey(0), cfg),
+                           slots=2, max_len=64, page_size=8,
+                           chunk_prefill=8, prefix_cache=False)
+    obs.reset_all()
+    prompts = (5, 20, 9, 30)
+    for i, n in enumerate(prompts):
+        eng.submit(Request(rid=i, prompt=[(3 * i + j) % 50 + 1
+                                          for j in range(n)],
+                           max_new_tokens=4))
+    assert len(eng.run()) == len(prompts)
+    writes = [e for e in obs.events() if e.name == "serve/kv_write"]
+    chunks = sum(-(-n // 8) for n in prompts)
+    assert len(writes) == chunks
+    assert eng.kv.stats.write_batches == {
+        "decode": eng.stats.decode_steps, "prefill": chunks,
+        "free": len(prompts)}
+    c = obs.counter("repro_kv_write_batches_total")
+    assert {k: c.value(kind=k) for k in ("decode", "prefill", "free")} == \
+        eng.kv.stats.write_batches
+    obs.reset_all()
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_verify_page_sums_equal_float64_recompute(dtype):
+    """The scrub's page sums (one read, values widened to float32, float64
+    sums on threads) equal a float64 recompute of every page."""
+    from concurrent.futures import ThreadPoolExecutor
+
+    from repro.serve.paged_kv import _page_sums
+    dt = np.float32 if dtype == "float32" else jnp.bfloat16
+    pages = np.asarray(jnp.asarray(
+        np.random.RandomState(3).standard_normal((37, PS, 2, 4)), dt))
+    with ThreadPoolExecutor(3) as ex:
+        got = _page_sums(pages, ex)
+    want = pages.astype(np.float64).reshape(37, -1).sum(axis=1)
+    np.testing.assert_allclose(got, want, rtol=1e-12, atol=1e-12)

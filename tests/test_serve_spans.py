@@ -60,7 +60,7 @@ def test_one_gather_decode_writeback_emit_per_decode_step(run):
         active = e.attrs["pages"] // leaves
         assert 1 <= active <= eng.slots
         assert e.attrs["pages"] == active * leaves
-        assert e.attrs["host_reads"] == 2 * e.attrs["pages"]
+        assert e.attrs["host_reads"] == 1   # one read per write batch
 
 
 def test_scrub_span_on_scrub_steps(run):
@@ -101,6 +101,7 @@ def test_stats_timers_are_span_durations(run):
     writes = _named(evs, "serve/kv_write")
     assert writes and {e.parent for e in writes} <= {
         "serve/prefill", "serve/prefill_chunk"}
+    assert all(e.attrs["host_reads"] == 1 for e in writes)
 
 
 def test_span_attrs_are_profiler_metadata(run):
