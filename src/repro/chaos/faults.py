@@ -161,7 +161,7 @@ def ensure_registered() -> Dict[str, Surface]:
                 "repro.kernels.flash_attention", "repro.ckpt.diskless",
                 "repro.ft.runtime", "repro.serve.engine",
                 "repro.serve.paged_kv", "repro.models.layers",
-                "repro.solvers.subspace_cg"):
+                "repro.models.moe", "repro.solvers.subspace_cg"):
         importlib.import_module(mod)
     return dict(_REGISTRY)
 

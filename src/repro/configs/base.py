@@ -37,8 +37,14 @@ class ModelConfig:
     moe_dff: int = 0               # per-expert hidden size
     capacity_factor: float = 1.25
     moe_groups: int = 1            # dispatch groups (set to DP shard count)
+    # expert parallelism: the experts this chip holds, [expert_offset,
+    # expert_offset + experts_held) of n_experts; 0 = all, through the
+    # capacity-dispatch layer.  Set, the dropless held-expert layer runs.
+    experts_held: int = 0
+    expert_offset: int = 0
     # attention
     qkv_bias: bool = False
+    qk_norm: bool = False          # per-head RMSNorm on q and k before RoPE
     attn_softcap: Optional[float] = None
     final_softcap: Optional[float] = None
     window: Optional[int] = None   # sliding window for attn_local

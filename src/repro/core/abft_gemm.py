@@ -43,8 +43,8 @@ import jax.numpy as jnp
 from repro import obs
 from repro.core.checksum import checkpoint_matrix
 
-__all__ = ["ABFTConfig", "encode_weight", "abft_matmul", "verify_output",
-           "correct_output"]
+__all__ = ["ABFTConfig", "encode_weight", "abft_matmul",
+           "abft_grouped_matmul", "verify_output", "correct_output"]
 
 
 # Kernel compute dtypes the layer path accepts.  Checksum ACCUMULATION is
@@ -102,11 +102,16 @@ def _weights(n: int, f: int, seed: int, dtype) -> jax.Array:
     return checkpoint_matrix(f, n, seed=seed).T.astype(dtype)
 
 
-def encode_weight(w: jax.Array, cfg: ABFTConfig) -> jax.Array:
-    """Append f checksum columns to a [k, n] weight matrix -> [k, n + f]."""
+def encode_weight(w: jax.Array, cfg: ABFTConfig,
+                  precision=None) -> jax.Array:
+    """Append f checksum columns to a [..., k, n] weight matrix ->
+    [..., k, n + f].  ``precision`` of the encoding product: on a TPU the
+    default takes one bf16 pass, and a verify held to fp32 rounding needs
+    ``Precision.HIGHEST``."""
     n = w.shape[-1]
     wr = _weights(n, cfg.f, cfg.seed, jnp.float32)
-    cs = (w.astype(jnp.float32) @ wr).astype(w.dtype)
+    cs = jnp.matmul(w.astype(jnp.float32), wr,
+                    precision=precision).astype(w.dtype)
     return jnp.concatenate([w, cs], axis=-1)
 
 
@@ -217,6 +222,80 @@ def abft_matmul(
         return y.astype(x.dtype), ok
     y = correct_output(y, y_cs, residual, cfg)
     return y.astype(x.dtype), ok
+
+
+def abft_grouped_matmul(x: jax.Array, w_enc: jax.Array, layout,
+                        cfg: ABFTConfig, *, exact: bool = False,
+                        inject=None):
+    """Y = X @ W[expert of each row] over a `kernels.ops.GroupedLayout`
+    row buffer, with the per-expert encoding ``w_enc[e] = [W_e, W_e @ w_r]``
+    ([G, k, n + f], `encode_weight` at ``Precision.HIGHEST`` for fp32
+    operands) and a verify per row.
+
+    On a TPU the grouped kernel reduces each row's §4.3 residual
+    ``Y @ w_r - Y_cs`` in its epilogue; the jnp twin takes the verify
+    einsum.  ``cfg.in_dtype`` sets the operand precision as for
+    `abft_matmul` (int8: dynamically quantized operands, carried as exact
+    integers, the checksum columns re-encoded from the quantized weights).
+    ``exact``: x and w_enc's data columns hold values exact in bf16 (bf16
+    activations and weights), which lets the kernel take one MXU pass
+    where the products stay exact.
+    ``inject(y_f) -> y_f`` corrupts the product's output before the verify
+    (chaos drills; the residual is then taken from the corrupted output).
+    Returns (y [m, n], trips: rows whose residual exceeds the tolerance,
+    int32); ``correct`` mode repairs a single corrupted element.
+    """
+    from repro.kernels import ops as kops  # lazy: avoids core<->kernels cycle
+    n = w_enc.shape[-1] - cfg.f
+    wr = _weights(n, cfg.f, cfg.seed, jnp.float32)
+    wn_res = jnp.concatenate([wr, -jnp.eye(cfg.f, dtype=jnp.float32)], 0)
+    pallas = cfg.backend == "pallas" or (cfg.backend == "auto"
+                                         and kops.on_tpu())
+    inv = None
+    if cfg.in_dtype == "int8":
+        w = w_enc[..., :n].astype(jnp.float32)
+        x32 = x.astype(jnp.float32)
+        sx = 127.0 / (jnp.max(jnp.abs(x32)) + 1e-30)
+        sw = 127.0 / (jnp.max(jnp.abs(w)) + 1e-30)
+        # |q| <= 127 is exact in bf16
+        x = jnp.clip(jnp.round(x32 * sx), -127, 127).astype(jnp.bfloat16)
+        wq = jnp.clip(jnp.round(w * sw), -127, 127)
+        w_enc = jnp.concatenate(
+            [wq, jnp.matmul(wq, wr, precision=jax.lax.Precision.HIGHEST)],
+            axis=-1)
+        inv = 1.0 / (sx * sw)
+        cdt = jnp.float32
+        exact = True           # integers of at most 127
+    else:
+        cdt = cfg.compute_dtype
+    # the residual comes from the fp32 product; the output is stored in
+    # the activations' dtype, as the dense projections return it, unless
+    # a repair or a drill needs the checksum columns exact
+    out_dtype = jnp.float32 if (cfg.mode == "correct"
+                                or inject is not None) else x.dtype
+    y_f, residual = kops.grouped_matmul(x, w_enc, layout, wn_res,
+                                        compute_dtype=cdt,
+                                        out_dtype=out_dtype, exact=exact,
+                                        pallas=pallas)
+    if inv is not None:
+        y_f, residual = y_f * inv, residual * inv
+    if inject is not None:
+        y_f = inject(y_f)
+        residual = jnp.dot(y_f[:, :n], wr,
+                           precision=jax.lax.Precision.HIGHEST) - y_f[:, n:]
+    residual = jax.lax.stop_gradient(residual)
+    y, y_cs = y_f[:, :n], y_f[:, n:]
+    bm = x.shape[0] // layout.tile_group.shape[0]
+    rows = jnp.maximum(layout.n_used[0] * bm, 1).astype(jnp.float32)
+    # mean |Y| over the rows the kernel computed (the buffer's tail is 0)
+    scale = jax.lax.stop_gradient(
+        jnp.sum(jnp.abs(y), dtype=jnp.float32) / (rows * n)) + 1e-30
+    tol = cfg.tol_factor * n * _detection_eps(cfg) * scale
+    trips = jnp.sum(jnp.max(jnp.abs(residual), axis=-1) > tol,
+                    dtype=jnp.int32)
+    if cfg.mode == "correct":
+        y = correct_output(y, y_cs, residual, cfg)
+    return y, trips
 
 
 def _residual_ok(y: jax.Array, residual: jax.Array, cfg: ABFTConfig):

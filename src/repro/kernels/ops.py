@@ -18,10 +18,11 @@ from __future__ import annotations
 
 import dataclasses
 import functools
-from typing import Optional, Tuple
+from typing import NamedTuple, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax import lax
 
 from repro import obs
@@ -30,10 +31,12 @@ from repro.kernels import ref
 from repro.kernels.abft_matmul import (STATS_WIDTH, abft_matmul_acc_pallas,
                                        abft_matmul_pallas)
 from repro.kernels.checksum_encode import checksum_encode_pallas
+from repro.kernels.grouped_matmul import (abft_grouped_matmul_pallas,
+                                          abft_grouped_matmul_pallas_dw)
 
 __all__ = [
-    "BlockPlan", "abft_matmul", "abft_matmul_acc", "acc_state_zeros",
-    "checksum_encode", "correct_from_state", "detection_eps",
+    "BlockPlan", "GroupedLayout", "abft_matmul", "abft_matmul_acc",
+    "acc_state_zeros", "checksum_encode", "grouped_layout", "grouped_matmul", "correct_from_state", "detection_eps",
     "kernel_weights", "mxu_rate", "on_tpu", "pick_blocks",
     "plan_accounting", "rank_blocks", "reduce_state", "tile_checksums",
     "vmem_bytes",
@@ -739,3 +742,161 @@ def checksum_encode(x: jax.Array, a: jax.Array, *, force_pallas: bool = False):
             bn *= 2
         return checksum_encode_pallas(x, a, bm=bm, bn=bn, interpret=not on_tpu())
     return ref.checksum_encode_ref(x, a)
+
+
+# ---------------------------------------------------------------------------
+# Grouped (per-expert) products
+# ---------------------------------------------------------------------------
+
+GROUPED_BM = 256       # row tile: each expert's rows are padded to it
+_LANE = 128
+
+
+class GroupedLayout(NamedTuple):
+    """Where each expert's rows sit in a row buffer of whole ``bm`` tiles.
+
+    ``padded``: [G] rows per expert rounded up to the tile; ``starts``:
+    [G] their first row; ``sizes``: ``padded`` with the buffer's unused
+    tail added to the last expert (ragged-dot group sizes, summing to the
+    buffer's rows); ``tile_group``: [m/bm] the expert of each row tile,
+    non-decreasing, the tail carrying the last used tile's expert;
+    ``n_used``: [1] tiles that hold rows."""
+    padded: jax.Array
+    starts: jax.Array
+    sizes: jax.Array
+    tile_group: jax.Array
+    n_used: jax.Array
+
+
+def grouped_layout(counts: jax.Array, m: int,
+                   bm: int = GROUPED_BM) -> GroupedLayout:
+    """The layout of ``counts`` [G] rows per expert in an [m, .] buffer
+    (m a multiple of bm, at least the padded total)."""
+    assert m % bm == 0, (m, bm)
+    g = counts.shape[0]
+    counts = counts.astype(jnp.int32)
+    padded = (counts + bm - 1) // bm * bm
+    ends = jnp.cumsum(padded)
+    starts = ends - padded
+    used = ends[-1] // bm
+    raw = jnp.searchsorted(ends, jnp.arange(m // bm, dtype=jnp.int32) * bm,
+                           side="right").astype(jnp.int32)
+    last = raw[jnp.maximum(used - 1, 0)]
+    tile_group = jnp.minimum(
+        jnp.where(jnp.arange(m // bm) < used, raw, last), g - 1)
+    sizes = padded.at[g - 1].add(m - ends[-1])
+    return GroupedLayout(padded, starts, sizes, tile_group.astype(jnp.int32),
+                         used.reshape(1).astype(jnp.int32))
+
+
+def _tile(dim: int, cap: int) -> int:
+    """The largest multiple of 128 that divides ``dim`` (itself a multiple
+    of 128) and is at most ``cap``."""
+    units = dim // _LANE
+    best = 1
+    for d in range(1, units + 1):
+        if units % d == 0 and d * _LANE <= cap:
+            best = d
+    return best * _LANE
+
+
+def _pad_to(x: jax.Array, shape) -> jax.Array:
+    pads = [(0, t - s) for s, t in zip(x.shape, shape)]
+    return jnp.pad(x, pads) if any(p for _, p in pads) else x
+
+
+def _gmm(layout, a, b, wn, cdt, interpret, out_dtype=jnp.float32,
+         exact_cols=0):
+    """The forward kernel on lane-padded operands: (c [m, n], resid|None)."""
+    m, k = a.shape
+    n = b.shape[-1]
+    kp, np_ = _round_up(k, _LANE), _round_up(n, _LANE)
+    bm = m // layout.tile_group.shape[0]
+    wn_p = None if wn is None else _pad_to(wn, (np_, wn.shape[1]))
+    c, crow = abft_grouped_matmul_pallas(
+        layout.tile_group, layout.n_used, _pad_to(a, (m, kp)),
+        _pad_to(b, (b.shape[0], kp, np_)), wn_p, bm=bm,
+        bn=_tile(np_, 512), bk=_tile(kp, 2048), compute_dtype=cdt,
+        out_dtype=out_dtype, exact_cols=exact_cols, interpret=interpret)
+    return c[:, :n], (None if crow is None else jnp.sum(crow, axis=0))
+
+
+def _gmm_dw(layout, a, g, num_groups, cdt, interpret, exact=False):
+    """dW [G, k, n] of the grouped product (experts with no rows: 0)."""
+    m, k = a.shape
+    n = g.shape[1]
+    kp, np_ = _round_up(k, _LANE), _round_up(n, _LANE)
+    bm = m // layout.tile_group.shape[0]
+    dw = abft_grouped_matmul_pallas_dw(
+        layout.tile_group, layout.n_used, _pad_to(a, (m, kp)),
+        _pad_to(g, (m, np_)), num_groups=num_groups, bm=bm,
+        bk=_tile(kp, 512), bn=_tile(np_, 512), compute_dtype=cdt,
+        out_dtype=jnp.float32, exact=exact, interpret=interpret)
+    return jnp.where((layout.padded > 0)[:, None, None], dw[:, :k, :n], 0.0)
+
+
+def _exact_cols(b, wn, exact):
+    """B's leading columns exact in bf16: its data columns (not the
+    checksum columns ``wn`` reduces) when the caller says so."""
+    if not exact:
+        return 0
+    return b.shape[-1] - (0 if wn is None else wn.shape[1])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(0, 1, 2, 3))
+def _grouped_mm(cdt, out_dtype, exact, interpret, layout, a, b, wn):
+    return _gmm(layout, a, b, wn, cdt, interpret, out_dtype,
+                _exact_cols(b, wn, exact))
+
+
+def _grouped_mm_fwd(cdt, out_dtype, exact, interpret, layout, a, b, wn):
+    return (_gmm(layout, a, b, wn, cdt, interpret, out_dtype,
+                 _exact_cols(b, wn, exact)), (layout, a, b, wn))
+
+
+def _grouped_mm_bwd(cdt, out_dtype, exact, interpret, res, g):
+    """dA through the same kernel on the transposed weights, dB through
+    its ``_dw`` sibling; the residual output carries no gradient (it
+    only feeds the verify).  A bf16 cotangent keeps exact operands exact
+    (the checksum rows of B^T meet the checksum columns' cotangent, zero
+    unless a repair reads them)."""
+    layout, a, b, wn = res
+    gc = g[0]
+    exact = exact and gc.dtype == jnp.bfloat16
+    da, _ = _gmm(layout, gc, jnp.swapaxes(b, 1, 2), None, cdt, interpret,
+                 out_dtype=a.dtype, exact_cols=a.shape[1] if exact else 0)
+    db = _gmm_dw(layout, a, gc, b.shape[0], cdt, interpret, exact)
+    zero_layout = jax.tree.map(
+        lambda x: np.zeros(x.shape, jax.dtypes.float0), layout)
+    return (zero_layout, da, db.astype(b.dtype),
+            None if wn is None else jnp.zeros_like(wn))
+
+
+_grouped_mm.defvjp(_grouped_mm_fwd, _grouped_mm_bwd)
+
+
+def grouped_matmul(a: jax.Array, b: jax.Array, layout: GroupedLayout,
+                   wn: Optional[jax.Array] = None, *,
+                   compute_dtype=jnp.float32, out_dtype=jnp.float32,
+                   exact: bool = False, pallas: Optional[bool] = None):
+    """C = A @ B[expert of each row] over a `GroupedLayout` row buffer.
+
+    a: [m, k] rows sorted by expert (padding rows zero); b: [G, k, n];
+    wn: [n, f] or None.  Returns (c [m, n] in ``out_dtype``, the fp32
+    product @ wn [m, f] or None).  ``exact``: A and B's data columns hold
+    values exact in bf16 (the MXU then takes one pass where it can, the
+    same products).  With ``pallas`` (default: on a TPU) the
+    grouped Pallas kernels run, forward and backward (custom VJP),
+    interpreted off the TPU; otherwise the jnp twin
+    (`ref.grouped_matmul_ref`), differentiated by JAX.
+    """
+    use_pallas = on_tpu() if pallas is None else pallas
+    obs.counter("repro_kernel_traces_total",
+                "kernel dispatcher traces (≈ compiles)").inc(
+        op="abft_grouped_matmul", backend="pallas" if use_pallas else "ref")
+    cdt, odt = jnp.dtype(compute_dtype), jnp.dtype(out_dtype)
+    if use_pallas:
+        return _grouped_mm(cdt, odt, exact, not on_tpu(), layout, a, b, wn)
+    c, resid = ref.grouped_matmul_ref(a, b, layout.sizes, wn,
+                                      compute_dtype=cdt)
+    return c.astype(odt), resid

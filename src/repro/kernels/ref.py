@@ -7,7 +7,7 @@ import jax.numpy as jnp
 from repro.core.checksum import checkpoint_matrix
 
 __all__ = ["default_weights", "abft_matmul_ref", "checksum_encode_ref",
-           "checksum_verify_ref"]
+           "checksum_verify_ref", "grouped_matmul_ref"]
 
 # Seed for the kernel-level checkpoint matrices.  Fixed so that carried
 # checksum states are reproducible across calls, processes and the jnp/Pallas
@@ -56,3 +56,16 @@ def checksum_verify_ref(c: jax.Array, colsum: jax.Array):
     """Max abs residual between colsum(C) and a carried checksum row."""
     rec = jnp.sum(c.astype(jnp.float32), axis=0)
     return jnp.max(jnp.abs(rec - colsum.astype(jnp.float32)))
+
+
+def grouped_matmul_ref(a: jax.Array, b: jax.Array, sizes: jax.Array,
+                       wn=None, *, compute_dtype=jnp.float32):
+    """jnp twin of ``abft_grouped_matmul_pallas``: C = ragged_dot(A, B)
+    over consecutive row groups of ``sizes`` (summing to A's rows) in fp32
+    accumulation, and with ``wn`` the verify einsum ``C @ wn`` [m, f]."""
+    c = jax.lax.ragged_dot(a.astype(compute_dtype), b.astype(compute_dtype),
+                           sizes, preferred_element_type=jnp.float32)
+    if wn is None:
+        return c, None
+    return c, jnp.dot(c, wn.astype(jnp.float32),
+                      precision=jax.lax.Precision.HIGHEST)

@@ -35,6 +35,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import obs
 from repro.configs.base import ShapeConfig, get_config, smoke_config
 from repro.data.pipeline import DataConfig, DataPipeline
 from repro.data.pipeline import synthetic_batch as synthetic
@@ -52,8 +53,12 @@ def run(arch: str, *, smoke: bool = True, steps: int = 100, batch: int = 16,
         seq: int = 128, microbatches: int = 1, abft_mode: str = "off",
         inject_failures: int = 0, ckpt_dir: str = None, resume: bool = False,
         log_every: int = 10, lr: float = 3e-4, seed: int = 0,
-        diskless_every: int = 10, mesh=None, total_steps: int = None):
+        diskless_every: int = 10, mesh=None, total_steps: int = None,
+        experts_held: int = 0, expert_offset: int = 0):
     cfg = smoke_config(arch) if smoke else get_config(arch)
+    if experts_held:    # one chip's share of an expert-parallel layer
+        cfg = cfg.scaled(experts_held=experts_held,
+                         expert_offset=expert_offset)
     shape = ShapeConfig("cli", seq, batch, "train")
     if mesh is None:
         mesh = make_mesh((1, 1), ("data", "model"))
@@ -117,6 +122,7 @@ def run(arch: str, *, smoke: bool = True, steps: int = 100, batch: int = 16,
                          for k, v in synthetic(data_cfg, i).items()}
             state, metrics = jit_step(state, batch_dev)
             losses.append(float(metrics["loss"]))
+            publish_moe_counters(metrics, cfg)
             if i % log_every == 0:
                 print(f"[train] step {i:5d} loss={losses[-1]:.4f} "
                       f"gnorm={float(metrics['grad_norm']):.3f} "
@@ -130,6 +136,24 @@ def run(arch: str, *, smoke: bool = True, steps: int = 100, batch: int = 16,
         print(f"[train] done: loss {losses[0]:.3f} -> {losses[-1]:.3f}; "
               f"recoveries={ft.recoveries}")
         return losses
+
+
+def publish_moe_counters(metrics, cfg) -> None:
+    """The held-expert layer's step counters on the `repro.obs` bus:
+    assignments to this chip's experts, rows the grouped kernel computed
+    (tile padding included) and expert-product verify trips."""
+    if "moe_assignments" not in metrics:
+        return
+    held = f"{cfg.expert_offset}-{cfg.expert_offset + cfg.experts_held - 1}"
+    obs.counter("repro_moe_assignments_total",
+                "token-expert assignments routed to held experts").inc(
+        float(metrics["moe_assignments"]), held=held)
+    obs.counter("repro_moe_rows_computed_total",
+                "rows the grouped expert kernel computed, with padding").inc(
+        float(metrics["moe_rows_computed"]))
+    obs.counter("repro_abft_trips_total",
+                "ABFT residual trips by protected surface").inc(
+        float(metrics["moe_trips"]), surface="moe.expert")
 
 
 # stacked DP views moved to ft.runtime (shared with ElasticRuntime); kept
@@ -329,6 +353,10 @@ def main():
     ap.add_argument("--ckpt-dir", default=None)
     ap.add_argument("--resume", action="store_true")
     ap.add_argument("--lr", type=float, default=3e-4)
+    ap.add_argument("--experts-held", type=int, default=0,
+                    help="MoE: experts this chip holds (0: all)")
+    ap.add_argument("--expert-offset", type=int, default=0,
+                    help="MoE: the first held expert")
     # elastic drill flags (ft.runtime.ElasticRuntime end-to-end)
     ap.add_argument("--kill-pod-at-step", type=int, default=None,
                     help="run the pod-loss drill: lose a pod at this step")
@@ -353,7 +381,8 @@ def main():
     run(args.arch, smoke=args.smoke, steps=args.steps, batch=args.batch,
         seq=args.seq, microbatches=args.microbatches, abft_mode=args.abft,
         inject_failures=args.inject_failures, ckpt_dir=args.ckpt_dir,
-        resume=args.resume, lr=args.lr)
+        resume=args.resume, lr=args.lr, experts_held=args.experts_held,
+        expert_offset=args.expert_offset)
 
 
 if __name__ == "__main__":

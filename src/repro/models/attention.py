@@ -20,7 +20,8 @@ import jax.numpy as jnp
 import numpy as np
 from jax import lax
 
-from repro.models.layers import linear_apply, linear_init, rope, softcap_fn
+from repro.models.layers import (linear_apply, linear_init, rmsnorm_apply,
+                                 rmsnorm_init, rope, softcap_fn)
 
 NEG_INF = -1e30
 
@@ -36,11 +37,13 @@ class AttnSpec(NamedTuple):
     rope_theta: float = 10000.0
     use_rope: bool = True
     kc: int = 512                    # flash KV chunk length
+    qk_norm: bool = False            # RMSNorm over head_dim on q, k (Qwen3)
+    norm_eps: float = 1e-6
 
 
 def attn_init(key, s: AttnSpec, dtype=jnp.float32):
     kq, kk, kv, ko = jax.random.split(key, 4)
-    return {
+    p = {
         "wq": linear_init(kq, s.d_model, s.n_heads * s.head_dim,
                           bias=s.qkv_bias, dtype=dtype),
         "wk": linear_init(kk, s.d_model, s.n_kv * s.head_dim,
@@ -49,6 +52,10 @@ def attn_init(key, s: AttnSpec, dtype=jnp.float32):
                           bias=s.qkv_bias, dtype=dtype),
         "wo": linear_init(ko, s.n_heads * s.head_dim, s.d_model, dtype=dtype),
     }
+    if s.qk_norm:
+        p["q_norm"] = rmsnorm_init(s.head_dim, dtype)
+        p["k_norm"] = rmsnorm_init(s.head_dim, dtype)
+    return p
 
 
 def _split_heads(x, n, d):
@@ -108,8 +115,11 @@ def _scores(st: _FlashStatic, q32, k_c, q_pos, kp_c):
     s = jnp.einsum("bqhgd,bkhd->bhgqk", q32,
                    k_c.astype(jnp.float32)) * st.scale
     s = softcap_fn(s, st.softcap)
+    # an additive [Sq, kc] bias, not a select: a select's mask is kept per
+    # chunk when the loop is linearised, broadcast to the scores' shape (4.3
+    # GB at 2 x 8k tokens and 32 heads), the bias at its own [Sq, kc]
     msk = _mask(q_pos, kp_c, causal=st.causal, window=st.window)
-    return jnp.where(msk[None, None, None], s, NEG_INF), s
+    return s + jnp.where(msk, 0.0, NEG_INF)[None, None, None], s
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(0,))
@@ -227,6 +237,9 @@ def attn_apply(
     kv_src = cross_kv if cross_kv is not None else x
     k = _split_heads(linear_apply(p["wk"], kv_src, abft), s.n_kv, s.head_dim)
     v = _split_heads(linear_apply(p["wv"], kv_src, abft), s.n_kv, s.head_dim)
+    if s.qk_norm:
+        q = rmsnorm_apply(p["q_norm"], q, s.norm_eps)
+        k = rmsnorm_apply(p["k_norm"], k, s.norm_eps)
 
     if s.use_rope and cross_kv is None:
         pos_b = positions[None] if positions.ndim == 1 else positions

@@ -47,6 +47,8 @@ def _attn_spec(cfg: ModelConfig, kind: str) -> attn.AttnSpec:
         rope_theta=cfg.rope_theta,
         use_rope=kind not in ("cross",),
         kc=cfg.flash_kc,
+        qk_norm=cfg.qk_norm,
+        norm_eps=cfg.norm_eps,
     )
 
 
@@ -61,7 +63,19 @@ def _xlstm_spec(cfg: ModelConfig) -> xl.XLSTMSpec:
 def _moe_spec(cfg: ModelConfig) -> moe_mod.MoESpec:
     return moe_mod.MoESpec(cfg.d_model, cfg.moe_dff or cfg.d_ff,
                            cfg.n_experts, cfg.top_k, cfg.capacity_factor,
-                           cfg.moe_groups)
+                           cfg.moe_groups, experts_held=cfg.experts_held,
+                           expert_offset=cfg.expert_offset)
+
+
+def _no_moe_stats():
+    return jnp.zeros((len(moe_mod.MOE_STATS),), jnp.float32)
+
+
+def merge_moe_stats(a, b):
+    """Two `moe.MOE_STATS` vectors as one: counts add, the largest group
+    is the larger."""
+    i = moe_mod.MOE_STATS.index("moe_max_group")
+    return jnp.where(jnp.arange(a.shape[0]) == i, jnp.maximum(a, b), a + b)
 
 
 def _dtype(cfg: ModelConfig):
@@ -132,14 +146,16 @@ def _block_cache(cfg: ModelConfig, mixer: str, batch: int, max_len: int):
 def _block_apply(p, x, cfg: ModelConfig, mixer: str, ffn: str, *,
                  positions, cache=None, cross_src=None, abft=None,
                  invariants: bool = False):
-    """Returns (x, new_cache, aux_loss, inv_ok).
+    """Returns (x, new_cache, aux_loss, inv_ok, moe_stats).
 
     ``invariants=True`` runs each rmsnorm through its second-moment
     construction check (models.layers surface drills); ``inv_ok`` is the
-    AND of every check, constant True when checks are off.
+    AND of every check, constant True when checks are off.  ``moe_stats``
+    is the held-expert layer's `moe.MOE_STATS` (zeros for other blocks).
     """
     aux = jnp.zeros((), jnp.float32)
     ok = jnp.array(True)
+    stats = _no_moe_stats()
 
     def norm(pn, xx):
         if invariants:
@@ -219,10 +235,13 @@ def _block_apply(p, x, cfg: ModelConfig, mixer: str, ffn: str, *,
         ok &= ok2
         if ffn == "dense":
             y2 = mlp_apply(p["mlp"], h2, activation=cfg.activation, abft=abft)
+        elif cfg.experts_held:
+            y2, aux, stats = moe_mod.moe_held_apply(p["moe"], h2,
+                                                    _moe_spec(cfg), abft)
         else:
             y2, aux = moe_mod.moe_apply(p["moe"], h2, _moe_spec(cfg), abft)
         x = x + y2
-    return x, new_cache, aux, ok
+    return x, new_cache, aux, ok, stats
 
 
 def _cross_from_cache(p_cross, h, spec, cache):
@@ -291,7 +310,8 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int):
 def _run_groups(params, x, cfg: ModelConfig, *, positions, cache,
                 cross_src, abft, remat: bool, x_sharding=None,
                 invariants: bool = False):
-    """Scan every layout group; returns (x, new_cache, aux_total, inv_ok).
+    """Scan every layout group; returns (x, new_cache, aux_total, inv_ok,
+    moe_stats).
 
     The cache rides in the scan CARRY (indexed by the layer counter), not in
     xs/ys: while-loop carries alias in place, so a decode step updates the
@@ -301,12 +321,14 @@ def _run_groups(params, x, cfg: ModelConfig, *, positions, cache,
     new_groups = []
     aux_total = jnp.zeros((), jnp.float32)
     ok_total = jnp.array(True)
+    # only a held-expert layer has counters to carry
+    stats_total = _no_moe_stats() if cfg.experts_held else None
     for gi, (pattern, repeats) in enumerate(cfg.layout):
         gparams = params["groups"][gi]
         gcache = cache["groups"][gi] if cache is not None else None
 
         def body(carry, xs, _pattern=pattern):
-            xx, aux_acc, ok_acc, cstack = carry
+            xx, aux_acc, ok_acc, st_acc, cstack = carry
             pslice, idx = xs
             if x_sharding is not None:
                 # pin the residual stream so the auto-partitioner doesn't
@@ -320,31 +342,33 @@ def _run_groups(params, x, cfg: ModelConfig, *, positions, cache,
                         cstack[f"b{bi}"])
                 else:
                     c_in = None
-                xx, c_out, aux, ok_b = _block_apply(
+                xx, c_out, aux, ok_b, st_b = _block_apply(
                     pslice[f"b{bi}"], xx, cfg, mixer, ffn,
                     positions=positions, cache=c_in, cross_src=cross_src,
                     abft=abft, invariants=invariants)
                 aux_acc = aux_acc + aux
                 ok_acc = ok_acc & ok_b
+                if st_acc is not None:
+                    st_acc = merge_moe_stats(st_acc, st_b)
                 if cstack is not None and c_out is not None:
                     cstack = dict(cstack)
                     cstack[f"b{bi}"] = jax.tree.map(
                         lambda full, new: lax.dynamic_update_index_in_dim(
                             full, new.astype(full.dtype), idx, 0),
                         cstack[f"b{bi}"], c_out)
-            return (xx, aux_acc, ok_acc, cstack), None
+            return (xx, aux_acc, ok_acc, st_acc, cstack), None
 
         if remat:
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                       if remat == "dots" else
                       jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(body, policy=policy)
-        (x, aux_total, ok_total, new_gcache), _ = lax.scan(
-            body, (x, aux_total, ok_total, gcache),
+        (x, aux_total, ok_total, stats_total, new_gcache), _ = lax.scan(
+            body, (x, aux_total, ok_total, stats_total, gcache),
             (gparams, jnp.arange(repeats)))
         new_groups.append(new_gcache)
     new_cache = {"groups": new_groups} if cache is not None else None
-    return x, new_cache, aux_total, ok_total
+    return x, new_cache, aux_total, ok_total, stats_total
 
 
 def _encode_frames(params, frames, cfg: ModelConfig):
@@ -353,8 +377,8 @@ def _encode_frames(params, frames, cfg: ModelConfig):
 
     def body(carry, pslice):
         xx = carry
-        xx, _, _, _ = _block_apply(pslice, xx, cfg, "attn_bidir", "dense",
-                                   positions=jnp.arange(x.shape[1]))
+        xx = _block_apply(pslice, xx, cfg, "attn_bidir", "dense",
+                          positions=jnp.arange(x.shape[1]))[0]
         return xx, None
 
     x, _ = lax.scan(body, x, params["encoder"])
@@ -364,7 +388,7 @@ def _encode_frames(params, frames, cfg: ModelConfig):
 def forward(params, tokens, cfg: ModelConfig, *, positions=None, cache=None,
             frames=None, img_emb=None, abft=None, remat: bool = False,
             logits_sharding=None, x_sharding=None, return_hidden: bool = False,
-            invariants: bool = False):
+            invariants: bool = False, moe_stats: bool = False):
     """Train/prefill forward. tokens: [B,S] -> logits [B,S,V] fp32.
 
     frames: [B, n_frames, d_model] (whisper stub input);
@@ -377,6 +401,8 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None, cache=None,
     gather checksum column, every rmsnorm second moment) and return a
     4-tuple (..., inv_ok) — StepOptions.invariant_checks surfaces it as
     metrics["inv_ok"].
+    moe_stats: append the held-expert layers' `moe.MOE_STATS`, summed
+    over the layers (the largest group: the largest of any layer).
     """
     b, s = tokens.shape
     if positions is None:
@@ -393,12 +419,10 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None, cache=None,
         cross_src = _encode_frames(params, frames, cfg)
     elif img_emb is not None:
         cross_src = img_emb
-    x, new_cache, aux, ok_run = _run_groups(params, x, cfg,
-                                            positions=positions,
-                                            cache=cache, cross_src=cross_src,
-                                            abft=abft, remat=remat,
-                                            x_sharding=x_sharding,
-                                            invariants=invariants)
+    x, new_cache, aux, ok_run, stats = _run_groups(
+        params, x, cfg, positions=positions, cache=cache,
+        cross_src=cross_src, abft=abft, remat=remat, x_sharding=x_sharding,
+        invariants=invariants)
     if invariants:
         x, ok_fn = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps,
                                  check=True)
@@ -406,9 +430,12 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None, cache=None,
         x = rmsnorm_apply(params["final_norm"], x, cfg.norm_eps)
         ok_fn = jnp.array(True)
     inv_ok = ok_embed & ok_run & ok_fn
+    if moe_stats and stats is None:
+        stats = _no_moe_stats()
+    extra = ((inv_ok,) if invariants else ()) + ((stats,) if moe_stats
+                                                  else ())
     if return_hidden:
-        return (x, new_cache, aux, inv_ok) if invariants else \
-            (x, new_cache, aux)
+        return (x, new_cache, aux) + extra
     head = params.get("lm_head")
     if head is None:
         logits = (x.astype(jnp.float32) @
@@ -418,8 +445,7 @@ def forward(params, tokens, cfg: ModelConfig, *, positions=None, cache=None,
         logits = unembed_apply(head, x, softcap=cfg.final_softcap, abft=abft)
     if logits_sharding is not None:
         logits = jax.lax.with_sharding_constraint(logits, logits_sharding)
-    return (logits, new_cache, aux, inv_ok) if invariants else \
-        (logits, new_cache, aux)
+    return (logits, new_cache, aux) + extra
 
 
 def decode_step(params, token, pos, cache, cfg: ModelConfig, *,
@@ -443,18 +469,24 @@ def decode_step(params, token, pos, cache, cfg: ModelConfig, *,
 def loss_fn(params, tokens, labels, cfg: ModelConfig, *, frames=None,
             img_emb=None, abft=None, remat: bool = False,
             aux_weight: float = 0.01, logits_sharding=None, x_sharding=None,
-            invariants: bool = False):
-    """Scalar LM loss; with ``invariants=True`` returns ``(loss, inv_ok)``
-    (value_and_grad has_aux form)."""
+            invariants: bool = False, moe_stats: bool = False):
+    """Scalar LM loss; with ``invariants`` or ``moe_stats`` returns
+    ``(loss, extras)`` (value_and_grad has_aux form), extras holding
+    ``inv_ok`` and/or ``moe`` (the `forward` values)."""
     out = forward(params, tokens, cfg, frames=frames,
                   img_emb=img_emb, abft=abft, remat=remat,
                   logits_sharding=logits_sharding,
-                  x_sharding=x_sharding, invariants=invariants)
+                  x_sharding=x_sharding, invariants=invariants,
+                  moe_stats=moe_stats)
     logits, aux = out[0], out[2]
     logp = jax.nn.log_softmax(logits, axis=-1)
     nll = -jnp.take_along_axis(logp, labels[..., None], axis=-1)[..., 0]
     loss = jnp.mean(nll) + aux_weight * aux
-    return (loss, out[3]) if invariants else loss
+    if not (invariants or moe_stats):
+        return loss
+    extras = dict(zip([n for n, on in (("inv_ok", invariants),
+                                       ("moe", moe_stats)) if on], out[3:]))
+    return loss, extras
 
 
 def param_count(params) -> int:

@@ -23,6 +23,7 @@ from repro.configs.base import ModelConfig, ShapeConfig
 from repro.core.abft_gemm import ABFTConfig
 from repro.dist import sharding as shd
 from repro.models import transformer as tf
+from repro.models.moe import MOE_STATS
 from repro.train.optimizer import (AdamWConfig, adamw_init, adamw_opt_specs,
                                    adamw_update)
 
@@ -47,6 +48,9 @@ class StepOptions:
     # grad_compression="int8_ef" wire for the end-to-end low-precision run.
     kernel_dtype: str = "fp32"
     grad_compression: str = "none"  # none | int8_ef
+    # weight of `models.moe.balance_loss`, E * sum_e f_e P_e with the f_e
+    # summing to top_k (the published form): a weight tuned for f_e
+    # summing to 1 is top_k times larger here
     aux_weight: float = 0.01
     # defer the DP gradient all-reduce to AFTER microbatch accumulation
     # (shard_map manual-DP region: one psum instead of one per microbatch —
@@ -250,6 +254,12 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
     x_sharding = NamedSharding(mesh, P(*(list(bspec) + [None, None])))
     batch_sharding = NamedSharding(mesh, P(*bspec))
 
+    inv_on = opts.invariant_checks
+    # the held-expert layer's counters (models.moe.MOE_STATS) ride the
+    # loss's has_aux output on the standard grad path
+    moe_on = bool(cfg.experts_held) and not opts.defer_grad_reduce
+    has_extras = inv_on or moe_on
+
     def loss_of(params, batch):
         batch = dict(batch,
                      tokens=jax.lax.with_sharding_constraint(
@@ -261,20 +271,26 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
             frames=batch.get("frames"), img_emb=batch.get("img_emb"),
             abft=opts.abft, remat=opts.remat_arg, aux_weight=opts.aux_weight,
             logits_sharding=logits_sharding, x_sharding=x_sharding,
-            invariants=opts.invariant_checks)
+            invariants=inv_on, moe_stats=moe_on)
 
-    inv_on = opts.invariant_checks
+    def _merge_extras(a, b):
+        out = {}
+        if "inv_ok" in a:
+            out["inv_ok"] = a["inv_ok"] & b["inv_ok"]
+        if "moe" in a:
+            out["moe"] = tf.merge_moe_stats(a["moe"], b["moe"])
+        return out
 
     def _accumulate(loss_fn_, params, batch):
         """Microbatch scan accumulating fp32 grads (no reduction choices).
 
-        Returns (loss, grads), or (loss, grads, inv_ok) when the loss fn
-        carries the invariant flag (has_aux form)."""
-        vg = jax.value_and_grad(loss_fn_, has_aux=inv_on)
+        Returns (loss, grads), or (loss, grads, extras) when the loss fn
+        carries invariant flags or MoE counters (has_aux form)."""
+        vg = jax.value_and_grad(loss_fn_, has_aux=has_extras)
         if m <= 1:
-            if inv_on:
-                (loss, ok), grads = vg(params, batch)
-                return loss, grads, ok
+            if has_extras:
+                (loss, ex), grads = vg(params, batch)
+                return loss, grads, ex
             return vg(params, batch)
 
         def split(x):
@@ -282,21 +298,26 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
         mbatch = jax.tree.map(split, batch)
 
         def acc_step(carry, mb):
-            loss_acc, g_acc, ok_acc = carry
-            if inv_on:
-                (l, ok_mb), g = vg(params, mb)
-                ok_acc = ok_acc & ok_mb
+            loss_acc, g_acc, ex_acc = carry
+            if has_extras:
+                (l, ex_mb), g = vg(params, mb)
+                ex_acc = _merge_extras(ex_acc, ex_mb)
             else:
                 l, g = vg(params, mb)
             g_acc = jax.tree.map(
                 lambda a, b: a + b.astype(jnp.float32), g_acc, g)
-            return (loss_acc + l, g_acc, ok_acc), None
+            return (loss_acc + l, g_acc, ex_acc), None
 
         g0 = jax.tree.map(lambda p: jnp.zeros(p.shape, jnp.float32), params)
-        (loss, grads, ok), _ = lax.scan(
-            acc_step, (jnp.zeros(()), g0, jnp.array(True)), mbatch)
+        ex0 = {}
+        if inv_on:
+            ex0["inv_ok"] = jnp.array(True)
+        if moe_on:
+            ex0["moe"] = jnp.zeros((len(MOE_STATS),), jnp.float32)
+        (loss, grads, ex), _ = lax.scan(
+            acc_step, (jnp.zeros(()), g0, ex0), mbatch)
         loss, grads = loss / m, jax.tree.map(lambda g: g / m, grads)
-        return (loss, grads, ok) if inv_on else (loss, grads)
+        return (loss, grads, ex) if has_extras else (loss, grads)
 
     if opts.defer_grad_reduce:
         dp = shd.dp_axes(mesh)
@@ -422,13 +443,13 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
         params = state["params"]
         new_res = None
         reduce_ok = None
-        inv_ok = None
+        extras = {}
         if "ef_residual" in state:
             loss, grads, new_res = grad_fn(params, batch, state["ef_residual"])
         elif abft_reduce_on:
             loss, grads, reduce_ok = grad_fn(params, batch)
-        elif inv_on:
-            loss, grads, inv_ok = grad_fn(params, batch)
+        elif has_extras:
+            loss, grads, extras = grad_fn(params, batch)
         else:
             loss, grads = grad_fn(params, batch)
         new_params, new_opt, metrics = adamw_update(
@@ -440,8 +461,10 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
         metrics = dict(metrics, loss=loss)
         if reduce_ok is not None:
             metrics["abft_ok"] = reduce_ok
-        if inv_ok is not None:
-            metrics["inv_ok"] = inv_ok.astype(jnp.float32)
+        if "inv_ok" in extras:
+            metrics["inv_ok"] = extras["inv_ok"].astype(jnp.float32)
+        if "moe" in extras:
+            metrics.update(zip(MOE_STATS, extras["moe"]))
         return new_state, metrics
 
     state_shapes = jax.eval_shape(
@@ -460,6 +483,8 @@ def build_train_step(cfg: ModelConfig, mesh: Mesh, shape: ShapeConfig,
         metric_sh["abft_ok"] = NamedSharding(mesh, P())
     if inv_on:
         metric_sh["inv_ok"] = NamedSharding(mesh, P())
+    if moe_on:
+        metric_sh.update({k: NamedSharding(mesh, P()) for k in MOE_STATS})
     out_shardings = (state_sh, metric_sh)
     return step_fn, in_shardings, out_shardings
 

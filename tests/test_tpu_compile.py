@@ -5,7 +5,9 @@ the (8, 128) tiling, too much VMEM), so each kernel is compiled here with
 ``interpret=False`` for a *described* v5e chip — no chip needed — and must
 come out as a ``tpu_custom_call``.  Shapes are the real ones: qwen2-0.5b's
 prefill projections (m = 128 prompt tokens, d 896, d_ff 4864), a multi-tile
-SUMMA local block, and attention at seq 1024 with head dim 64.
+SUMMA local block, attention at seq 1024 with head dim 64, and the grouped
+expert products of the qwen3-moe-30b-a3b.train-8k cell.  That cell's whole
+train step is compiled too, and must fit one v5e beside its state.
 
 The topology is described inside a module fixture, never at import: only
 one process may load the TPU library at a time, and a test file that loads
@@ -25,11 +27,11 @@ from repro.kernels.flash_attention import _flash_call, flash_attention_pallas
 
 
 @pytest.fixture(scope="module")
-def one_chip():
+def topo():
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
@@ -39,8 +41,13 @@ def one_chip():
     was = jax.config.jax_enable_compilation_cache
     jax.config.update("jax_enable_compilation_cache", False)
     cc.reset_cache()
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    return SingleDeviceSharding(topo.devices[0])
 
 
 def _compiled_text(fn, *structs):
@@ -100,3 +107,78 @@ def test_flash_kernel_at_seq_1024(one_chip, checked):
         fn = lambda q, k, v: flash_attention_pallas(
             q, k, v, scale=d ** -0.5, interpret=False)
     assert "tpu_custom_call" in _compiled_text(fn, S, S, S)
+
+
+# qwen3-moe-30b-a3b.train-8k: 2 x 8192 tokens, top-8, 16 held experts; the
+# dropless row buffer of the grouped products at its worst case
+MOE_TOKENS, MOE_TOP_K, MOE_HELD = 2 * 8192, 8, 16
+MOE_ROWS = -(-(MOE_TOKENS * MOE_TOP_K + MOE_HELD * (kops.GROUPED_BM - 1))
+             // kops.GROUPED_BM) * kops.GROUPED_BM
+
+
+@pytest.mark.parametrize("pas", ["forward", "dx", "dw"])
+@pytest.mark.parametrize("k, n", [
+    (2048, 2 * 768 + 2),    # gate and up in one product, 2 checksum columns
+    (768, 2048 + 2),        # down
+])
+def test_grouped_kernels_at_the_moe_cell(one_chip, k, n, pas):
+    S = lambda shape, dt=jnp.int32: jax.ShapeDtypeStruct(shape, dt,
+                                                         sharding=one_chip)
+    m, g = MOE_ROWS, MOE_HELD
+    lay = kops.GroupedLayout(S((g,)), S((g,)), S((g,)),
+                             S((m // kops.GROUPED_BM,)), S((1,)))
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    if pas == "forward":
+        fn = lambda lay, a, b, wn: kops._gmm(lay, a, b, wn, f32, False,
+                                             bf16)
+        args = (S((m, k), bf16), S((g, k, n), f32), S((n, 2), f32))
+    elif pas == "dx":
+        fn = lambda lay, a, b: kops._gmm(lay, a, b, None, f32, False, bf16)
+        args = (S((m, n), bf16), S((g, n, k), f32))
+    else:
+        fn = lambda lay, a, d: kops._gmm_dw(lay, a, d, g, f32, False)
+        args = (S((m, k), bf16), S((m, n), bf16))
+    assert "tpu_custom_call" in _compiled_text(fn, lay, *args)
+
+
+def test_moe_cell_train_step_fits_one_v5e(topo, monkeypatch):
+    """The cell's whole step (`bench/program_moe.py` config, the
+    workload's step options) compiled for a v5e: its state and its
+    temporaries stay under 15 GiB, the evidence for batch 2."""
+    import json
+    from pathlib import Path
+
+    from bench.program_moe import model_config
+    from repro.configs.base import ShapeConfig
+    from repro.dist.sharding import make_mesh
+    from repro.train.optimizer import AdamWConfig
+    from repro.train.step import StepOptions, build_train_step, init_state
+    monkeypatch.setattr(kops, "on_tpu", lambda: True)
+    bench = Path(__file__).resolve().parents[1] / "bench"
+    conf = json.loads((bench / "configs" / "qwen3-moe-30b-a3b.json")
+                      .read_text())
+    work = json.loads((bench / "workloads" /
+                       "qwen3-moe-30b-a3b.train-8k.json").read_text())
+    mix = json.loads((bench / "traffic" / "train-8k.json").read_text())
+    cfg = model_config(conf)
+    mesh = make_mesh((1, 1), ("data", "model"), devices=topo.devices[:1])
+    shape = ShapeConfig("bench", mix["seq_len"], mix["batch"], "train")
+    opts = StepOptions(**work["step"])
+    step, in_sh, out_sh = build_train_step(
+        cfg, mesh, shape, AdamWConfig(**work["adamw"]), opts)
+    state = jax.tree.map(
+        lambda s, sh: jax.ShapeDtypeStruct(s.shape, s.dtype, sharding=sh),
+        jax.eval_shape(lambda k: init_state(k, cfg, opts),
+                       jax.random.PRNGKey(0)), in_sh[0])
+    batch = {k: jax.ShapeDtypeStruct((mix["batch"], mix["seq_len"]),
+                                     jnp.int32, sharding=in_sh[1][k])
+             for k in ("tokens", "labels")}
+    compiled = jax.jit(step, in_shardings=in_sh, out_shardings=out_sh,
+                       donate_argnums=(0,)).lower(state, batch).compile()
+    mem = compiled.memory_analysis()
+    state_gib = mem.argument_size_in_bytes / 2**30
+    temps_gib = mem.temp_size_in_bytes / 2**30
+    print(f"moe cell step: state {state_gib:.2f} GiB, temporaries "
+          f"{temps_gib:.2f} GiB")
+    assert "abft_grouped_matmul_pallas" in compiled.as_text()
+    assert state_gib + temps_gib < 15.0, (state_gib, temps_gib)
