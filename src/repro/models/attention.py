@@ -230,6 +230,10 @@ def attn_apply(
        - train/prefill: cache None -> full self-attention over x
        - prefill w/ cache: cache with index 0, Sq tokens written
        - decode: Sq == 1, reads cache, writes at cache["index"]
+       - paged decode: Sq == 1, cache {"k","v": page pools [n_pages,
+         layers, page_size, n_kv * D], "layer": this layer's index,
+         "table": [B, pages]}; positions [B, 1]; new_cache holds only the
+         token's K/V rows [B, 1, n_kv, D]
        - cross: cross_kv set (no cache, no mask)
     """
     b, sq, _ = x.shape
@@ -247,7 +251,23 @@ def attn_apply(
         k = rope(k, pos_b, s.rope_theta)
 
     new_cache = None
-    if cache is not None:
+    paged = cache is not None and "table" in cache
+    if paged:
+        # paged decode: this layer's pages read through the page table and
+        # the fresh token set at each slot's position in that view only;
+        # the new K/V rows go back to the caller, which owns the pools
+        if sq != 1:
+            raise ValueError(f"a paged cache decodes one token, got {sq}")
+        new_cache = {"k": k.astype(cache["k"].dtype),
+                     "v": v.astype(cache["v"].dtype)}
+        k_pos = jnp.arange(cache["table"].shape[1] * cache["k"].shape[2])
+        fresh = (k_pos[None, :] == positions)[:, :, None]
+
+        def view(n):
+            pages = paged_view(cache[n], cache["layer"], cache["table"])
+            return jnp.where(fresh, new_cache[n].reshape(b, 1, -1), pages)
+        k, v = view("k"), view("v")
+    elif cache is not None:
         idx = cache["index"]
         if jnp.ndim(idx) == 0:
             ck = lax.dynamic_update_slice(
@@ -275,7 +295,17 @@ def attn_apply(
     window = s.window if cross_kv is None else None
 
     sk = k.shape[1]
-    if sq == 1 or sk <= flash_threshold or cross_kv is not None:
+    if paged:
+        mask = _mask(positions, k_pos, causal=use_causal, window=window)
+        # one head at a time on its features of the merged axis: on a TPU
+        # no operand then gets a head_dim-wide minor axis, which is padded
+        # to 128 lanes and re-laid before the dot
+        hd = s.head_dim
+        o = jnp.concatenate([_sdpa_dense(
+            qh[:, :, i:i + 1], k[:, :, None, i * hd:(i + 1) * hd],
+            v[:, :, None, i * hd:(i + 1) * hd], scale=scale,
+            softcap=s.softcap, mask=mask) for i in range(s.n_kv)], axis=2)
+    elif sq == 1 or sk <= flash_threshold or cross_kv is not None:
         mask = _mask(positions, k_pos, causal=use_causal, window=window)
         o = _sdpa_dense(qh, k, v, scale=scale, softcap=s.softcap, mask=mask)
     else:
@@ -285,6 +315,14 @@ def attn_apply(
     o = o.reshape(b, sq, s.n_heads * s.head_dim).astype(x.dtype)
     y = linear_apply(p["wo"], o, abft)
     return y, new_cache
+
+
+def paged_view(pool, layer, table):
+    """``[B, pages * page_size, features]`` view of layer ``layer``'s pages
+    that ``table`` ``[B, pages]`` names in a pool ``[n_pages, layers,
+    page_size, features]``, read straight from the pool."""
+    b, n = table.shape
+    return pool[table, layer].reshape(b, n * pool.shape[2], -1)
 
 
 def make_cache(batch: int, max_len: int, n_kv: int, head_dim: int,

@@ -316,7 +316,10 @@ def _run_groups(params, x, cfg: ModelConfig, *, positions, cache,
     The cache rides in the scan CARRY (indexed by the layer counter), not in
     xs/ys: while-loop carries alias in place, so a decode step updates the
     KV cache without materializing a second stacked copy (xs->ys streaming
-    measured ~2.5x the cache size in temps).
+    measured ~2.5x the cache size in temps).  A paged block's cache (page
+    pools and a page table, `attention.attn_apply`'s paged decode) is only
+    read, each layer its own pages by the layer counter: it stays out of
+    the carry, and the block's new K/V rows come out as ys.
     """
     new_groups = []
     aux_total = jnp.zeros((), jnp.float32)
@@ -326,20 +329,27 @@ def _run_groups(params, x, cfg: ModelConfig, *, positions, cache,
     for gi, (pattern, repeats) in enumerate(cfg.layout):
         gparams = params["groups"][gi]
         gcache = cache["groups"][gi] if cache is not None else None
+        paged = {b: c for b, c in (gcache or {}).items() if "table" in c}
+        if gcache is not None:
+            gcache = {b: c for b, c in gcache.items() if b not in paged}
 
         def body(carry, xs, _pattern=pattern):
             xx, aux_acc, ok_acc, st_acc, cstack = carry
             pslice, idx = xs
+            rows = {}
             if x_sharding is not None:
                 # pin the residual stream so the auto-partitioner doesn't
                 # drift to batch-replicated layouts inside the scan
                 xx = jax.lax.with_sharding_constraint(xx, x_sharding)
             for bi, (mixer, ffn) in enumerate(_pattern):
-                if cstack is not None:
+                name = f"b{bi}"
+                if name in paged:
+                    c_in = {**paged[name], "layer": idx}
+                elif cstack is not None:
                     c_in = jax.tree.map(
                         lambda c: lax.dynamic_index_in_dim(c, idx, 0,
                                                            keepdims=False),
-                        cstack[f"b{bi}"])
+                        cstack[name])
                 else:
                     c_in = None
                 xx, c_out, aux, ok_b, st_b = _block_apply(
@@ -350,23 +360,26 @@ def _run_groups(params, x, cfg: ModelConfig, *, positions, cache,
                 ok_acc = ok_acc & ok_b
                 if st_acc is not None:
                     st_acc = merge_moe_stats(st_acc, st_b)
-                if cstack is not None and c_out is not None:
+                if name in paged:
+                    rows[name] = c_out
+                elif cstack is not None and c_out is not None:
                     cstack = dict(cstack)
-                    cstack[f"b{bi}"] = jax.tree.map(
+                    cstack[name] = jax.tree.map(
                         lambda full, new: lax.dynamic_update_index_in_dim(
                             full, new.astype(full.dtype), idx, 0),
-                        cstack[f"b{bi}"], c_out)
-            return (xx, aux_acc, ok_acc, st_acc, cstack), None
+                        cstack[name], c_out)
+            return (xx, aux_acc, ok_acc, st_acc, cstack), rows
 
         if remat:
             policy = (jax.checkpoint_policies.dots_with_no_batch_dims_saveable
                       if remat == "dots" else
                       jax.checkpoint_policies.nothing_saveable)
             body = jax.checkpoint(body, policy=policy)
-        (x, aux_total, ok_total, stats_total, new_gcache), _ = lax.scan(
+        (x, aux_total, ok_total, stats_total, new_gcache), rows = lax.scan(
             body, (x, aux_total, ok_total, stats_total, gcache),
             (gparams, jnp.arange(repeats)))
-        new_groups.append(new_gcache)
+        new_groups.append(None if new_gcache is None
+                          else {**new_gcache, **rows})
     new_cache = {"groups": new_groups} if cache is not None else None
     return x, new_cache, aux_total, ok_total, stats_total
 

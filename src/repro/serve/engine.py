@@ -203,6 +203,18 @@ _INFO0 = {"row": -1, "col": -1, "index": -1, "magnitude": 0.0,
           "corrected": False}
 
 
+def _by_path(tree) -> Dict[str, object]:
+    """The leaves of ``tree`` keyed by their keystr paths."""
+    return {jax.tree_util.keystr(path): x for path, x in
+            jax.tree_util.tree_flatten_with_path(tree)[0]}
+
+
+def _is_block(x) -> bool:
+    """A block's cache in a cache tree: a dict of leaves."""
+    return isinstance(x, dict) and all(
+        isinstance(v, jax.ShapeDtypeStruct) for v in x.values())
+
+
 class ServeEngine:
     def __init__(self, cfg: ModelConfig, params, *, slots: int = 4,
                  max_len: int = 256, abft_mode: str = "off",
@@ -305,9 +317,10 @@ class ServeEngine:
             self._arm_kv()
 
         if mesh is not None:
+            self._state_sh, new_sh = self._decode_shardings()
             in_sh = (self._param_sh, self._tok_sh, self._pos_sh,
-                     self._cache_sh)
-            out_sh = (self._rep, self._cache_sh, self._rep,
+                     self._state_sh)
+            out_sh = (self._rep, new_sh, self._rep,
                       {k: self._rep for k in _INFO0})
             self._decode = jax.jit(self.serve_decode, in_shardings=in_sh,
                                    out_shardings=out_sh)
@@ -382,17 +395,29 @@ class ServeEngine:
 
     # -- internals --------------------------------------------------------------
     def _place(self):
-        """(tokens, pos, cache) re-placed onto their program shardings.
+        """(tokens, pos, state) re-placed onto their program shardings;
+        ``state`` is what the decode program reads besides the params
+        (`_decode_state`).
 
         Host-side slot bookkeeping (`.at[s].set` scatters, eager argmax
         outputs) commits these arrays to whatever sharding the eager ops
         produced; pjit matches input shardings strictly, so re-place before
         every compiled call (no-op when already placed)."""
+        state = self._decode_state()
         if self.mesh is None:
-            return self.tokens, self.pos, self.cache
+            return self.tokens, self.pos, state
         return (jax.device_put(self.tokens, self._tok_sh),
                 jax.device_put(self.pos, self._pos_sh),
-                jax.device_put(self.cache, self._cache_sh))
+                jax.device_put(state, self._state_sh))
+
+    def _decode_state(self):
+        """The decode program's state argument: the dense cache."""
+        return self.cache
+
+    def _decode_shardings(self):
+        """Shardings of the decode program's state argument and of the
+        state it returns."""
+        return self._cache_sh, self._cache_sh
 
     def _fresh_cache(self):
         cache = tf.init_cache(self.cfg, self.slots, self.max_len)
@@ -567,13 +592,19 @@ class ServeEngine:
         return bool(self.queue)
 
     def _pre_decode(self):
-        """Before each compiled decode call (paged engine: materialize the
-        dense working cache from the page pools)."""
+        """Before each compiled decode call (paged engine: place the page
+        table on the device when it changed)."""
 
-    def _post_decode(self):
-        """After the decode mutated the cache, before positions advance.
-        Contiguous engine: whole-cache re-arm — PR 6 granularity; the
-        paged engine overrides this with per-page write-back + re-arm."""
+    def _decode_attrs(self) -> Dict[str, int]:
+        """Attrs of the ``serve/decode_step`` span beyond ``active``."""
+        return {}
+
+    def _post_decode(self, new_cache):
+        """After the decode, before positions advance: keep the state it
+        returned.  Contiguous engine: the new cache, whole-cache re-arm;
+        the paged engine overrides this with per-page write-back +
+        re-arm."""
+        self.cache = new_cache
         if self.scrub_every and not self._warming:
             self._arm_kv()  # re-arm: the decode mutated every live slot
 
@@ -589,12 +620,6 @@ class ServeEngine:
     def _write_pages(self, s: int, cache_tree, start: int, end: int):
         """Persist positions [start, end) of a 1-slot prefill cache (paged
         engine; the contiguous engine's slot scatter already holds them)."""
-
-    def _paged_leaf(self, path) -> bool:
-        """Is this cache leaf stored in pages (which `_pre_decode` gathers
-        into the dense cache before every decode)?  The contiguous engine
-        pages nothing."""
-        return False
 
     def _admit(self):
         admitted = False
@@ -658,8 +683,6 @@ class ServeEngine:
             key = getattr(path[-1], "key", None)
             if key == "index":
                 return big.at[..., s].set(plen)
-            if self._paged_leaf(path):
-                return big
             # leading dims: [repeats, B(slots), ...] <- [repeats, 1, ...]
             return big.at[:, s].set(small[:, 0].astype(big.dtype))
 
@@ -744,22 +767,23 @@ class ServeEngine:
         with obs.span("serve/kv_gather", active=n_active):
             self._pre_decode()
         ev: Optional[SDCEvent] = None
-        with obs.span("serve/decode_step", active=n_active) as sp:
+        with obs.span("serve/decode_step", active=n_active,
+                      **self._decode_attrs()) as sp:
             if self.sdc is not None and not self._warming:
                 fired = self.sdc.check(self.stats.decode_steps)
                 if fired is not None:
                     shard, delta = fired
                     ev = SDCEvent(step=self.stats.decode_steps, shard=shard,
                                   delta=delta)
-            tokens, pos, cache = self._place()
+            tokens, pos, state = self._place()
             if ev is not None:
-                logits, self.cache, ok, info = self._decode_drill(
-                    self.params, tokens, pos, cache,
+                logits, new_state, ok, info = self._decode_drill(
+                    self.params, tokens, pos, state,
                     jnp.asarray(ev.shard, jnp.int32),
                     jnp.asarray(ev.delta, jnp.float32))
             else:
-                logits, self.cache, ok, info = self._decode(
-                    self.params, tokens, pos, cache)
+                logits, new_state, ok, info = self._decode(
+                    self.params, tokens, pos, state)
             next_tok = jnp.argmax(logits, axis=-1).astype(jnp.int32)
             jax.block_until_ready(next_tok)
         wall = sp.dur_s
@@ -808,7 +832,7 @@ class ServeEngine:
         pages = self._writeback_pages(n_active)
         with obs.span("serve/kv_writeback", pages=pages,
                       host_reads=min(pages, 1)):
-            self._post_decode()
+            self._post_decode(new_state)
         with obs.span("serve/emit", active=n_active):
             self._emit(next_tok, finished)
 
@@ -848,22 +872,24 @@ class PagedServeEngine(ServeEngine):
     """`ServeEngine` on a paged/block KV cache (serve.paged_kv) with prefix
     caching, chunked prefill, and an optional SLO-aware scheduler.
 
-    The page pools are the AUTHORITATIVE storage: every decode step gathers
-    them into the fixed-shape dense cache the inherited compiled programs
-    consume (`_pre_decode`), and writes each slot's freshly decoded K/V
-    back into its page afterwards (`_post_decode`) — re-arming exactly the
-    pages it touched instead of the whole cache (the PR 6 scrub-unit fix).
-    A retiring slot frees its pages (zero-at-free), and the at-rest scrub
-    verifies/repairs at page granularity via the pool's erasure sum.
+    The page pools are the ONLY storage of the K/V leaves: the compiled
+    decode reads them in place, each layer its own pages through the page
+    table (`models.attention.paged_view`), and returns just the new K/V
+    rows, which `_post_decode` writes into their pages — re-arming exactly
+    the pages it touched instead of the whole cache (the scrub unit is
+    the page).  ``self.cache`` holds only the leaves that are not paged
+    (recurrent state), keyed by path.  A retiring slot frees its pages
+    (zero-at-free), and the at-rest scrub verifies/repairs at page
+    granularity via the pool's erasure sum.
 
     Decode parity: with ``chunk_prefill=0`` and no prefix hit, admission
-    runs the parent's compiled prefill program verbatim and the gathered
-    dense cache differs from the contiguous engine's only at causally
-    masked positions (zeros vs prefill pad garbage) — decode logits, and
-    therefore the emitted token streams, are bit-identical
-    (tests/test_traffic.py).  Chunked and prefix-shared prefills change
-    the prefill computation's shape, so their guarantee is on the argmax
-    token stream, not logits bits.
+    runs the parent's compiled prefill program verbatim, and the decode's
+    per-layer view of the pages differs from the contiguous engine's cache
+    only at causally masked positions (zeros vs prefill pad garbage) —
+    decode logits, and therefore the emitted token streams, are
+    bit-identical (tests/test_traffic.py).  Chunked and prefix-shared
+    prefills change the prefill computation's shape, so their guarantee is
+    on the argmax token stream, not logits bits.
 
     ``scheduler``: an `SLOScheduler` (serve.scheduler) takes over queueing
     — `submit()` routes through its admission control (rejections land in
@@ -891,21 +917,42 @@ class PagedServeEngine(ServeEngine):
 
     # -- paged storage ---------------------------------------------------------
     def _fresh_cache(self):
+        """Build the page pools of the sequence-indexed K/V leaves
+        (``[repeats, slots, max_len, *tail]`` in the contiguous layout) and
+        return the leaves of the blocks that have none (recurrent state:
+        mamba, xLSTM), dense and keyed by path."""
         from repro.serve.paged_kv import PagedKVCache
-        dense = super()._fresh_cache()
-        shapes = {}
-        for path, x in jax.tree_util.tree_flatten_with_path(dense)[0]:
-            # paged leaves: per-slot sequence-indexed float K/V, i.e.
-            # [repeats, slots, max_len, *tail]; recurrent state (mamba,
-            # xLSTM) has no max_len axis and stays dense-only
-            if (jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= 4
-                    and x.shape[1] == self.slots
-                    and x.shape[2] == self.max_len):
-                shapes[jax.tree_util.keystr(path)] = (x.shape, x.dtype)
+        struct = jax.eval_shape(
+            lambda: tf.init_cache(self.cfg, self.slots, self.max_len))
+        self._cache_struct = struct
+        flat = jax.tree_util.tree_flatten_with_path(struct)[0]
+        shapes = {jax.tree_util.keystr(path): (x.shape, x.dtype)
+                  for path, x in flat
+                  if (jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= 4
+                      and x.shape[1] == self.slots
+                      and x.shape[2] == self.max_len)}
+        self.kv = None                    # the old pools go first
         self.kv = PagedKVCache(shapes, slots=self.slots,
                                max_len=self.max_len,
                                page_size=self.page_size,
                                max_prefixes=self.max_prefixes)
+        self._table_host = self.kv.table.copy()
+        self._table = jnp.asarray(self._table_host)
+        # a block with a paged leaf decodes from pages alone (its index
+        # leaf is the slot's position, which the decode is given)
+        paged = {path[:-1] for path, _ in flat
+                 if jax.tree_util.keystr(path) in shapes}
+        keep = {jax.tree_util.keystr(path) for path, _ in flat
+                if path[:-1] not in paged}
+        if not keep:
+            return {}
+        # jitted, so that the paged leaves are never built
+        dense = jax.jit(lambda: {k: v for k, v in _by_path(
+            tf.init_cache(self.cfg, self.slots, self.max_len)).items()
+            if k in keep})()
+        if self._cache_sh is not None:
+            dense = jax.device_put(dense, {k: v for k, v in _by_path(
+                self._cache_sh).items() if k in keep})
         return dense
 
     def _arm_kv(self):
@@ -917,33 +964,72 @@ class PagedServeEngine(ServeEngine):
                                      page=page, repaired=True))
 
     def _pre_decode(self):
-        flat, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
-        leaves = []
-        for path, x in flat:
-            key = jax.tree_util.keystr(path)
-            leaves.append(self.kv.gather(key) if key in self.kv.pools else x)
-        self.cache = jax.tree_util.tree_unflatten(treedef, leaves)
+        # the table changes at admission, retirement and a page allocated
+        # by a write: place it only then
+        if not np.array_equal(self._table_host, self.kv.table):
+            self._table_host = self.kv.table.copy()
+            self._table = jnp.asarray(self._table_host)
 
-    def _paged_leaf(self, path) -> bool:
-        return jax.tree_util.keystr(path) in self.kv.pools
+    def _decode_state(self):
+        return {"pools": dict(self.kv.pools), "table": self._table,
+                "dense": self.cache}
+
+    def _decode_shardings(self):
+        # pools and table replicated: every device reads any slot's pages
+        dense = {k: v for k, v in _by_path(self._cache_sh).items()
+                 if k in self.cache}
+        state = {"pools": {k: self._rep for k in self.kv.pools},
+                 "table": self._rep, "dense": dense}
+        return state, {**{k: self._rep for k in self.kv.pools}, **dense}
+
+    def _decode_attrs(self) -> Dict[str, int]:
+        # table entries naming a live page, and entries the decode reads
+        return {"pages_live": int(np.count_nonzero(self.kv.table)),
+                "pages_read": int(self.kv.table.size)}
+
+    def serve_decode(self, params, tokens, pos, state):
+        return self._paged_decode(params, tokens, pos, state, None)
+
+    def serve_decode_drill(self, params, tokens, pos, state, shard, delta):
+        return self._paged_decode(params, tokens, pos, state, (shard, delta))
+
+    def _paged_decode(self, params, tokens, pos, state, inject):
+        """The decode over the pools in place: returns the logits, the
+        paged leaves' new rows ``[repeats, slots, 1, *tail]`` and the
+        other leaves' new state, keyed by path, and the reduction's
+        verdict."""
+        pools, dense = state["pools"], state["dense"]
+
+        def block(path, c):
+            keys = {n: jax.tree_util.keystr(path + (
+                jax.tree_util.DictKey(n),)) for n in c}
+            if not any(k in pools for k in keys.values()):
+                return {n: dense[k] for n, k in keys.items()}
+            return {**{n: pools[k] for n, k in keys.items() if k in pools},
+                    "table": state["table"]}
+
+        cache = jax.tree_util.tree_map_with_path(
+            block, self._cache_struct, is_leaf=_is_block)
+        logits, new_cache, ok, info = self._decode_core(
+            params, tokens, pos, cache, inject)
+        return logits, _by_path(new_cache), ok, info
 
     def _paged_leaves(self, tree) -> Dict[str, jax.Array]:
-        return {jax.tree_util.keystr(path): x for path, x in
-                jax.tree_util.tree_flatten_with_path(tree)[0]
-                if self._paged_leaf(path)}
+        return {k: x for k, x in _by_path(tree).items() if k in self.kv.pools}
 
-    def _post_decode(self):
+    def _post_decode(self, new_state):
         # page-granular write-back + re-arm: ONE page per leaf per active
-        # slot (the decode wrote exactly position pos[s] = prompt + output
-        # - 1), all slots and leaves in one batch padded to `slots`
-        writes = [(s, p, 1, s, p) for s, p in
+        # slot (the decode's row for slot s lands at pos[s] = prompt +
+        # output - 1), all slots and leaves in one batch padded to `slots`
+        self.cache = {k: new_state[k] for k in self.cache}
+        writes = [(s, p, 1, s, 0) for s, p in
                   ((s, len(r.prompt) + len(r.output) - 1)
                    for s, r in enumerate(self.active) if r is not None)]
         if not writes:
             return
         self.kv.begin_mutation()
-        self.kv.write_batch(self._paged_leaves(self.cache), writes,
-                            kind="decode", pad_to=self.slots)
+        self.kv.write_batch({k: new_state[k] for k in self.kv.pools},
+                            writes, kind="decode", pad_to=self.slots)
 
     def _writeback_pages(self, n_active: int) -> int:
         return n_active * len(self.kv.pools)
@@ -1036,19 +1122,21 @@ class PagedServeEngine(ServeEngine):
         """Dense 1-slot cache for the chunk program: this slot's pages
         gathered, non-paged leaves sliced from the engine cache, block
         indices set to the chunk's start position."""
-        flat, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
-        leaves = []
-        for path, x in flat:
+        def leaf(path, x):
             key = jax.tree_util.keystr(path)
             if key in self.kv.pools:
-                leaves.append(self.kv.gather_slot(key, s))
-            elif getattr(path[-1], "key", None) == "index":
-                leaves.append(jnp.full((x.shape[0],), start, jnp.int32))
-            elif x.ndim >= 2 and x.shape[1] == self.slots:
-                leaves.append(x[:, s:s + 1])
-            else:
-                leaves.append(x)
-        return jax.tree_util.tree_unflatten(treedef, leaves)
+                return self.kv.gather_slot(key, s)
+            if getattr(path[-1], "key", None) == "index":
+                return jnp.full((x.shape[0],), start, jnp.int32)
+            return self.cache[key][:, s:s + 1]
+        return jax.tree_util.tree_map_with_path(leaf, self._cache_struct)
+
+    def _scatter_slot(self, s: int, small_cache, plen: int):
+        # the paged leaves went to pages (`_write_pages`); the others carry
+        # into slot s
+        small = _by_path(small_cache)
+        self.cache = {k: x.at[:, s].set(small[k][:, 0].astype(x.dtype))
+                      for k, x in self.cache.items()}
 
     def _get_chunk(self, bucket: int):
         if bucket not in self._chunk_progs:

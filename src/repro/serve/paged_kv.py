@@ -1,13 +1,14 @@
 """Paged (block) KV cache: fixed-size pages as the unified protection unit.
 
 vLLM-style memory layout for the serving engines: every float KV-cache
-leaf gets a page pool ``[n_pages, page_size, repeats, features]`` (the
+leaf gets a page pool ``[n_pages, repeats, page_size, features]`` (the
 leaf's tail flattened into features) and all
 leaves share ONE host-side page table ``[slots, pages_per_slot]`` mapping
 each slot's logical pages onto physical pages.  Physical page 0 is the
 reserved immutable **zero page** — unallocated table entries point at it,
-so a gathered dense cache is exactly zero beyond every slot's write head
-(which the causal mask discards; see the decode-parity note below).
+so a page read through the table is exactly zero beyond every slot's
+write head (which the causal mask discards; see the decode-parity note
+below).
 
 The page is also the repo's ABFT unit for serving memory, replacing the
 per-slot fingerprints from PR 6 whose scrub unit was the whole slot:
@@ -15,14 +16,16 @@ per-slot fingerprints from PR 6 whose scrub unit was the whole slot:
   * **checksum-on-write, page granular** — every mutation re-arms exactly
     the pages it touched: a per-(leaf, page) float64 scalar fingerprint
     (detect + locate) and a per-leaf float64 *elementwise* page sum
-    ``esum[o, r, f] = sum_p pool[p, o, r, f]`` (the erasure row
+    ``esum[r, o, f] = sum_p pool[p, r, o, f]`` (the erasure row
     that repairs).  The engine's decode writes ONE token per slot per
     step, so the incremental update is always ``+= new`` — the write-once
     invariant (cells are zero until their first and only write between
     free/zero cycles) makes arming O(page) instead of O(cache).
-  * **verify-on-read** — `verify()` recomputes page fingerprints and
-    returns the tripped (leaf, page) pairs; NaN-poisoned pages (a bit-30
-    flip near 1.0) compare as tripped, not silently equal.
+  * **verify-on-read** — `verify()` recomputes the fingerprints of the
+    live pages, read to the host ``SCRUB_PAGES`` at a time, checks on the
+    device that every other page is exactly zero, and returns the tripped
+    (leaf, page) pairs; NaN-poisoned pages (a bit-30 flip near 1.0)
+    compare as tripped, not silently equal.
   * **erasure repair** — `repair()` rebuilds a page as
     ``esum - sum(other live pages)`` in float64 (single-page fault model,
     the f=1 erasure code of the diskless family applied to serving DRAM).
@@ -47,21 +50,28 @@ slots, prefill: the prompt bucket, free: pages per slot) so that a warmed
 engine compiles nothing new; padding entries name an out-of-range page,
 which the gathers fill with 0 and the scatters drop.
 
-The pools are page-major with the tail flattened because of the TPU's
-tiled layouts: in the dense order ``[repeats, n_pages, page_size, 2, 64]``
-the compiler lays the page axis innermost (a 64-wide minor dimension
-would be padded to 128), and every page update, zeroing or gather then
-re-lays the whole pool, twice (on a v5e, about 1 GB of copies per 0.5 GB
-pool).  ``[n_pages, page_size, repeats, features]`` keeps whole pages
-contiguous, so a write is an in-place scatter of ``[repeats, features]``
-rows.
+The layout serves the TPU's tiled memory.  Within a page the layers are
+major, so one layer's page ``[page_size, features]`` is one contiguous
+tile (16 x 128 in bf16 for qwen2-0.5b), which the paged decode reads
+whole, and a page of every layer is one contiguous block, which the
+programs above write, zero and read whole.  A write reads the pages it
+touches, sets its tokens in them and writes the pages back: a scatter of
+single tokens across every layer would make the compiler re-lay the
+whole pool (about 1 GB of copies per 0.5 GB pool on a v5e), as would the
+unflattened order ``[..., 2, 64]`` with its 64-wide minor dimension.
 
-Decode parity: the dense cache `gather()` materializes differs from the
-contiguous engine's only at causally-masked positions (zeros here, prefill
-pad garbage there); `_sdpa_dense` masks with ``where(mask, s, NEG_INF)``
-before the softmax, so those positions carry exactly zero weight either
-way and the paged engine's decode logits are bit-identical
-(tests/test_traffic.py golden-parity, clean and drilled).
+Decode reads the pools in place: the compiled decode
+(`serve.engine.PagedServeEngine`) reads each layer's pages through the
+page table (`models.attention.paged_view`), sets the fresh token in that
+per-layer view only and returns the new K/V rows, which `write_batch`
+puts into the pools.  Decode parity: that view differs from the
+contiguous engine's cache only at causally-masked positions (zeros here,
+prefill pad garbage there); `_sdpa_dense` masks with ``where(mask, s,
+NEG_INF)`` before the softmax, so those positions carry exactly zero
+weight either way and the paged engine's decode logits are bit-identical
+(tests/test_traffic.py golden-parity, clean and drilled).  `gather()`
+builds the same view for every slot and layer at once: the tests' oracle
+and the chunked prefill's one-slot cache.
 """
 from __future__ import annotations
 
@@ -116,18 +126,6 @@ class PagedStats:
 _PAD = np.iinfo(np.int32).max   # index of a padding entry: out of range
 
 
-def _rows_at(src, pos):
-    """``src[:, s, pos[s]]`` for every slot s, ``[repeats, B, *tail]``: the
-    bit pattern at each slot's position, selected over the whole sequence
-    axis.  This reads the cache in the layout the decode program left it
-    in, where a gather would first re-lay all of it."""
-    uint = jnp.dtype(f"uint{8 * src.dtype.itemsize}")
-    hit = jnp.arange(src.shape[2])[None, None, :] == pos[None, :, None]
-    hit = hit.reshape(hit.shape + (1,) * (src.ndim - 3))
-    bits = jnp.where(hit, jax.lax.bitcast_convert_type(src, uint), 0)
-    return jax.lax.bitcast_convert_type(jnp.max(bits, axis=2), src.dtype)
-
-
 def _page_sums(pages: np.ndarray, ex: ThreadPoolExecutor) -> np.ndarray:
     """Float64 sum of each page of a host pool ``[n_pages, ...]``: values
     widened exactly to float32, summed in float64, blocks of pages on the
@@ -142,28 +140,27 @@ def _page_sums(pages: np.ndarray, ex: ThreadPoolExecutor) -> np.ndarray:
 # the jitted programs take these names (the trace's "XLA Modules" line
 # shows jit_serve_kv_write, jit_serve_kv_zero, jit_serve_kv_gather)
 @functools.partial(jax.jit, donate_argnums=0)
-def serve_kv_write(pools, srcs, idx):
-    """One batch of token writes into every pool, in place.
+def serve_kv_write(pools, srcs, idx, pages):
+    """One batch of token writes into every pool, in place, a page at a
+    time.
 
-    ``idx`` is ``[4, N]`` int32: token i goes from ``src[:, idx[0, i],
-    idx[1, i]]`` (a dense ``[repeats, B, len, *tail]`` source; when B > 1,
-    at most one token per slot) to ``pool[idx[2, i], idx[3, i]]``
-    (physical page, offset).  Returns the pools, the values written and
-    the values they replaced, each ``[N, repeats, features]``."""
-    src_slot, src_pos, phys, off = idx
+    ``pages`` ``[P]`` are the physical pages the batch writes (padding:
+    out of range).  ``idx`` is ``[4, N]`` int32: token i goes from
+    ``src[:, idx[0, i], idx[1, i]]`` (a dense ``[repeats, B, len, *tail]``
+    source: a prefill's one-slot cache, or the decode's new rows, ``len``
+    1) to offset ``idx[3, i]`` of page ``pages[idx[2, i]]``.  Returns the
+    pools, the values written and the values they replaced, each ``[N,
+    repeats, features]``."""
+    src_slot, src_pos, at, off = idx
     new_pools, news, olds = [], [], []
     for pool, src in zip(pools, srcs):
-        if src.shape[1] == 1:
-            rows = src[:, 0, src_pos]
-        else:
-            pos = jnp.zeros(src.shape[1], jnp.int32).at[src_slot].set(
-                src_pos, mode="drop")
-            rows = jnp.take(_rows_at(src, pos), src_slot, axis=1,
-                            mode="fill", fill_value=0)
-        new = jnp.moveaxis(rows, 1, 0).reshape(
-            (len(phys),) + pool.shape[2:]).astype(pool.dtype)
-        olds.append(pool.at[phys, off].get(mode="fill", fill_value=0))
-        new_pools.append(pool.at[phys, off].set(new, mode="drop"))
+        rows = src.at[:, src_slot, src_pos].get(mode="fill", fill_value=0)
+        new = jnp.moveaxis(rows.reshape(rows.shape[:2] + (-1,)), 1, 0)
+        new = new.astype(pool.dtype)
+        block = pool.at[pages].get(mode="fill", fill_value=0)
+        olds.append(block.at[at, :, off].get(mode="fill", fill_value=0))
+        block = block.at[at, :, off].set(new, mode="drop")
+        new_pools.append(pool.at[pages].set(block, mode="drop"))
         news.append(new)
     return tuple(new_pools), tuple(news), tuple(olds)
 
@@ -171,7 +168,7 @@ def serve_kv_write(pools, srcs, idx):
 @functools.partial(jax.jit, donate_argnums=0)
 def serve_kv_zero(pools, phys):
     """Zero pages ``phys`` ``[P]`` of every pool in place; returns the
-    pools and the pages' previous contents ``[P, page, repeats,
+    pools and the pages' previous contents ``[P, repeats, page,
     features]``."""
     olds = tuple(pool.at[phys].get(mode="fill", fill_value=0)
                  for pool in pools)
@@ -179,14 +176,34 @@ def serve_kv_zero(pools, phys):
                  for pool in pools), olds
 
 
+SCRUB_PAGES = 128
+"""Pages of every pool that one scrub read brings to the host."""
+
+
+@jax.jit
+def serve_kv_read(pools, idx):
+    """Pages ``idx`` ``[SCRUB_PAGES]`` of every pool (padding: out of
+    range, read as zero)."""
+    return tuple(pool.at[idx].get(mode="fill", fill_value=0)
+                 for pool in pools)
+
+
+@jax.jit
+def serve_kv_nonzero(pools):
+    """For every page of every pool, whether any of its values is not zero
+    (a NaN is not zero)."""
+    return tuple(jnp.any(pool != 0, axis=tuple(range(1, pool.ndim)))
+                 for pool in pools)
+
+
 @functools.partial(jax.jit, static_argnums=2)
 def serve_kv_gather(pool, table, tail):
     """Dense ``[repeats, B, len, *tail]`` view of the pages ``table``
     ``[B, pages]`` maps."""
     b, n = table.shape
-    _, ps, r, _ = pool.shape
-    dense = pool[table.reshape(-1)].reshape(b, n * ps, r, -1)
-    return jnp.moveaxis(dense, 2, 0).reshape((r, b, n * ps) + tail)
+    _, r, ps, _ = pool.shape
+    dense = jnp.moveaxis(pool[table.reshape(-1)], 1, 0)
+    return dense.reshape((r, b, n * ps) + tail)
 
 
 class PagedKVCache:
@@ -195,7 +212,7 @@ class PagedKVCache:
     ``leaf_shapes`` maps a leaf key (the engine uses jax keystr paths) to
     ``(dense_shape, dtype)`` where dense_shape is the contiguous layout
     ``[repeats, slots, max_len, *tail]`` the leaf would occupy; its pages
-    live in ``pools[key]`` ``[n_pages, page_size, repeats, features]``.
+    live in ``pools[key]`` ``[n_pages, repeats, page_size, features]``.
     """
 
     def __init__(self, leaf_shapes: Dict[str, Tuple[Sequence[int], object]],
@@ -223,7 +240,7 @@ class PagedKVCache:
                     f"*tail], got {shape}")
             repeats, tail = shape[0], shape[3:]
             self.pools[key] = jnp.zeros(
-                (self.n_pages, page_size, repeats, int(np.prod(tail))),
+                (self.n_pages, repeats, page_size, int(np.prod(tail))),
                 dtype)
             self._tails[key] = tail
 
@@ -243,6 +260,7 @@ class PagedKVCache:
             key: np.zeros(p.shape[1:], np.float64)
             for key, p in self.pools.items()}
         self.last_rearmed: List[Tuple[str, int]] = []
+        self._bufs: Dict[Tuple[int, ...], Tuple[np.ndarray, ...]] = {}
 
     # -- bookkeeping helpers ---------------------------------------------------
     def page_of(self, slot: int, pos: int) -> int:
@@ -438,25 +456,55 @@ class PagedKVCache:
         slot = np.concatenate([np.full(w[2], w[0]) for w in writes])
         pos = np.concatenate([np.arange(w[1], w[1] + w[2]) for w in writes])
         n = len(pos)
-        idx = np.zeros((4, max(n, pad_to)), np.int32)
+        width = max(n, pad_to)
+        idx = np.zeros((4, width), np.int32)
         idx[(0, 2), n:] = _PAD
         idx[0, :n] = np.concatenate([np.full(w[2], w[3]) for w in writes])
         idx[1, :n] = np.concatenate([np.arange(w[4], w[4] + w[2])
                                      for w in writes])
-        idx[2, :n] = phys = self.table[slot, pos // ps]
+        phys = self.table[slot, pos // ps]
         idx[3, :n] = off = pos % ps
+        touched, idx[2, :n] = np.unique(phys, return_inverse=True)
+        # one run of tokens spans at most this many pages; a batch of runs
+        # (the decode's, one token a slot) no more pages than tokens
+        one_run = len(writes) == 1 and all(
+            srcs[k].shape[1] == 1 for k in keys)
+        pages = np.full(min(width, -(-(width + ps - 1) // ps)) if one_run
+                        else width, _PAD, np.int32)
+        pages[:len(touched)] = touched
         pools, news, olds = serve_kv_write(
             tuple(self.pools[k] for k in keys),
-            tuple(srcs[k] for k in keys), jnp.asarray(idx))
+            tuple(srcs[k] for k in keys), jnp.asarray(idx),
+            jnp.asarray(pages))
         self.pools.update(zip(keys, pools))
+        # tokens grouped by their offset in the page, in write order
+        order = np.argsort(off, kind="stable")
+        offs, starts = np.unique(off[order], return_index=True)
+        bounds = list(zip(offs, starts, [*starts[1:], n]))
         for key, new, old in zip(keys, *jax.device_get((news, olds))):
-            d = new[:n].astype(np.float64) - old[:n].astype(np.float64)
+            d, grouped, row = self._scratch(n, new.shape[1:])
+            np.subtract(new[:n], old[:n], out=d, dtype=np.float64)
             self.page_fp[key] += np.bincount(
                 phys, d.reshape(n, -1).sum(axis=1), minlength=self.n_pages)
+            np.take(d, order, axis=0, out=grouped)
             esum = self.esum[key]
-            for o in np.unique(off):
-                esum[o] += d[off == o].sum(axis=0)
+            for o, a, b in bounds:
+                esum[:, o] += grouped[a:b].sum(axis=0, out=row)
         self._count_batch(kind)
+
+    def _scratch(self, n: int, shape: Tuple[int, ...]):
+        """Float64 buffers for the checksum update of ``n`` tokens of
+        ``shape``: the differences, the same grouped by page offset, and
+        one group's sum.  They are kept from write to write: fresh
+        temporaries of megabytes cost page faults whose number follows the
+        allocator's state, which made a prefill's page write take from 30
+        to 80 ms (TPU v5e host)."""
+        bufs = self._bufs.get(shape)
+        if bufs is None or len(bufs[0]) < n:
+            bufs = self._bufs[shape] = (
+                np.empty((n,) + shape), np.empty((n,) + shape),
+                np.empty(shape))
+        return bufs[0][:n], bufs[1][:n], bufs[2]
 
     def write(self, key: str, slot: int, start: int, vals):
         """Write ``vals`` ``[repeats, n, *tail]`` of leaf ``key`` at
@@ -473,7 +521,8 @@ class PagedKVCache:
     # -- reads -----------------------------------------------------------------
     def gather(self, key: str) -> jax.Array:
         """Dense ``[repeats, slots, max_len, *tail]`` view of every slot
-        (zero beyond each write head — the zero page)."""
+        (zero beyond each write head — the zero page): what the decode
+        reads through the table, one layer at a time."""
         return serve_kv_gather(self.pools[key], jnp.asarray(self.table),
                                self._tails[key])
 
@@ -483,22 +532,59 @@ class PagedKVCache:
                                self._tails[key])
 
     # -- verify / repair (the scrub + DRAM-recovery unit) ----------------------
+    def _read(self, phys: np.ndarray):
+        """Host copies of pages ``phys`` of every pool, ``SCRUB_PAGES`` a
+        read: yields ``(pages, {key: [len(pages), ...]})``.  One read runs
+        even for no pages (yielding nothing), so that a reset compiles the
+        program before any scrub needs it."""
+        pools = tuple(self.pools.values())
+        for a in range(0, max(len(phys), 1), SCRUB_PAGES):
+            part = phys[a:a + SCRUB_PAGES]
+            idx = np.full(SCRUB_PAGES, _PAD, np.int32)
+            idx[:len(part)] = part
+            got = jax.device_get(serve_kv_read(pools, jnp.asarray(idx)))
+            if len(part):
+                yield part, {key: g[:len(part)]
+                             for key, g in zip(self.pools, got)}
+
+    def _nonzero(self) -> Dict[str, np.ndarray]:
+        """Per leaf, ``[n_pages]``: does the page hold a value other than
+        zero?  Computed on the device; only the flags come home."""
+        flags = jax.device_get(serve_kv_nonzero(tuple(self.pools.values())))
+        return dict(zip(self.pools, flags))
+
     def arm_all(self):
-        """Full recompute of every checksum from the pools (init/reset)."""
+        """Full recompute of every checksum from the pools (init/reset):
+        every page that is live or not zero is read; the others add
+        nothing."""
+        nonzero = self._nonzero()
+        read = np.nonzero((self.refcount > 0)
+                          | np.logical_or.reduce(list(nonzero.values())))[0]
         for key, pool in self.pools.items():
-            p64 = np.asarray(pool, np.float64)
-            self.page_fp[key] = p64.reshape(self.n_pages, -1).sum(axis=1)
-            self.esum[key] = p64.sum(axis=0)
+            self.page_fp[key] = np.zeros((self.n_pages,), np.float64)
+            self.esum[key] = np.zeros(pool.shape[1:], np.float64)
+        for part, pages in self._read(read):
+            for key, pg in pages.items():
+                p64 = pg.astype(np.float64)
+                self.page_fp[key][part] = p64.reshape(len(part), -1).sum(
+                    axis=1)
+                self.esum[key] += p64.sum(axis=0)
 
     def verify(self) -> List[Tuple[str, int]]:
         """Recompute page fingerprints; returns tripped (leaf, page) pairs.
-        Every non-zero physical page is checked — a corrupted FREE page
-        (fingerprint must be 0) trips too, protecting zero-at-free."""
+        Live pages are read to the host and summed in float64; every other
+        page must be zero, which the device checks (zero-at-free), so a
+        corrupted FREE page trips too."""
         self.stats.verifies += 1
         tripped = []
-        pools = jax.device_get(self.pools)       # one read for every leaf
+        nonzero = self._nonzero()
+        live = np.nonzero(self.refcount)[0]
+        sums = {key: np.zeros((self.n_pages,), np.float64)
+                for key in self.pools}
         with ThreadPoolExecutor(min(8, os.cpu_count() or 1)) as ex:
-            sums = {key: _page_sums(pages, ex) for key, pages in pools.items()}
+            for part, pages in self._read(live):
+                for key, pg in pages.items():
+                    sums[key][part] = _page_sums(pg, ex)
         for key, fp in sums.items():
             armed = self.page_fp[key]
             diff = np.abs(fp - armed)
@@ -506,21 +592,25 @@ class PagedKVCache:
             # compares false against any threshold — count it tripped
             diff = np.where(np.isnan(diff), np.inf, diff)
             scale = float(np.max(np.abs(armed))) + 1.0
-            for phys in np.nonzero(diff > 1e-6 * scale)[0]:
+            bad = ((diff > 1e-6 * scale)
+                   | (nonzero[key] & (self.refcount == 0)))
+            for phys in np.nonzero(bad)[0]:
                 if phys:  # page 0 is immutable-zero by construction
                     tripped.append((key, int(phys)))
         return tripped
 
     def repair(self, key: str, phys: int) -> bool:
         """Erasure solve: rebuild page ``phys`` of leaf ``key`` as
-        ``esum - sum(other live pages)`` in float64 (a corrupted free page
-        rebuilds to zero: it contributes nothing to esum)."""
+        ``esum - sum(other live pages)`` in float64.  A page that is not
+        live contributes nothing to esum: it rebuilds to zero."""
         pool = self.pools[key]
-        others = [p for p in self.live_pages() if p != phys]
-        recon = self.esum[key].copy()
-        if others:
-            recon -= np.asarray(pool[np.asarray(others)],
-                                np.float64).sum(axis=0)
+        recon = np.zeros(pool.shape[1:], np.float64)
+        if self.refcount[phys]:
+            recon += self.esum[key]
+            others = np.asarray([p for p in self.live_pages() if p != phys],
+                                np.int32)
+            for _, pages in self._read(others):
+                recon -= pages[key].astype(np.float64).sum(axis=0)
         self.pools[key] = pool.at[phys].set(
             jnp.asarray(recon.astype(pool.dtype)))
         self.page_fp[key][phys] = float(recon.sum())

@@ -179,6 +179,59 @@ def test_corrupted_free_page_detected_and_rebuilt_to_zero(rs):
     ok(kv)
 
 
+def test_free_page_off_by_less_than_the_tolerance_trips(rs):
+    """A free page must be exactly zero: a value far below the page-sum
+    tolerance trips, and the repair writes zeros, not a float64
+    remainder."""
+    kv = make_kv()
+    kv.alloc_slot(0, 8)
+    kv.write("k", 0, 0, fill(rs, 8))
+    free_page = kv.free[0]
+    kv.pools["k"] = kv.pools["k"].at[free_page, 1, 2, 3].set(1e-30)
+    assert kv.verify() == [("k", free_page)]
+    kv.repair("k", free_page)
+    assert not np.any(np.asarray(kv.pools["k"][free_page]))
+    ok(kv)
+
+
+@pytest.mark.parametrize("chunk", [1, 2, 3, 128])
+def test_scrub_reads_live_pages_only_in_chunks(rs, monkeypatch, chunk):
+    """The scrub brings home the live pages, ``SCRUB_PAGES`` at a time,
+    and no free page: a flip in any live page, first chunk or last, is
+    located and rebuilt exactly."""
+    from repro.serve import paged_kv
+    monkeypatch.setattr(paged_kv, "SCRUB_PAGES", chunk)
+    reads = []
+    read = paged_kv.serve_kv_read
+    monkeypatch.setattr(paged_kv, "serve_kv_read", lambda pools, idx: (
+        reads.append(np.asarray(idx)), read(pools, idx))[1])
+    kv = make_kv()
+    kv.alloc_slot(0, 20)
+    kv.alloc_slot(2, 9)
+    kv.write("k", 0, 0, fill(rs, 20))
+    kv.write("v", 2, 0, fill(rs, 9))
+    live = kv.live_pages()
+    assert len(live) == 5
+    reads.clear()
+    assert not kv.verify()
+    got = np.concatenate(reads)
+    assert sorted(got[got < kv.n_pages].tolist()) == live
+    assert len(reads) == -(-len(live) // chunk)
+    for phys in (live[0], live[-1]):
+        golden = np.asarray(kv.pools["v"][phys]).copy()
+        kv.corrupt_page("v", phys, index=5, bit=30)
+        assert kv.scrub() == [("v", phys)]
+        np.testing.assert_array_equal(np.asarray(kv.pools["v"][phys]),
+                                      golden)
+    ok(kv)
+    # nothing live: one read of padding, so a reset compiles the program
+    for s in (0, 2):
+        kv.free_slot(s)
+    reads.clear()
+    assert not kv.verify()
+    assert len(reads) == 1 and not np.any(reads[0] < kv.n_pages)
+
+
 def test_nan_poisoned_page_trips(rs):
     kv = make_kv()
     kv.alloc_slot(0, 8)
@@ -328,16 +381,17 @@ class EagerKV(PagedKVCache):
                     phys = self._writable(slot, logical)
                     a = src_start + pos - start
                     pool = self.pools[key]
-                    seg = jnp.moveaxis(src[:, src_slot, a:a + seg_n], 1, 0)
-                    seg = seg.reshape((seg_n,) + pool.shape[2:]).astype(
+                    seg = src[:, src_slot, a:a + seg_n]
+                    seg = seg.reshape(seg.shape[:2] + pool.shape[3:]).astype(
                         pool.dtype)
-                    old64 = np.asarray(pool[phys, off:off + seg_n],
+                    old64 = np.asarray(pool[phys, :, off:off + seg_n],
                                        np.float64)
-                    self.pools[key] = pool.at[phys, off:off + seg_n].set(seg)
+                    self.pools[key] = pool.at[phys, :, off:off + seg_n].set(
+                        seg)
                     seg64 = np.asarray(seg, np.float64)
                     self.page_fp[key][phys] += float(seg64.sum()
                                                      - old64.sum())
-                    self.esum[key][off:off + seg_n] += seg64 - old64
+                    self.esum[key][:, off:off + seg_n] += seg64 - old64
                     self.last_rearmed.append((key, phys))
                     self.stats.checksum_rearms += 1
                     pos += seg_n
@@ -466,11 +520,11 @@ def test_batched_writes_match_eager_segments(seed, dtype):
                 # its contents out of esum as they stand
                 phys = own[rs.randint(len(own))]
                 seen.add("corrupt_free")
-                orig = float(np.asarray(kvs[0].pools["k"][phys, 1, 0, 2]))
+                orig = float(np.asarray(kvs[0].pools["k"][phys, 0, 1, 2]))
                 for kv in kvs:
-                    kv.pools["k"] = kv.pools["k"].at[phys, 1, 0, 2].add(4)
+                    kv.pools["k"] = kv.pools["k"].at[phys, 0, 1, 2].add(4)
                 delta = float(np.asarray(
-                    kvs[0].pools["k"][phys, 1, 0, 2])) - orig
+                    kvs[0].pools["k"][phys, 0, 1, 2])) - orig
                 both(lambda kv: kv.free_slot(s))
                 _assert_same(*kvs)
                 for kv in kvs:
@@ -479,7 +533,7 @@ def test_batched_writes_match_eager_segments(seed, dtype):
                     gap = (np.asarray(kv.pools["k"], np.float64).sum(axis=0)
                            - kv.esum["k"])
                     want = np.zeros_like(gap)
-                    want[1, 0, 2] = delta
+                    want[0, 1, 2] = delta
                     np.testing.assert_allclose(gap, want, atol=1e-9)
                     kv.arm_all()
             else:
@@ -493,6 +547,43 @@ def test_batched_writes_match_eager_segments(seed, dtype):
             assert kv.checksums_consistent(), op
     assert kvs[0].stats.prefix_evictions and kvs[0].stats.cow_copies
     assert (True, True, True) in seen and "corrupt_free" in seen
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+def test_checksum_update_is_the_float64_formula_in_kept_buffers(dtype):
+    """The float64 update of a write (``d = new - old``, page sums by
+    bincount, ``esum[:, o] += d[off == o].sum(axis=0)``) comes out bit for
+    bit on values that are not integers, from buffers that later writes
+    of as many tokens or fewer reuse."""
+    dt = np.float32 if dtype == "float32" else jnp.bfloat16
+    rng = np.random.default_rng(7)
+    kv = PagedKVCache({"k": ((2, SLOTS, MAX_LEN, 4), dt)}, slots=SLOTS,
+                      max_len=MAX_LEN, page_size=PS)
+    fp = np.zeros(kv.n_pages)
+    esum = np.zeros((2, PS, 4))
+
+    def write(src, writes, pad_to):
+        vals = np.asarray(jnp.asarray(src, dt)).astype(np.float64)
+        kv.write_batch({"k": jnp.asarray(src, dt)}, writes, kind="prefill",
+                       pad_to=pad_to)
+        for slot, start, n, s_slot, s_start in writes:
+            pos = np.arange(start, start + n)
+            d = np.moveaxis(vals[:, s_slot, s_start:s_start + n], 1, 0)
+            phys = kv.table[slot, pos // PS]
+            fp[:] += np.bincount(phys, d.reshape(n, -1).sum(axis=1),
+                                 minlength=kv.n_pages)
+            for o in np.unique(pos % PS):
+                esum[:, o] += d[pos % PS == o].sum(axis=0)
+
+    scale = 10.0 ** rng.uniform(-3, 3, (1, 1, 27, 1))
+    write(rng.standard_normal((2, 1, 27, 4)) * scale, [(0, 3, 27, 0, 0)], 32)
+    bufs = kv._bufs[(2, 4)]
+    # one token a slot from a source of every slot, as the decode writes
+    write(rng.standard_normal((2, SLOTS, 1, 4)),
+          [(s, 30, 1, s, 0) for s in range(SLOTS)], SLOTS)
+    assert all(a is b for a, b in zip(kv._bufs[(2, 4)], bufs))
+    np.testing.assert_array_equal(kv.page_fp["k"], fp)
+    np.testing.assert_array_equal(kv.esum["k"], esum)
 
 
 def test_write_programs_trace_once_per_padded_shape(rs):

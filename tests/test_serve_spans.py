@@ -63,6 +63,16 @@ def test_one_gather_decode_writeback_emit_per_decode_step(run):
         assert e.attrs["host_reads"] == 1   # one read per write batch
 
 
+def test_decode_step_span_counts_pages_live_and_read(run):
+    """The decode reads every table entry; the live ones name a page."""
+    eng, evs = run
+    steps = _named(evs, "serve/decode_step")
+    assert len(steps) == eng.stats.decode_steps
+    for e in steps:
+        assert e.attrs["pages_read"] == eng.slots * eng.kv.pages_per_slot
+        assert 1 <= e.attrs["pages_live"] <= e.attrs["pages_read"]
+
+
 def test_scrub_span_on_scrub_steps(run):
     eng, evs = run
     scrubs = _named(evs, "serve/scrub")

@@ -182,3 +182,60 @@ def test_moe_cell_train_step_fits_one_v5e(topo, monkeypatch):
           f"{temps_gib:.2f} GiB")
     assert "abft_grouped_matmul_pallas" in compiled.as_text()
     assert state_gib + temps_gib < 15.0, (state_gib, temps_gib)
+
+
+@pytest.mark.parametrize("program", ["decode_write", "prefill_write", "zero"])
+def test_paged_kv_programs_update_the_pools_in_place(one_chip, program):
+    """chat-open's page programs on its pools (32 slots x 2560 tokens,
+    pages of 16, qwen2-0.5b's 24 layers of 2 x 64 K/V features): the
+    donated pools are updated in place, with no copy of a pool in the
+    optimised program (a relayout of the pools costs about 1 GB of copies
+    each)."""
+    import re
+
+    from repro.serve.paged_kv import serve_kv_write, serve_kv_zero
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = (1 + 32 * 2560 // 16, 24, 16, 128)
+    pools = (S(pool, jnp.bfloat16), S(pool, jnp.bfloat16))
+    i32 = jnp.int32
+    if program == "zero":
+        low = serve_kv_zero.lower(pools, S((160,), i32))
+    else:
+        n, src, pages = ((32, (24, 32, 1, 2, 64), 32)
+                         if program == "decode_write" else
+                         (512, (24, 1, 2560, 2, 64), 33))
+        low = serve_kv_write.lower(pools, (S(src, jnp.bfloat16),) * 2,
+                                   S((4, n), i32), S((pages,), i32))
+    compiled = low.compile()
+    shape = "bf16[" + ",".join(map(str, pool)) + "]"
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= \S+ copy(-start)?\(", line) and shape in line]
+    assert not copies, copies[:2]
+    alias = compiled.memory_analysis().alias_size_in_bytes
+    assert alias >= 2 * 2 * 24 * (1 + 32 * 160) * 16 * 128
+
+
+@pytest.mark.parametrize("program", ["read", "nonzero"])
+def test_scrub_programs_read_the_pools_without_a_copy(one_chip, program):
+    """The scrub's programs on chat-open's pools: a read of
+    ``SCRUB_PAGES`` pages and the free pages' zero check take no copy of
+    a pool, and bring home only the pages or one flag a page."""
+    import re
+
+    from repro.serve.paged_kv import (SCRUB_PAGES, serve_kv_nonzero,
+                                      serve_kv_read)
+    S = lambda shape, dt: jax.ShapeDtypeStruct(shape, dt, sharding=one_chip)
+    pool = (1 + 32 * 2560 // 16, 24, 16, 128)
+    pools = (S(pool, jnp.bfloat16), S(pool, jnp.bfloat16))
+    if program == "read":
+        low = serve_kv_read.lower(pools, S((SCRUB_PAGES,), jnp.int32))
+        out = (SCRUB_PAGES,) + pool[1:]
+    else:
+        low = serve_kv_nonzero.lower(pools)
+        out = pool[:1]
+    assert [o.shape for o in low.out_info] == [out, out]
+    compiled = low.compile()
+    shape = "bf16[" + ",".join(map(str, pool)) + "]"
+    copies = [line for line in compiled.as_text().splitlines()
+              if re.search(r"= \S+ copy(-start)?\(", line) and shape in line]
+    assert not copies, copies[:2]
