@@ -2,11 +2,11 @@
 SDC-drill recovery accounting — the serving-side analogue of the paper's
 Table 2 (FT overhead on a live workload) and §4.3 (fault-injection cost).
 
-Warm-up discipline: each engine's two compiled programs (prefill bucket +
-decode_B) are warmed via `ServeEngine.warm()` with a SINGLE dummy request
-(and the drill decode variant where one can fire), then the engine is
-`reset()` and the real workload is timed — no real-request decode steps are
-wasted on warming, and compile time never pollutes the timed rows.
+Warm-up discipline: each engine's compiled programs (prefill bucket +
+decode_B) are warmed via `PagedServeEngine.warm()` with a SINGLE dummy
+request (and the drill decode variant where one can fire), then the engine
+is `reset()` and the real workload is timed — no real-request decode steps
+are wasted on warming, and compile time never pollutes the timed rows.
 """
 import time
 
@@ -17,7 +17,7 @@ def run():
     from repro.configs.base import smoke_config
     from repro.ft.failures import SDCInjector, SDCPlan
     from repro.models import transformer as tf
-    from repro.serve.engine import Request, ServeEngine
+    from repro.serve.engine import PagedServeEngine, Request
 
     lines = []
     cfg = smoke_config("qwen2-0.5b")
@@ -38,7 +38,7 @@ def run():
 
     times = {}
     for mode in ("off", "verify"):
-        us_tok, finished, s = drive(ServeEngine(
+        us_tok, finished, s = drive(PagedServeEngine(
             cfg, params, slots=2, max_len=64, abft_mode=mode))
         times[mode] = us_tok
         lines.append((
@@ -49,7 +49,7 @@ def run():
                   f"verify_vs_off={100*times['verify']/times['off']:.1f}%"))
 
     # --- protected decode-path reduction: clean overhead ----------------------
-    us_clean, _, s_clean = drive(ServeEngine(
+    us_clean, _, s_clean = drive(PagedServeEngine(
         cfg, params, slots=2, max_len=64, abft_reduce="correct"))
     assert s_clean.detections == 0, "clean protected run must see no faults"
     lines.append((
@@ -60,7 +60,7 @@ def run():
 
     # --- SDC drill: detection/correction + recovery latency -------------------
     sdc = SDCInjector(SDCPlan(((2, 0, 1e4), (7, 0, -3e4))))
-    us_drill, fin_drill, s_drill = drive(ServeEngine(
+    us_drill, fin_drill, s_drill = drive(PagedServeEngine(
         cfg, params, slots=2, max_len=64, abft_reduce="correct", sdc=sdc))
     assert s_drill.detections == len(s_drill.events) == 2
     assert s_drill.corrections == 2
@@ -80,9 +80,9 @@ def run():
 
     # --- at-rest scrubber: KV/params verify-on-read cost ----------------------
     # scrub_every=1 is the worst case (every decode step re-verifies the
-    # per-slot KV fingerprints + the params scalar sums); production would
+    # KV page checksums + the params scalar sums); production would
     # scrub every N steps, so the marginal per-step cost divides by N.
-    us_scrub, _, s_scrub = drive(ServeEngine(
+    us_scrub, _, s_scrub = drive(PagedServeEngine(
         cfg, params, slots=2, max_len=64, scrub_every=1))
     assert s_scrub.detections == 0, "clean scrubbed run must see no faults"
     assert s_scrub.scrub_checks > 0

@@ -15,8 +15,7 @@ vocab 151936, bf16; weights random from a seed):
          `repro.launch.serve.run` with ABFT-verified projections and the
          checksum-corrected logits reduction.  Checks: the fused Pallas
          kernel was traced; the same requests with one SDC drill are
-         detected and corrected with identical tokens; `PagedServeEngine`
-         emits the same tokens as `ServeEngine`.
+         detected and corrected with identical tokens.
   train  3 steps through `repro.launch.train.run` with ABFT verify; the
          losses must be finite.
 
@@ -25,7 +24,7 @@ Four chips (only the path that spans chips, and what it is compared with):
          fused Pallas local update on 4096^2 local blocks; clean, with one
          device failure, and with one bit flip — each against `jnp.dot` on
          one device at the same (default) precision.
-  serve  `ServeEngine` on a (data=2, model=2) mesh with the corrected
+  serve  `PagedServeEngine` on a (data=2, model=2) mesh with the corrected
          logits reduction and one drill, against the same requests on a
          one-device mesh (fp32 weights, so the two layouts' summation
          orders cannot flip an argmax).
@@ -114,7 +113,6 @@ def phase_serve():
     from repro import obs
     from repro.ft.failures import SDCPlan
     from repro.launch import serve
-    from repro.serve.engine import PagedServeEngine, Request
 
     kw = dict(smoke=False, requests=REQUESTS, slots=SLOTS,
               prompt_len=PROMPT_LEN, gen=GEN, abft_mode="verify",
@@ -139,17 +137,6 @@ def phase_serve():
           "drill: corrected token streams differ from the clean run")
     log(f"serve drill at decode step {DRILL[0]}: detected and corrected, "
         "tokens identical to the clean run")
-
-    paged = PagedServeEngine(eng.cfg, eng.params, slots=SLOTS,
-                             max_len=eng.max_len, abft_mode="verify",
-                             abft_reduce="correct")
-    paged.warm(prompt_len=PROMPT_LEN)
-    for r in sorted(clean, key=lambda r: r.rid):
-        paged.submit(Request(rid=r.rid, prompt=list(r.prompt),
-                             max_new_tokens=GEN))
-    check(_streams(paged.run()) == ref,
-          "PagedServeEngine tokens differ from ServeEngine tokens")
-    log("PagedServeEngine tokens identical to ServeEngine tokens")
 
     traces = _kernel_traces()
     log(f"kernel traces by backend: {traces}")
@@ -230,7 +217,7 @@ def phase_serve_sharded():
     from repro.dist.sharding import make_mesh
     from repro.ft.failures import SDCInjector, SDCPlan
     from repro.models import transformer as tf
-    from repro.serve.engine import Request, ServeEngine
+    from repro.serve.engine import PagedServeEngine, Request
 
     cfg = get_config(ARCH).scaled(dtype="float32")
     params = tf.init_params(jax.random.PRNGKey(SEED), cfg)
@@ -239,9 +226,9 @@ def phase_serve_sharded():
                for _ in range(REQUESTS)]
 
     def drive(mesh, sdc=None):
-        eng = ServeEngine(cfg, params, slots=SLOTS,
-                          max_len=PROMPT_LEN + GEN + 8, mesh=mesh,
-                          abft_reduce="correct", sdc=sdc)
+        eng = PagedServeEngine(cfg, params, slots=SLOTS,
+                               max_len=PROMPT_LEN + GEN + 8, mesh=mesh,
+                               abft_reduce="correct", sdc=sdc)
         eng.warm(prompt_len=PROMPT_LEN)
         for i, p in enumerate(prompts):
             eng.submit(Request(rid=i, prompt=p, max_new_tokens=GEN))
@@ -251,7 +238,7 @@ def phase_serve_sharded():
                              devices=jax.devices()[:1]))
     got, eng = drive(make_mesh((2, 2), ("data", "model")),
                      SDCInjector(SDCPlan((SHARDED_DRILL,))))
-    leaves = jax.tree.leaves(eng.params) + jax.tree.leaves(eng.cache)
+    leaves = jax.tree.leaves(eng.params) + jax.tree.leaves(eng.kv.pools)
     devices = {d for x in leaves for d in x.sharding.device_set}
     check(len(devices) == 4, f"sharded serve spans {len(devices)} devices")
     check(any(not x.sharding.is_fully_replicated for x in leaves),

@@ -1,8 +1,9 @@
 """Continuous-batching serving with ABFT-verified projections.
 
-Eight requests stream through a 2-slot engine: slots retire and re-admit
-independently (per-slot positions), every projection carries Huang-Abraham
-checksum columns (silent-corruption detection while serving).
+Eight requests stream through a 2-slot engine on a paged KV cache: slots
+retire and re-admit independently (per-slot positions, pages freed and
+reallocated), every projection carries Huang-Abraham checksum columns
+(silent-corruption detection while serving).
 
 Run:  PYTHONPATH=src python examples/continuous_batching.py
 """
@@ -13,14 +14,14 @@ import numpy as np
 
 from repro.configs.base import smoke_config
 from repro.models import transformer as tf
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import PagedServeEngine, Request
 
 
 def main():
     cfg = smoke_config("qwen2-0.5b")
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
-    engine = ServeEngine(cfg, params, slots=2, max_len=64,
-                         abft_mode="verify")
+    engine = PagedServeEngine(cfg, params, slots=2, max_len=64,
+                              abft_mode="verify")
 
     rs = np.random.RandomState(0)
     t0 = time.time()

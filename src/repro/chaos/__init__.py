@@ -13,7 +13,7 @@ SUMMA, pod kill in the train CLI) into one queryable surface:
     by `repro.ft.failures` for back-compat) and the single `flip_bit` /
     `scatter_delta` injection primitives.
   * `chaos.campaign` — `CampaignRunner` sweeps a `FaultSpace` over an
-    `ElasticRuntime` train loop and a drilled `ServeEngine` decode,
+    `ElasticRuntime` train loop and a drilled `PagedServeEngine` decode,
     classifying every event against a clean golden run.
   * `chaos.report`   — the coverage-matrix artifact (JSON + markdown)
     with the uncovered-surface ledger.

@@ -2,8 +2,8 @@
 
 For every `FaultSpec` the runner builds the real workload — an
 `ft.runtime.ElasticRuntime` training loop or a drilled
-`serve.engine.ServeEngine` decode — injects exactly that fault through the
-spec's adapter (`SDCPlan` into the protected collective, `FailurePlan`
+`serve.engine.PagedServeEngine` decode — injects exactly that fault through
+the spec's adapter (`SDCPlan` into the protected collective, `FailurePlan`
 into the shard-erasure path, `lose_pod()`/`demote_pod()` for topology
 faults, `flip_bit` for DRAM corruption), and classifies what happened:
 
@@ -1060,7 +1060,7 @@ class CampaignRunner:
 
     def _serve_engine(self, sdc=None, scrub: int = 0):
         from repro.models import transformer as tf
-        from repro.serve.engine import ServeEngine
+        from repro.serve.engine import PagedServeEngine
 
         cfg, prompts = self._serve_prompts()
         if sdc is None:
@@ -1073,10 +1073,10 @@ class CampaignRunner:
                 return cached, prompts
         params = tf.init_params(jax.random.PRNGKey(0), cfg)
         mesh = self._make_mesh(self._serve_mesh(), ("data", "model"))
-        eng = ServeEngine(cfg, params, slots=self.serve.slots,
-                          max_len=self.serve.max_len, mesh=mesh,
-                          abft_reduce="correct", sdc=sdc,
-                          scrub_every=scrub)
+        eng = PagedServeEngine(cfg, params, slots=self.serve.slots,
+                               max_len=self.serve.max_len, mesh=mesh,
+                               abft_reduce="correct", sdc=sdc,
+                               scrub_every=scrub)
         eng.warm(prompt_len=self.serve.prompt_len)
         if sdc is None:
             if scrub:
@@ -1162,7 +1162,7 @@ class CampaignRunner:
             corrected = detected and all(e.repaired for e in evs)
             rung = None
             if detected:
-                rung = ("scrub:kv_repair" if evs[0].domain == "kv"
+                rung = ("scrub:page_repair" if evs[0].domain == "kv"
                         else "scrub:restore")
             end_state = ("bit_identical" if outputs == golden["outputs"]
                          else "diverged")
@@ -1197,7 +1197,7 @@ class CampaignRunner:
                      f"decode step {spec.step}; scrub "
                      + (", ".join(
                          f"{e.domain}:{e.leaf}"
-                         + (f"[slot {e.slot}]" if e.slot >= 0 else "")
+                         + (f"[page {e.page}]" if e.page >= 0 else "")
                          for e in evs) or "never tripped")
                      + f"; outputs "
                      f"{'unchanged' if end_state == 'bit_identical' else 'diverged'}")
@@ -1297,20 +1297,8 @@ class CampaignRunner:
 
             def on_step(engine, step):
                 if step == fire and not fired:
-                    if spec.kind == "dram_kv_cache":
-                        kv = engine.kv
-                        live = kv.live_pages()
-                        phys = spec.page if spec.page >= 0 else \
-                            (live[0] if live else 1)
-                        key = sorted(kv.pools)[
-                            spec.seed % len(kv.pools)]
-                        kv.corrupt_page(key, phys, bit=spec.bit)
-                        fired["leaf"] = f"{key}[page {phys}]"
-                        fired["undo"] = lambda: None  # reset() rebuilds kv
-                        fired["page"] = phys
-                    else:
-                        fired["leaf"], fired["undo"] = _flip_engine_bit(
-                            engine, spec)
+                    fired["leaf"], fired["undo"] = _flip_engine_bit(engine,
+                                                                    spec)
 
             try:
                 rep = run_trace(eng, trace, on_step=on_step)
@@ -1998,7 +1986,7 @@ class CampaignRunner:
                 e = by_domain[dom].pop(0)
                 ev[j].update(
                     detected=True, corrected=bool(e.repaired),
-                    rung=("scrub:kv_repair" if dom == "kv"
+                    rung=("scrub:page_repair" if dom == "kv"
                           else "scrub:restore"),
                     latency=e.wall_s,
                     note=ev[j]["note"] + f"; scrub {e.domain}:{e.leaf}")
@@ -2079,11 +2067,11 @@ class CampaignRunner:
                     f"{g['stats']['decode_steps']} clean decode steps")
             if scrub:
                 note += (f", {g['stats']['scrub_checks']} at-rest scrubs "
-                         f"(KV + params fingerprints)")
+                         f"(KV page checksums + params fingerprints)")
             rows.append(FaultResult(
                 name=f"serve:clean_sweep:{'x'.join(map(str, key))}",
                 workload="serve", kind="clean_sweep",
-                surface=("serve.engine/kv_cache_at_rest" if scrub
+                surface=("serve.paged_kv/pages" if scrub
                          else "serve.engine/logits_reduce"), protected=True,
                 promise="none", outcome=outcome, detected=detected,
                 corrected=False, rung=None, recovery_latency_s=None,
@@ -2141,12 +2129,11 @@ class _Skip(Exception):
 # ---------------------------------------------------------------------------
 
 
-def _flip_candidates(tree, *, min_ndim: int = 0):
+def _flip_candidates(tree):
     """Flippable (path, leaf) pairs of a pytree: float32, non-trivial."""
     flat, _ = jax.tree_util.tree_flatten_with_path(tree)
     return [(p, x) for p, x in flat
-            if x.dtype == jnp.float32 and x.size >= 64
-            and x.ndim >= min_ndim]
+            if x.dtype == jnp.float32 and x.size >= 64]
 
 
 def _replace_leaf(tree, path, value):
@@ -2173,22 +2160,19 @@ def _flip_state_leaf(state, group: str, spec: FaultSpec):
 
 
 def _flip_engine_bit(engine, spec: FaultSpec):
-    """Flip one bit inside a live ServeEngine: a KV-cache leaf (an early,
-    attended position of slot 0) or a params leaf (the embedding table /
-    first float32 weight).  Returns ``(leaf_name, undo)`` — ``undo`` puts
-    the original (immutable) leaf back, so a shared engine survives a
-    params drill (the cache is cleared by ``reset()`` anyway)."""
+    """Flip one bit inside a live serve engine: a KV page (``spec.page``,
+    else the first live page, of the pool ``spec.seed`` picks) or a params
+    leaf (the embedding table / first float32 weight).  Returns
+    ``(leaf_name, undo)`` — ``undo`` puts the original (immutable) params
+    leaf back, so a shared engine survives a params drill (``reset()``
+    rebuilds the page pools anyway)."""
     if spec.kind == "dram_kv_cache":
-        cands = _flip_candidates(engine.cache, min_ndim=3)
-        assert cands, "no float32 KV leaf to corrupt"
-        path, leaf = cands[0]
-        # slot 0, an early (already-attended) position: first leading-dim
-        # entry, batch index 0, position 1, everything else 0
-        pos = (0, 0, 1) + (0,) * (leaf.ndim - 3)
-        idx = int(np.ravel_multi_index(pos, leaf.shape))
-        engine.cache = _replace_leaf(engine.cache, path,
-                                     flip_bit(leaf, idx, bit=spec.bit))
-        return f"cache{jax.tree_util.keystr(path)}[{idx}]", lambda: None
+        kv = engine.kv
+        live = kv.live_pages()
+        phys = spec.page if spec.page >= 0 else (live[0] if live else 1)
+        key = sorted(kv.pools)[spec.seed % len(kv.pools)]
+        kv.corrupt_page(key, phys, bit=spec.bit)
+        return f"{key}[page {phys}]", lambda: None
     # dram_params: hit the embedding table (the gather surface) when
     # present, else the first sizable float32 weight
     cands = _flip_candidates(engine.params)

@@ -203,10 +203,10 @@ WORKLOADS = ("train", "serve", "solver", "traffic")
 # the same fault kinds map onto solver-native surfaces — an SDC lands in
 # one replica's block correction, a DRAM flip hits the resident iterate,
 # and shard/pod loss kills subspace workers.  The "traffic" workload
-# (PR 8) drills the PAGED serving engine under an open-loop load trace:
-# same logits-reduce and params surfaces as "serve", but dram_kv_cache
-# lands in the page pools where the per-page checksums own detection +
-# erasure repair (surface "serve.paged_kv/pages").
+# (PR 8) drills the serving engine under an open-loop load trace, with the
+# same surfaces as "serve": dram_kv_cache lands in the page pools, where
+# the per-page checksums own detection + erasure repair (surface
+# "serve.paged_kv/pages").
 _KIND_INFO = {
     "sdc_collective": dict(
         workloads=("train", "serve", "solver", "traffic"),
@@ -232,8 +232,7 @@ _KIND_INFO = {
         workloads=("train",), surface="state.opt_state_at_rest"),
     "dram_kv_cache": dict(
         workloads=("serve", "traffic"),
-        surface={"serve": "serve.engine/kv_cache_at_rest",
-                 "traffic": "serve.paged_kv/pages"}),
+        surface="serve.paged_kv/pages"),
     "shard_loss": dict(
         workloads=("train", "solver"),
         surface={"train": "ckpt.diskless/shards",
@@ -348,7 +347,8 @@ class FaultSpec:
     # -- adapters onto the existing drill paths ------------------------------
     def sdc_plan(self) -> "SDCPlan":
         """This spec as the one-event `SDCPlan` the existing SDC drill
-        paths (`StepOptions.sdc_inject`, `ServeEngine(sdc=...)`) consume."""
+        paths (`StepOptions.sdc_inject`, `PagedServeEngine(sdc=...)`)
+        consume."""
         if self.kind != "sdc_collective":
             raise ValueError(f"{self.kind!r} is not an SDC-collective fault")
         return SDCPlan(((self.step, self.shard, self.delta),))

@@ -3,7 +3,7 @@ matrix.
 
 The campaign runs every spec AND every multi-fault episode of the chosen
 space against live workloads (an `ElasticRuntime` train loop, a drilled
-`ServeEngine` decode, a redundant-subspace CG solve), classifies each
+`PagedServeEngine` decode, a redundant-subspace CG solve), classifies each
 event as detected / corrected / absorbed / missed / false-alarm against a
 clean golden run, and writes the machine-readable artifact CI gates on
 (`--json`) plus a rendered markdown matrix on stdout.

@@ -1,10 +1,11 @@
 """Serving driver: the fault-tolerant continuous-batching engine as a CLI.
 
-Drives `serve.ServeEngine` — slot-scheduled prefill+decode with ABFT-verified
-projections (``--abft verify``), a checksum-protected decode-path logits
-reduction (``--reduce verify|correct``) and optional SDC drills that flip a
-bit inside the decode collective mid-flight (``--drill-step/shard/delta``).
-Single-host it serves a reduced config; with ``--mesh RxM`` the two compiled
+Drives `serve.PagedServeEngine` — slot-scheduled prefill+decode over a paged
+KV cache with ABFT-verified projections (``--abft verify``), a
+checksum-protected decode-path logits reduction (``--reduce
+verify|correct``) and optional SDC drills that flip a bit inside the decode
+collective mid-flight (``--drill-step/shard/delta``).
+Single-host it serves a reduced config; with ``--mesh RxM`` the compiled
 programs shard over a (data=R, model=M) `repro.dist` mesh (spawn fake CPU
 devices with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``).
 
@@ -27,7 +28,7 @@ from repro.dist.sharding import make_mesh
 from repro.ft.failures import SDCInjector, SDCPlan
 from repro.launch import compile_cache
 from repro.models import transformer as tf
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import PagedServeEngine, Request
 
 
 def run(arch: str, *, smoke: bool = True, requests: int = 6, slots: int = 2,
@@ -47,8 +48,10 @@ def run(arch: str, *, smoke: bool = True, requests: int = 6, slots: int = 2,
     mesh = None
     if mesh_shape is not None:
         mesh = make_mesh(mesh_shape, ("data", "model"))
-    engine = ServeEngine(
-        cfg, params, slots=slots, max_len=prompt_len + gen + 8,
+    page = 8
+    engine = PagedServeEngine(
+        cfg, params, slots=slots, page_size=page,
+        max_len=-(-(prompt_len + gen + 8) // page) * page,   # whole pages
         abft_mode=abft_mode, abft_reduce=abft_reduce, mesh=mesh,
         sdc=SDCInjector(drill) if drill is not None else None)
     engine.warm(prompt_len=prompt_len)

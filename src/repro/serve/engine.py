@@ -1,29 +1,35 @@
 """Fault-tolerant distributed continuous-batching serving engine.
 
-vLLM-style slot scheduler on top of the framework's decode path:
+vLLM-style slot scheduler on top of the framework's decode path, with the
+K/V cache in pages (`serve.paged_kv`):
   * fixed decode batch of `slots`; every engine step decodes ONE token for
     all occupied slots (per-slot positions — slots are never in lockstep),
-  * a finished slot (max_new_tokens or EOS) retires immediately and a queued
-    request is admitted: its prompt is prefilled as a single sequence and
-    the resulting KV cache is scattered into the freed slot,
-  * the whole engine state (batched caches, per-slot positions) lives in
-    fixed-shape device arrays — two compiled programs total (prefill_1,
-    decode_B), no recompilation as requests come and go.
+  * a finished slot (max_new_tokens or EOS) retires immediately and frees
+    its pages, and a queued request is admitted: its prompt is prefilled
+    (whole, in chunks, or after a shared prefix) and the prefill's K/V
+    rows are written into the slot's pages,
+  * the page pools are the only storage of the K/V leaves: the compiled
+    decode reads them in place through the page table and returns the new
+    rows, which are written back into their pages.  All engine state lives
+    in fixed-shape device arrays — one prefill program per prompt bucket
+    and one decode program, no recompilation as requests come and go.
 
-Distribution (``mesh=``): both compiled programs shard over a `repro.dist`
+Distribution (``mesh=``): the compiled programs shard over a `repro.dist`
 mesh — params via `dist.sharding.infer_param_specs` (Megatron-style
-column/row rules over the "model" axis), KV caches via
-`dist.sharding.cache_specs` (slot batch over the DP axes), tokens/positions
-over the batch entry.  The model body runs auto-sharded exactly like
-`train.step.build_serve_step`; the *final projection* of the decode program
-is restructured into an explicit row-parallel `shard_map` region: each model
-shard computes a partial-logits contribution from its feature slice and the
-cross-shard reduction runs through `dist.collectives.abft_psum` — the
-paper's Huang-Abraham checksums ride the decode path's collective itself.
+column/row rules over the "model" axis), the page pools and page table
+replicated (every device reads any slot's pages), cache leaves that are
+not paged via `dist.sharding.cache_specs` (slot batch over the DP axes),
+tokens/positions over the batch entry.  The model body runs auto-sharded
+exactly like `train.step.build_serve_step`; the *final projection* of the
+decode program is restructured into an explicit row-parallel `shard_map`
+region: each model shard computes a partial-logits contribution from its
+feature slice and the cross-shard reduction runs through
+`dist.collectives.abft_psum` — the paper's Huang-Abraham checksums ride
+the decode path's collective itself.
 
 Fault tolerance while serving:
   * ``abft_mode="verify"`` carries checksum columns through the projections
-    of both programs (matmul-level SDC detection, core.abft_gemm),
+    of every program (matmul-level SDC detection, core.abft_gemm),
   * ``abft_reduce="verify"|"correct"`` checksum-protects the decode-path
     cross-shard logits reduction (collective-level SDC detection/repair).
     Coverage boundary when BOTH are on: the final projection's local
@@ -36,7 +42,11 @@ Fault tolerance while serving:
     shard's contribution AFTER its checksums are taken — mid-collective,
     exactly the paper's transient-fault model — and the engine detects,
     locates, corrects in-flight and records the event in `EngineStats`
-    (detections, corrections, recovery latency, per-request TTFT / tok/s).
+    (detections, corrections, recovery latency, per-request TTFT / tok/s),
+  * ``scrub_every=N`` verifies the at-rest state every N decode steps: the
+    page checksums (a tripped page is rebuilt from its pool's erasure sum,
+    surface ``serve.paged_kv/pages``) and the params fingerprints (a
+    tripped leaf is restored from the held origin copy).
 
 The verified-unembed shard_map region is partial-manual over {"model"} and
 contains only a matmul + one psum; the layer scans stay in the auto-sharded
@@ -62,14 +72,14 @@ from repro.dist import sharding as shd
 from repro.dist.collectives import abft_psum
 from repro.models import transformer as tf
 from repro.models.layers import softcap_fn
+from repro.serve.paged_kv import PagedKVCache
 from repro.train.step import StepOptions
 
-__all__ = ["Request", "ServeEngine", "PagedServeEngine", "EngineStats",
-           "SDCEvent", "ScrubEvent"]
+__all__ = ["Request", "PagedServeEngine", "EngineStats", "SDCEvent",
+           "ScrubEvent"]
 
-# the protection domains/surfaces this module owns (repro.chaos drills
-# them): the verified unembed reduction is protected; the KV cache sitting
-# in device memory between decode steps is an honest ledger entry
+# the protection domain this module owns (repro.chaos drills it); the KV
+# cache at rest is serve.paged_kv's surface
 register_surface(
     "serve.engine/logits_reduce", owner=__name__, protected=True,
     promise="bit_identity",
@@ -80,19 +90,6 @@ register_surface(
     note="promise is on the EMITTED TOKEN STREAM: correction is near-exact "
          "on logits and the argmax absorbs the residual ulps, so drilled "
          "outputs are bit-identical to clean (tests/test_serve_drill.py)")
-register_surface(
-    "serve.engine/kv_cache_at_rest", owner=__name__, protected=True,
-    promise="tolerance",
-    detector="per-slot fingerprints (fp32 sums over the non-slot axes) "
-             "verified before every decode step, plus a slot-sum checksum "
-             "array per cache leaf: a tripped slot is rebuilt by the "
-             "erasure solve ksum - sum(other slots); armed after every "
-             "legitimate cache mutation (decode, admission scatter)",
-    kinds=("dram_kv_cache",),
-    note="single-slot fault model (one checksum row, like f=1 diskless); "
-         "enabled via ServeEngine(scrub_every=N).  The same cadence "
-         "verifies the params fingerprints and restores a tripped leaf "
-         "from the held origin copy (stand-in for a checkpoint re-fetch)")
 
 
 @dataclasses.dataclass
@@ -142,15 +139,14 @@ class ScrubEvent:
     step: int                 # engine decode step the verify ran at
     domain: str               # "kv" | "params"
     leaf: str                 # keystr of the tripped leaf
-    slot: int = -1            # KV slot rebuilt (-1 for params / paged)
-    page: int = -1            # physical page rebuilt (PagedServeEngine)
+    page: int = -1            # physical page rebuilt (-1 for params)
     repaired: bool = False
     wall_s: float = 0.0       # verify + repair wall
 
 
 @dataclasses.dataclass
 class EngineStats:
-    """Per-engine step/FT accounting, reset by `ServeEngine.reset()`.
+    """Per-engine step/FT accounting, reset by `PagedServeEngine.reset()`.
 
     detections/corrections count decode steps whose protected reduction
     reported an inconsistent / repaired checksum (drilled or not — a real
@@ -215,13 +211,45 @@ def _is_block(x) -> bool:
         isinstance(v, jax.ShapeDtypeStruct) for v in x.values())
 
 
-class ServeEngine:
+class PagedServeEngine:
+    """The serving engine on a paged/block KV cache (serve.paged_kv) with
+    prefix caching, chunked prefill, and an optional SLO-aware scheduler.
+
+    The page pools are the ONLY storage of the K/V leaves: the compiled
+    decode reads them in place, each layer its own pages through the page
+    table (`models.attention.paged_view`), and returns just the new K/V
+    rows, which `_post_decode` writes into their pages — re-arming exactly
+    the pages it touched (the scrub unit is the page).  ``self.cache``
+    holds only the leaves that are not paged (recurrent state), keyed by
+    path.  A retiring slot frees its pages (zero-at-free), and the at-rest
+    scrub verifies/repairs at page granularity via the pool's erasure sum.
+
+    Decode parity: with ``chunk_prefill=0`` and no prefix hit, admission
+    prefills the whole prompt in one compiled program, and the decode's
+    per-layer view of the pages differs from a dense cache only at
+    causally masked positions (zeros vs prefill pad garbage) — the emitted
+    token streams equal single-request greedy decoding
+    (tests/test_serve_engine.py, tests/test_traffic.py).  Chunked and
+    prefix-shared prefills change the prefill computation's shape, so
+    their guarantee is on the argmax token stream, not logits bits.
+
+    ``scheduler``: an `SLOScheduler` (serve.scheduler) takes over queueing
+    — `submit()` routes through its admission control (rejections land in
+    ``self.rejected``) and `_admit` pops by aged effective priority.
+    ``chunk_prefill=C``: prompts longer than C prefill C tokens per engine
+    step, so a long prompt never delays a running decode step by more than
+    one chunk's work (tests/test_scheduler.py); when no decode is active
+    the chunks free-run back-to-back.
+    """
+
     def __init__(self, cfg: ModelConfig, params, *, slots: int = 4,
                  max_len: int = 256, abft_mode: str = "off",
                  abft_backend: str = "auto", mesh: Optional[Mesh] = None,
                  abft_reduce: str = "off", abft_f: int = 2,
                  sdc: Optional[SDCInjector] = None, scrub_every: int = 0,
-                 kernel_dtype: str = "fp32"):
+                 kernel_dtype: str = "fp32", page_size: int = 8,
+                 chunk_prefill: int = 0, prefix_cache: bool = True,
+                 scheduler=None, max_prefixes: int = 16):
         assert cfg.n_enc_layers == 0, "engine serves decoder-only archs"
         if abft_reduce not in ("off", "verify", "correct"):
             raise ValueError(f"unknown abft_reduce {abft_reduce!r}")
@@ -236,11 +264,16 @@ class ServeEngine:
         self.abft_reduce = abft_reduce
         self.abft_f = abft_f
         self.sdc = sdc
+        self.page_size = page_size
+        self.chunk_prefill = chunk_prefill
+        self.prefix_cache = prefix_cache
+        self.max_prefixes = max_prefixes
+        self.scheduler = scheduler
         self._protected = abft_reduce != "off"
         self._warming = False
-        # abft_backend="pallas" puts every protected projection of both
-        # compiled programs (prefill_1, decode_B) on the fused dual-checksum
-        # kernel; "auto" does so on TPU (see core.abft_gemm).
+        # abft_backend="pallas" puts every protected projection of the
+        # compiled programs on the fused dual-checksum kernel; "auto" does
+        # so on TPU (see core.abft_gemm).
         # kernel_dtype narrows the protected-projection operand stream
         # (bf16 / int8 MXU rates); checksums stay fp32 with dtype-aware
         # detection eps, so the serving projections ride the mixed-
@@ -295,33 +328,41 @@ class ServeEngine:
 
         self.active: List[Optional[Request]] = [None] * slots
         self.queue: Deque[Request] = deque()
+        self.rejected: List[Request] = []
         self.stats = EngineStats()
+        self.kv: Optional[PagedKVCache] = None    # built by _fresh_cache
         self.cache = self._fresh_cache()
         self.pos = jnp.zeros((slots,), jnp.int32)
         self.tokens = jnp.zeros((slots, 1), jnp.int32)
+        self._prefilling: Optional[dict] = None
 
-        # at-rest scrub (serve.engine/kv_cache_at_rest + the serve side of
+        # at-rest scrub (serve.paged_kv/pages + the serve side of
         # state.params_at_rest): `scrub_every` sets the verify cadence in
-        # decode steps; arming (checksum-on-write) happens after every
-        # legitimate cache mutation regardless.  Params are immutable while
-        # serving, so they arm once: fingerprints for detection plus an
-        # origin copy for repair (the stand-in for a checkpoint re-fetch).
+        # decode steps; the pages re-arm on every write regardless.
+        # Params are immutable while serving, so they arm once:
+        # fingerprints for detection plus an origin copy for repair (the
+        # stand-in for a checkpoint re-fetch).
         self.scrub_every = scrub_every
-        self._kv_sums = {}
         self._param_fp = {}
         self._param_origin = None
         if scrub_every:
             self._param_fp = self._fingerprints(self.params)
             self._param_origin = jax.tree.map(
                 lambda x: jnp.array(x, copy=True), self.params)
-            self._arm_kv()
+            self.kv.arm_all()
 
         if mesh is not None:
-            self._state_sh, new_sh = self._decode_shardings()
+            # pools and table replicated: every device reads any slot's
+            # pages
+            dense = {k: v for k, v in _by_path(self._cache_sh).items()
+                     if k in self.cache}
+            self._state_sh = {"pools": {k: self._rep for k in self.kv.pools},
+                              "table": self._rep, "dense": dense}
             in_sh = (self._param_sh, self._tok_sh, self._pos_sh,
                      self._state_sh)
-            out_sh = (self._rep, new_sh, self._rep,
-                      {k: self._rep for k in _INFO0})
+            out_sh = (self._rep,
+                      {**{k: self._rep for k in self.kv.pools}, **dense},
+                      self._rep, {k: self._rep for k in _INFO0})
             self._decode = jax.jit(self.serve_decode, in_shardings=in_sh,
                                    out_shardings=out_sh)
             self._decode_drill = jax.jit(
@@ -332,11 +373,17 @@ class ServeEngine:
             self._decode = jax.jit(self.serve_decode)
             self._decode_drill = jax.jit(self.serve_decode_drill)
         self._prefill = {}  # len -> jitted prefill (bucketed)
+        self._chunk_progs = {}  # len -> jitted prefill chunk (bucketed)
 
     # -- public ---------------------------------------------------------------
-    def submit(self, req: Request):
+    def submit(self, req: Request, priority: Optional[int] = None):
         if not req.t_submit:
             req.t_submit = time.perf_counter()
+        if self.scheduler is not None and not self._warming:
+            if not self.scheduler.submit(req, priority=priority):
+                req.done = True
+                self.rejected.append(req)
+            return
         self.queue.append(req)
 
     def run(self, max_steps: int = 10_000, on_step=None) -> List[Request]:
@@ -344,7 +391,7 @@ class ServeEngine:
 
         ``on_step(engine, decode_step)`` — called before each decode step
         with the engine itself — is the chaos-campaign hook: a fault drill
-        mutates engine state (flip a KV-cache or weight bit) mid-flight at
+        mutates engine state (flip a KV page or weight bit) mid-flight at
         a planned step; the engine re-places the mutated arrays before the
         compiled call as it always does."""
         finished: List[Request] = []
@@ -367,15 +414,17 @@ class ServeEngine:
         self.tokens = jnp.zeros((self.slots, 1), jnp.int32)
         self.active = [None] * self.slots
         self.queue = deque()
+        self.rejected = []
+        self._prefilling = None
         self.stats = EngineStats()
         if self.scrub_every:
-            self._arm_kv()
+            self.kv.arm_all()
 
     def warm(self, prompt_len: int = 8, decode_steps: int = 2):
-        """Warm BOTH compiled programs (the prefill bucket for `prompt_len`
-        and decode_B) with a single dummy request — plus the drill variant
-        of the decode program (injected delta 0.0 = no corruption) on
-        engines that carry an SDC plan — then reset state and stats."""
+        """Warm the prefill bucket for `prompt_len` and the decode program
+        with a single dummy request — plus the drill variant of the decode
+        program (injected delta 0.0 = no corruption) on engines that carry
+        an SDC plan — then reset state and stats."""
         self._warming = True
         try:
             # +1: the prefill's argmax token is output[0], so max_new_tokens
@@ -396,49 +445,62 @@ class ServeEngine:
     # -- internals --------------------------------------------------------------
     def _place(self):
         """(tokens, pos, state) re-placed onto their program shardings;
-        ``state`` is what the decode program reads besides the params
-        (`_decode_state`).
+        ``state`` is what the decode program reads besides the params: the
+        page pools, the page table and the leaves that are not paged.
 
         Host-side slot bookkeeping (`.at[s].set` scatters, eager argmax
         outputs) commits these arrays to whatever sharding the eager ops
         produced; pjit matches input shardings strictly, so re-place before
         every compiled call (no-op when already placed)."""
-        state = self._decode_state()
+        state = {"pools": dict(self.kv.pools), "table": self._table,
+                 "dense": self.cache}
         if self.mesh is None:
             return self.tokens, self.pos, state
         return (jax.device_put(self.tokens, self._tok_sh),
                 jax.device_put(self.pos, self._pos_sh),
                 jax.device_put(state, self._state_sh))
 
-    def _decode_state(self):
-        """The decode program's state argument: the dense cache."""
-        return self.cache
-
-    def _decode_shardings(self):
-        """Shardings of the decode program's state argument and of the
-        state it returns."""
-        return self._cache_sh, self._cache_sh
-
     def _fresh_cache(self):
-        cache = tf.init_cache(self.cfg, self.slots, self.max_len)
-        # force vector per-slot indices (init_cache makes scalars)
-        cache = jax.tree_util.tree_map_with_path(
-            lambda p, x: jnp.zeros((x.shape[0], self.slots), jnp.int32)
-            if (p and getattr(p[-1], "key", None) == "index") else x,
-            cache)
+        """Build the page pools of the sequence-indexed K/V leaves
+        (``[repeats, slots, max_len, *tail]`` in a dense layout) and
+        return the leaves of the blocks that have none (recurrent state:
+        mamba, xLSTM), dense and keyed by path."""
+        struct = jax.eval_shape(
+            lambda: tf.init_cache(self.cfg, self.slots, self.max_len))
+        self._cache_struct = struct
+        flat = jax.tree_util.tree_flatten_with_path(struct)[0]
+        shapes = {jax.tree_util.keystr(path): (x.shape, x.dtype)
+                  for path, x in flat
+                  if (jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= 4
+                      and x.shape[1] == self.slots
+                      and x.shape[2] == self.max_len)}
+        self.kv = None                    # the old pools go first
+        self.kv = PagedKVCache(shapes, slots=self.slots,
+                               max_len=self.max_len,
+                               page_size=self.page_size,
+                               max_prefixes=self.max_prefixes)
+        self._table_host = self.kv.table.copy()
+        self._table = jnp.asarray(self._table_host)
+        # a block with a paged leaf decodes from pages alone (its index
+        # leaf is the slot's position, which the decode is given)
+        paged = {path[:-1] for path, _ in flat
+                 if jax.tree_util.keystr(path) in shapes}
+        keep = {jax.tree_util.keystr(path) for path, _ in flat
+                if path[:-1] not in paged}
+        if not keep:
+            return {}
+        # jitted, so that the paged leaves are never built
+        dense = jax.jit(lambda: {k: v for k, v in _by_path(
+            tf.init_cache(self.cfg, self.slots, self.max_len)).items()
+            if k in keep})()
         if self._cache_sh is not None:
-            cache = jax.device_put(cache, self._cache_sh)
-        return cache
+            dense = jax.device_put(dense, {k: v for k, v in _by_path(
+                self._cache_sh).items() if k in keep})
+        return dense
 
     def _cache_shardings(self, batch: int):
         shapes = jax.eval_shape(
             lambda: tf.init_cache(self.cfg, batch, self.max_len))
-        if batch == self.slots:  # engine cache carries VECTOR slot indices
-            shapes = jax.tree_util.tree_map_with_path(
-                lambda p, x: jax.ShapeDtypeStruct((x.shape[0], batch),
-                                                  jnp.int32)
-                if (p and getattr(p[-1], "key", None) == "index") else x,
-                shapes)
         rule = shd.cache_specs(self.mesh, batch, self.cfg)
         specs = jax.tree_util.tree_map_with_path(rule, shapes)
         return shd.to_shardings(specs, self.mesh)
@@ -451,8 +513,11 @@ class ServeEngine:
 
     def _get_prefill(self, bucket: int):
         if bucket not in self._prefill:
-            def serve_prefill(pr, tok, ln, _b=bucket):
-                return self._prefill_impl(pr, tok, ln, _b)
+            def serve_prefill(pr, tok, ln):
+                cache = tf.init_cache(self.cfg, 1, self.max_len)
+                logits, new_cache, _ = tf.forward(pr, tok, self.cfg,
+                                                  cache=cache, abft=self.abft)
+                return logits, new_cache
             if self.mesh is not None:
                 small_sh = self._cache_shardings(1)
                 self._prefill[bucket] = jax.jit(
@@ -474,30 +539,18 @@ class ServeEngine:
                     jnp.asarray(x, jnp.float32))
         return fps
 
-    def _arm_kv(self):
-        """Checksum-on-write for the KV cache: per-slot fingerprints
-        (detect + locate the tripped slot) and a slot-sum checksum array
-        (the erasure row that repairs it) per float cache leaf."""
-        sums = {}
-        for path, x in jax.tree_util.tree_flatten_with_path(self.cache)[0]:
-            if (jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= 2
-                    and x.shape[1] == self.slots):
-                x32 = jnp.asarray(x, jnp.float32)
-                fp = jnp.sum(x32, axis=tuple(range(2, x.ndim)))
-                ks = jnp.sum(x32, axis=1)
-                sums[jax.tree_util.keystr(path)] = (fp, ks)
-        self._kv_sums = sums
-
     def _scrub_check(self):
-        """Verify-on-read: recompute KV and params fingerprints against the
-        armed values (split into `_scrub_kv` / `_scrub_params` so the paged
-        engine can swap in its page-granular unit)."""
+        """Verify-on-read: the page checksums and the params fingerprints
+        against their armed values; repair what tripped."""
         step = self.stats.decode_steps
         events: List[ScrubEvent] = []
         with obs.span("serve/scrub", step=step) as sp:
             self.stats.scrub_checks += 1
             with obs.span("serve/scrub_kv", step=step):
-                self._scrub_kv(step, events)
+                for key, page in self.kv.scrub():
+                    events.append(ScrubEvent(step=step, domain="kv",
+                                             leaf=key, page=page,
+                                             repaired=True))
             with obs.span("serve/scrub_params", step=step):
                 self._scrub_params(step, events)
         wall = sp.dur_s
@@ -515,50 +568,16 @@ class ServeEngine:
             rep = obs.counter("repro_scrub_repairs_total",
                               "at-rest scrub repairs")
             for e in events:
-                rung = ("scrub:page_repair" if e.page >= 0 else
-                        "scrub:kv_repair" if e.domain == "kv" else
+                rung = ("scrub:page_repair" if e.domain == "kv" else
                         "scrub:restore")
                 det.inc(surface="serve.scrub/" + e.domain)
                 obs.event("fault/detect", step=step,
                           surface="serve.scrub/" + e.domain,
-                          detector="fingerprint", leaf=e.leaf,
-                          slot=e.slot, page=e.page)
+                          detector="fingerprint", leaf=e.leaf, page=e.page)
                 if e.repaired:
                     rep.inc(domain=e.domain)
                     obs.recovery(rung, wall, step=step, leaf=e.leaf,
-                                 slot=e.slot, page=e.page)
-
-    def _scrub_kv(self, step: int, events: List[ScrubEvent]):
-        """A tripped KV slot is rebuilt by the erasure solve
-        ``ksum - sum(other slots)`` (single-slot fault model, like f=1
-        diskless)."""
-        flat, treedef = jax.tree_util.tree_flatten_with_path(self.cache)
-        leaves = []
-        for path, x in flat:
-            key = jax.tree_util.keystr(path)
-            armed = self._kv_sums.get(key)
-            if armed is not None:
-                fp_a, ks_a = armed
-                x32 = jnp.asarray(x, jnp.float32)
-                fp = jnp.sum(x32, axis=tuple(range(2, x.ndim)))
-                scale = float(jnp.max(jnp.abs(fp_a))) + 1.0
-                diff = np.asarray(jnp.abs(fp - fp_a))
-                # a flip into the NaN pattern poisons the slot sum; NaN
-                # compares false against any threshold — count it tripped
-                diff = np.where(np.isnan(diff), np.inf, diff)
-                for s in sorted({int(b[1])
-                                 for b in np.argwhere(diff > 1e-4 * scale)}):
-                    # erasure solve over the SURVIVING slots only (zeroing
-                    # the bad slot keeps a NaN/inf flip out of the sum)
-                    live = jnp.sum(x32.at[:, s].set(0.0), axis=1)
-                    x = x.at[:, s].set((ks_a - live).astype(x.dtype))
-                    x32 = jnp.asarray(x, jnp.float32)
-                    events.append(ScrubEvent(step=step, domain="kv",
-                                             leaf=key, slot=s,
-                                             repaired=True))
-            leaves.append(x)
-        if any(e.domain == "kv" for e in events):
-            self.cache = jax.tree_util.tree_unflatten(treedef, leaves)
+                                 page=e.page)
 
     def _scrub_params(self, step: int, events: List[ScrubEvent]):
         """A tripped params leaf is restored from the origin copy."""
@@ -585,51 +604,36 @@ class ServeEngine:
                 params = jax.device_put(params, self._param_sh)
             self.params = params
 
-    # -- subclass hooks --------------------------------------------------------
+    # -- queueing / admission --------------------------------------------------
     def _pending(self) -> bool:
-        """Anything left to admit? (run()'s drain condition; the paged
-        engine adds its scheduler queue and in-flight chunked prefill)."""
-        return bool(self.queue)
+        """Anything left to admit? (run()'s drain condition)."""
+        return (bool(self.queue) or self._prefilling is not None
+                or (self.scheduler is not None and len(self.scheduler) > 0))
 
-    def _pre_decode(self):
-        """Before each compiled decode call (paged engine: place the page
-        table on the device when it changed)."""
-
-    def _decode_attrs(self) -> Dict[str, int]:
-        """Attrs of the ``serve/decode_step`` span beyond ``active``."""
-        return {}
-
-    def _post_decode(self, new_cache):
-        """After the decode, before positions advance: keep the state it
-        returned.  Contiguous engine: the new cache, whole-cache re-arm;
-        the paged engine overrides this with per-page write-back +
-        re-arm."""
-        self.cache = new_cache
-        if self.scrub_every and not self._warming:
-            self._arm_kv()  # re-arm: the decode mutated every live slot
-
-    def _retire_slot(self, s: int):
-        """A slot's request just finished (paged engine frees its pages)."""
-
-    def _writeback_pages(self, n_active: int) -> int:
-        """One-token page writes `_post_decode` makes for ``n_active``
-        slots (the contiguous engine writes no pages: it re-arms the whole
-        cache)."""
-        return 0
-
-    def _write_pages(self, s: int, cache_tree, start: int, end: int):
-        """Persist positions [start, end) of a 1-slot prefill cache (paged
-        engine; the contiguous engine's slot scatter already holds them)."""
+    def _next_request(self) -> Optional[Request]:
+        if self.scheduler is not None:
+            req = self.scheduler.pop()
+            if req is not None:
+                return req
+        return self.queue.popleft() if self.queue else None
 
     def _admit(self):
-        admitted = False
-        for s in range(self.slots):
-            if self.active[s] is not None or not self.queue:
-                continue
-            self._start_admission(s, self.queue.popleft())
-            admitted = True
-        if admitted and self.scrub_every and not self._warming:
-            self._arm_kv()  # re-arm after the admission scatter
+        if self._prefilling is not None:
+            self._advance_prefill()
+            if self._prefilling is not None:
+                return          # one chunk per engine step under decode load
+        while True:
+            free = [s for s in range(self.slots) if self.active[s] is None]
+            if not free:
+                return
+            req = self._next_request()
+            if req is None:
+                return
+            self._start_admission(free[0], req)
+            if self._prefilling is not None:
+                self._advance_prefill()   # free-runs when no decode active
+                if self._prefilling is not None:
+                    return
 
     def _admission(self, req: Request, prefix_tokens: int = 0):
         """The ``serve/admit`` span of one admission, which starts now;
@@ -644,9 +648,19 @@ class ServeEngine:
                         prefix_tokens=prefix_tokens)
 
     def _start_admission(self, s: int, req: Request):
-        with self._admission(req):
+        plen = len(req.prompt)
+        need = min(plen + req.max_new_tokens, self.max_len)
+        shared = (self.kv.lookup_prefix(req.prompt)
+                  if self.prefix_cache and not self._warming else [])
+        with self._admission(req, prefix_tokens=len(shared) * self.page_size):
+            start = self.kv.alloc_slot(s, need, shared=shared)
+            if start or (self.chunk_prefill and plen > self.chunk_prefill):
+                self._prefilling = {"slot": s, "req": req, "start": start}
+                return
+            # no prefix hit, no chunking: one compiled program over the
+            # whole prompt
             tok = self._prefill_whole(s, req)
-            self._finish_admission(s, req, tok, len(req.prompt))
+            self._finish_admission(s, req, tok, plen)
 
     def _prefill_whole(self, s: int, req: Request) -> int:
         """Prefill the whole prompt in one compiled program into slot
@@ -658,7 +672,7 @@ class ServeEngine:
                 jnp.asarray(req.prompt, jnp.int32))
             logits, small_cache = self._get_prefill(bucket)(
                 self.params, prompt, jnp.asarray(plen, jnp.int32))
-            self._scatter_slot(s, small_cache, plen)
+            self._carry_dense(s, small_cache)
             self._write_pages(s, small_cache, 0, plen)
             tok = int(jnp.argmax(logits[0, plen - 1]))
         self.stats.prefill_s += sp.dur_s
@@ -671,44 +685,124 @@ class ServeEngine:
         self.tokens = self.tokens.at[s, 0].set(tok)
         self.pos = self.pos.at[s].set(plen)
         self.active[s] = req
+        if self.prefix_cache and not self._warming:
+            self.kv.register_prefix(s, req.prompt)
 
-    def _prefill_impl(self, params, prompt, plen, bucket):
-        cache = tf.init_cache(self.cfg, 1, self.max_len)
-        logits, new_cache, _ = tf.forward(params, prompt, self.cfg,
-                                          cache=cache, abft=self.abft)
-        return logits, new_cache
+    def _paged_leaves(self, tree) -> Dict[str, jax.Array]:
+        return {k: x for k, x in _by_path(tree).items() if k in self.kv.pools}
 
-    def _scatter_slot(self, s: int, small_cache, plen: int):
-        def put(path, big, small):
-            key = getattr(path[-1], "key", None)
-            if key == "index":
-                return big.at[..., s].set(plen)
-            # leading dims: [repeats, B(slots), ...] <- [repeats, 1, ...]
-            return big.at[:, s].set(small[:, 0].astype(big.dtype))
+    def _write_pages(self, s: int, cache_tree, start: int, end: int):
+        """Persist positions [start, end) of a 1-slot cache into pages."""
+        if end <= start:
+            return
+        ps = self.page_size
+        pages = len(self.kv.pools) * (-(-end // ps) - start // ps)
+        with obs.span("serve/kv_write", pages=pages, host_reads=1):
+            self.kv.begin_mutation()
+            # padded to the prefill's own bucket: warmed with its program
+            self.kv.write_batch(self._paged_leaves(cache_tree),
+                                [(s, start, end - start, 0, start)],
+                                kind="prefill",
+                                pad_to=self._bucket(end - start))
 
-        self.cache = jax.tree_util.tree_map_with_path(
-            lambda p, b, sm: put(p, b, sm), self.cache, small_cache)
+    def _carry_dense(self, s: int, small_cache):
+        """Carry the leaves that are not paged of a 1-slot cache into slot
+        ``s`` (the paged leaves go to pages: `_write_pages`)."""
+        small = _by_path(small_cache)
+        self.cache = {k: x.at[:, s].set(small[k][:, 0].astype(x.dtype))
+                      for k, x in self.cache.items()}
+
+    # -- chunked / prefix-shared prefill --------------------------------------
+    def _slot_cache(self, s: int, start: int):
+        """Dense 1-slot cache for the chunk program: this slot's pages
+        gathered, non-paged leaves sliced from the engine cache, block
+        indices set to the chunk's start position."""
+        def leaf(path, x):
+            key = jax.tree_util.keystr(path)
+            if key in self.kv.pools:
+                return self.kv.gather_slot(key, s)
+            if getattr(path[-1], "key", None) == "index":
+                return jnp.full((x.shape[0],), start, jnp.int32)
+            return self.cache[key][:, s:s + 1]
+        return jax.tree_util.tree_map_with_path(leaf, self._cache_struct)
+
+    def _get_chunk(self, bucket: int):
+        if bucket not in self._chunk_progs:
+            def serve_prefill_chunk(pr, tok, start, cache, _b=bucket):
+                positions = start + jnp.arange(_b)
+                logits, new_cache, _ = tf.forward(
+                    pr, tok, self.cfg, positions=positions, cache=cache,
+                    abft=self.abft)
+                return logits, new_cache
+            self._chunk_progs[bucket] = jax.jit(serve_prefill_chunk)
+        return self._chunk_progs[bucket]
+
+    def _advance_prefill(self):
+        """Process chunks of the in-flight prefill: one chunk when any
+        decode is running (the chunk budget is the most a decode step can
+        be delayed), back-to-back when the engine is otherwise idle."""
+        while self._prefilling is not None:
+            pf = self._prefilling
+            s, req, start = pf["slot"], pf["req"], pf["start"]
+            plen = len(req.prompt)
+            n = min(self.chunk_prefill or plen - start, plen - start)
+            bucket = self._bucket(n)
+            with obs.span("serve/prefill_chunk", rid=req.rid, start=start,
+                          n=n) as sp:
+                toks = jnp.zeros((1, bucket), jnp.int32).at[0, :n].set(
+                    jnp.asarray(req.prompt[start:start + n], jnp.int32))
+                cache = self._slot_cache(s, start)
+                logits, new_cache = self._get_chunk(bucket)(
+                    self.params, toks, jnp.asarray(start, jnp.int32), cache)
+                # carry non-paged leaves (recurrent state) across chunks
+                self._carry_dense(s, new_cache)
+                self._write_pages(s, new_cache, start, start + n)
+                pf["start"] = start + n
+            self.stats.prefill_s += sp.dur_s
+            if pf["start"] >= plen:
+                tok = int(jnp.argmax(logits[0, n - 1]))
+                self._prefilling = None
+                self._finish_admission(s, req, tok, plen)
+                return
+            if any(r is not None for r in self.active):
+                return
 
     # -- decode programs -------------------------------------------------------
     # the jitted programs take these names (the trace's "XLA Modules" line
     # shows jit_serve_decode, jit_serve_decode_drill)
-    def serve_decode(self, params, tokens, pos, cache):
-        return self._decode_core(params, tokens, pos, cache, None)
+    def serve_decode(self, params, tokens, pos, state):
+        return self._decode_body(params, tokens, pos, state, None)
 
-    def serve_decode_drill(self, params, tokens, pos, cache, shard, delta):
-        return self._decode_core(params, tokens, pos, cache, (shard, delta))
+    def serve_decode_drill(self, params, tokens, pos, state, shard, delta):
+        return self._decode_body(params, tokens, pos, state, (shard, delta))
 
-    def _decode_core(self, params, tokens, pos, cache, inject):
+    def _decode_body(self, params, tokens, pos, state, inject):
+        """The decode over the pools in place: returns the logits, the
+        paged leaves' new rows ``[repeats, slots, 1, *tail]`` and the
+        other leaves' new state, keyed by path, and the reduction's
+        verdict."""
+        pools, dense = state["pools"], state["dense"]
+
+        def block(path, c):
+            keys = {n: jax.tree_util.keystr(path + (
+                jax.tree_util.DictKey(n),)) for n in c}
+            if not any(k in pools for k in keys.values()):
+                return {n: dense[k] for n, k in keys.items()}
+            return {**{n: pools[k] for n, k in keys.items() if k in pools},
+                    "table": state["table"]}
+
+        cache = jax.tree_util.tree_map_with_path(
+            block, self._cache_struct, is_leaf=_is_block)
         if not self._protected:
             logits, new_cache = tf.decode_step(params, tokens, pos, cache,
                                                self.cfg, abft=self.abft)
-            return (logits, new_cache, jnp.asarray(True),
+            return (logits, _by_path(new_cache), jnp.asarray(True),
                     dict(self._info_struct))
         hidden, new_cache = tf.decode_step(params, tokens, pos, cache,
                                            self.cfg, abft=self.abft,
                                            return_hidden=True)
         logits, ok, info = self._verified_unembed(params, hidden, inject)
-        return logits, new_cache, ok, info
+        return logits, _by_path(new_cache), ok, info
 
     def _verified_unembed(self, params, x, inject):
         """Row-parallel final projection with the cross-shard reduction
@@ -765,10 +859,17 @@ class ServeEngine:
             self._scrub_check()
         n_active = sum(r is not None for r in self.active)
         with obs.span("serve/kv_gather", active=n_active):
-            self._pre_decode()
+            # the table changes at admission, retirement and a page
+            # allocated by a write: place it only then
+            if not np.array_equal(self._table_host, self.kv.table):
+                self._table_host = self.kv.table.copy()
+                self._table = jnp.asarray(self._table_host)
         ev: Optional[SDCEvent] = None
+        # attrs: table entries naming a live page, and entries the decode
+        # reads
         with obs.span("serve/decode_step", active=n_active,
-                      **self._decode_attrs()) as sp:
+                      pages_live=int(np.count_nonzero(self.kv.table)),
+                      pages_read=int(self.kv.table.size)) as sp:
             if self.sdc is not None and not self._warming:
                 fired = self.sdc.check(self.stats.decode_steps)
                 if fired is not None:
@@ -829,7 +930,7 @@ class ServeEngine:
             # program, so even the first detection's wall is compile-free
             obs.recovery("abft_inflight", rec, step=step, warm_s=rec,
                          compile_s=0.0, corrected=bool(info["corrected"]))
-        pages = self._writeback_pages(n_active)
+        pages = n_active * len(self.kv.pools)
         with obs.span("serve/kv_writeback", pages=pages,
                       host_reads=min(pages, 1)):
             self._post_decode(new_state)
@@ -864,163 +965,15 @@ class ServeEngine:
                 obs.counter("repro_requests_total",
                             "retired serve requests").inc()
                 finished.append(req)
-                self._retire_slot(s)
+                self.kv.free_slot(s)
                 self.active[s] = None
 
-
-class PagedServeEngine(ServeEngine):
-    """`ServeEngine` on a paged/block KV cache (serve.paged_kv) with prefix
-    caching, chunked prefill, and an optional SLO-aware scheduler.
-
-    The page pools are the ONLY storage of the K/V leaves: the compiled
-    decode reads them in place, each layer its own pages through the page
-    table (`models.attention.paged_view`), and returns just the new K/V
-    rows, which `_post_decode` writes into their pages — re-arming exactly
-    the pages it touched instead of the whole cache (the scrub unit is
-    the page).  ``self.cache`` holds only the leaves that are not paged
-    (recurrent state), keyed by path.  A retiring slot frees its pages
-    (zero-at-free), and the at-rest scrub verifies/repairs at page
-    granularity via the pool's erasure sum.
-
-    Decode parity: with ``chunk_prefill=0`` and no prefix hit, admission
-    runs the parent's compiled prefill program verbatim, and the decode's
-    per-layer view of the pages differs from the contiguous engine's cache
-    only at causally masked positions (zeros vs prefill pad garbage) —
-    decode logits, and therefore the emitted token streams, are
-    bit-identical (tests/test_traffic.py).  Chunked and prefix-shared
-    prefills change the prefill computation's shape, so their guarantee is
-    on the argmax token stream, not logits bits.
-
-    ``scheduler``: an `SLOScheduler` (serve.scheduler) takes over queueing
-    — `submit()` routes through its admission control (rejections land in
-    ``self.rejected``) and `_admit` pops by aged effective priority.
-    ``chunk_prefill=C``: prompts longer than C prefill C tokens per engine
-    step, so a long prompt never delays a running decode step by more than
-    one chunk's work (tests/test_scheduler.py); when no decode is active
-    the chunks free-run back-to-back.
-    """
-
-    def __init__(self, cfg: ModelConfig, params, *, page_size: int = 8,
-                 chunk_prefill: int = 0, prefix_cache: bool = True,
-                 scheduler=None, max_prefixes: int = 16, **kw):
-        from repro.serve.paged_kv import PagedKVCache  # noqa: F401 (type)
-        self.page_size = page_size
-        self.chunk_prefill = chunk_prefill
-        self.prefix_cache = prefix_cache
-        self.max_prefixes = max_prefixes
-        self.scheduler = scheduler
-        self.kv = None                    # built by _fresh_cache
-        self.rejected: List[Request] = []
-        self._prefilling: Optional[dict] = None
-        self._chunk_progs = {}
-        super().__init__(cfg, params, **kw)
-
-    # -- paged storage ---------------------------------------------------------
-    def _fresh_cache(self):
-        """Build the page pools of the sequence-indexed K/V leaves
-        (``[repeats, slots, max_len, *tail]`` in the contiguous layout) and
-        return the leaves of the blocks that have none (recurrent state:
-        mamba, xLSTM), dense and keyed by path."""
-        from repro.serve.paged_kv import PagedKVCache
-        struct = jax.eval_shape(
-            lambda: tf.init_cache(self.cfg, self.slots, self.max_len))
-        self._cache_struct = struct
-        flat = jax.tree_util.tree_flatten_with_path(struct)[0]
-        shapes = {jax.tree_util.keystr(path): (x.shape, x.dtype)
-                  for path, x in flat
-                  if (jnp.issubdtype(x.dtype, jnp.floating) and x.ndim >= 4
-                      and x.shape[1] == self.slots
-                      and x.shape[2] == self.max_len)}
-        self.kv = None                    # the old pools go first
-        self.kv = PagedKVCache(shapes, slots=self.slots,
-                               max_len=self.max_len,
-                               page_size=self.page_size,
-                               max_prefixes=self.max_prefixes)
-        self._table_host = self.kv.table.copy()
-        self._table = jnp.asarray(self._table_host)
-        # a block with a paged leaf decodes from pages alone (its index
-        # leaf is the slot's position, which the decode is given)
-        paged = {path[:-1] for path, _ in flat
-                 if jax.tree_util.keystr(path) in shapes}
-        keep = {jax.tree_util.keystr(path) for path, _ in flat
-                if path[:-1] not in paged}
-        if not keep:
-            return {}
-        # jitted, so that the paged leaves are never built
-        dense = jax.jit(lambda: {k: v for k, v in _by_path(
-            tf.init_cache(self.cfg, self.slots, self.max_len)).items()
-            if k in keep})()
-        if self._cache_sh is not None:
-            dense = jax.device_put(dense, {k: v for k, v in _by_path(
-                self._cache_sh).items() if k in keep})
-        return dense
-
-    def _arm_kv(self):
-        self.kv.arm_all()
-
-    def _scrub_kv(self, step: int, events: List[ScrubEvent]):
-        for key, page in self.kv.scrub():
-            events.append(ScrubEvent(step=step, domain="kv", leaf=key,
-                                     page=page, repaired=True))
-
-    def _pre_decode(self):
-        # the table changes at admission, retirement and a page allocated
-        # by a write: place it only then
-        if not np.array_equal(self._table_host, self.kv.table):
-            self._table_host = self.kv.table.copy()
-            self._table = jnp.asarray(self._table_host)
-
-    def _decode_state(self):
-        return {"pools": dict(self.kv.pools), "table": self._table,
-                "dense": self.cache}
-
-    def _decode_shardings(self):
-        # pools and table replicated: every device reads any slot's pages
-        dense = {k: v for k, v in _by_path(self._cache_sh).items()
-                 if k in self.cache}
-        state = {"pools": {k: self._rep for k in self.kv.pools},
-                 "table": self._rep, "dense": dense}
-        return state, {**{k: self._rep for k in self.kv.pools}, **dense}
-
-    def _decode_attrs(self) -> Dict[str, int]:
-        # table entries naming a live page, and entries the decode reads
-        return {"pages_live": int(np.count_nonzero(self.kv.table)),
-                "pages_read": int(self.kv.table.size)}
-
-    def serve_decode(self, params, tokens, pos, state):
-        return self._paged_decode(params, tokens, pos, state, None)
-
-    def serve_decode_drill(self, params, tokens, pos, state, shard, delta):
-        return self._paged_decode(params, tokens, pos, state, (shard, delta))
-
-    def _paged_decode(self, params, tokens, pos, state, inject):
-        """The decode over the pools in place: returns the logits, the
-        paged leaves' new rows ``[repeats, slots, 1, *tail]`` and the
-        other leaves' new state, keyed by path, and the reduction's
-        verdict."""
-        pools, dense = state["pools"], state["dense"]
-
-        def block(path, c):
-            keys = {n: jax.tree_util.keystr(path + (
-                jax.tree_util.DictKey(n),)) for n in c}
-            if not any(k in pools for k in keys.values()):
-                return {n: dense[k] for n, k in keys.items()}
-            return {**{n: pools[k] for n, k in keys.items() if k in pools},
-                    "table": state["table"]}
-
-        cache = jax.tree_util.tree_map_with_path(
-            block, self._cache_struct, is_leaf=_is_block)
-        logits, new_cache, ok, info = self._decode_core(
-            params, tokens, pos, cache, inject)
-        return logits, _by_path(new_cache), ok, info
-
-    def _paged_leaves(self, tree) -> Dict[str, jax.Array]:
-        return {k: x for k, x in _by_path(tree).items() if k in self.kv.pools}
-
     def _post_decode(self, new_state):
-        # page-granular write-back + re-arm: ONE page per leaf per active
-        # slot (the decode's row for slot s lands at pos[s] = prompt +
-        # output - 1), all slots and leaves in one batch padded to `slots`
+        """Keep the state the decode returned: the leaves that are not
+        paged, and the new K/V rows written into their pages — ONE page per
+        leaf per active slot (the decode's row for slot s lands at pos[s] =
+        prompt + output - 1), all slots and leaves in one batch padded to
+        `slots`, re-arming the pages written."""
         self.cache = {k: new_state[k] for k in self.cache}
         writes = [(s, p, 1, s, 0) for s, p in
                   ((s, len(r.prompt) + len(r.output) - 1)
@@ -1030,152 +983,3 @@ class PagedServeEngine(ServeEngine):
         self.kv.begin_mutation()
         self.kv.write_batch({k: new_state[k] for k in self.kv.pools},
                             writes, kind="decode", pad_to=self.slots)
-
-    def _writeback_pages(self, n_active: int) -> int:
-        return n_active * len(self.kv.pools)
-
-    def _retire_slot(self, s: int):
-        self.kv.free_slot(s)
-
-    # -- queueing / admission --------------------------------------------------
-    def submit(self, req: Request, priority: Optional[int] = None):
-        if not req.t_submit:
-            req.t_submit = time.perf_counter()
-        if self.scheduler is not None and not self._warming:
-            if not self.scheduler.submit(req, priority=priority):
-                req.done = True
-                self.rejected.append(req)
-            return
-        self.queue.append(req)
-
-    def reset(self):
-        super().reset()          # rebuilds self.kv via _fresh_cache
-        self.rejected = []
-        self._prefilling = None
-
-    def _pending(self) -> bool:
-        return (bool(self.queue) or self._prefilling is not None
-                or (self.scheduler is not None and len(self.scheduler) > 0))
-
-    def _next_request(self) -> Optional[Request]:
-        if self.scheduler is not None:
-            req = self.scheduler.pop()
-            if req is not None:
-                return req
-        return self.queue.popleft() if self.queue else None
-
-    def _admit(self):
-        if self._prefilling is not None:
-            self._advance_prefill()
-            if self._prefilling is not None:
-                return          # one chunk per engine step under decode load
-        while True:
-            free = [s for s in range(self.slots) if self.active[s] is None]
-            if not free:
-                return
-            req = self._next_request()
-            if req is None:
-                return
-            self._start_admission(free[0], req)
-            if self._prefilling is not None:
-                self._advance_prefill()   # free-runs when no decode active
-                if self._prefilling is not None:
-                    return
-
-    def _start_admission(self, s: int, req: Request):
-        plen = len(req.prompt)
-        need = min(plen + req.max_new_tokens, self.max_len)
-        shared = (self.kv.lookup_prefix(req.prompt)
-                  if self.prefix_cache and not self._warming else [])
-        with self._admission(req, prefix_tokens=len(shared) * self.page_size):
-            start = self.kv.alloc_slot(s, need, shared=shared)
-            if start or (self.chunk_prefill and plen > self.chunk_prefill):
-                self._prefilling = {"slot": s, "req": req, "start": start}
-                return
-            # no prefix hit, no chunking: the parent's compiled prefill
-            # program verbatim — bit-identical admission vs the contiguous
-            # engine
-            tok = self._prefill_whole(s, req)
-            self._finish_admission(s, req, tok, plen)
-
-    def _finish_admission(self, s: int, req: Request, tok: int, plen: int):
-        super()._finish_admission(s, req, tok, plen)
-        if self.prefix_cache and not self._warming:
-            self.kv.register_prefix(s, req.prompt)
-
-    def _write_pages(self, s: int, cache_tree, start: int, end: int):
-        """Persist positions [start, end) of a 1-slot cache into pages."""
-        if end <= start:
-            return
-        ps = self.page_size
-        pages = len(self.kv.pools) * (-(-end // ps) - start // ps)
-        with obs.span("serve/kv_write", pages=pages, host_reads=1):
-            self.kv.begin_mutation()
-            # padded to the prefill's own bucket: warmed with its program
-            self.kv.write_batch(self._paged_leaves(cache_tree),
-                                [(s, start, end - start, 0, start)],
-                                kind="prefill",
-                                pad_to=self._bucket(end - start))
-
-    # -- chunked / prefix-shared prefill --------------------------------------
-    def _slot_cache(self, s: int, start: int):
-        """Dense 1-slot cache for the chunk program: this slot's pages
-        gathered, non-paged leaves sliced from the engine cache, block
-        indices set to the chunk's start position."""
-        def leaf(path, x):
-            key = jax.tree_util.keystr(path)
-            if key in self.kv.pools:
-                return self.kv.gather_slot(key, s)
-            if getattr(path[-1], "key", None) == "index":
-                return jnp.full((x.shape[0],), start, jnp.int32)
-            return self.cache[key][:, s:s + 1]
-        return jax.tree_util.tree_map_with_path(leaf, self._cache_struct)
-
-    def _scatter_slot(self, s: int, small_cache, plen: int):
-        # the paged leaves went to pages (`_write_pages`); the others carry
-        # into slot s
-        small = _by_path(small_cache)
-        self.cache = {k: x.at[:, s].set(small[k][:, 0].astype(x.dtype))
-                      for k, x in self.cache.items()}
-
-    def _get_chunk(self, bucket: int):
-        if bucket not in self._chunk_progs:
-            def serve_prefill_chunk(pr, tok, start, cache, _b=bucket):
-                positions = start + jnp.arange(_b)
-                logits, new_cache, _ = tf.forward(
-                    pr, tok, self.cfg, positions=positions, cache=cache,
-                    abft=self.abft)
-                return logits, new_cache
-            self._chunk_progs[bucket] = jax.jit(serve_prefill_chunk)
-        return self._chunk_progs[bucket]
-
-    def _advance_prefill(self):
-        """Process chunks of the in-flight prefill: one chunk when any
-        decode is running (the chunk budget is the most a decode step can
-        be delayed), back-to-back when the engine is otherwise idle."""
-        while self._prefilling is not None:
-            pf = self._prefilling
-            s, req, start = pf["slot"], pf["req"], pf["start"]
-            plen = len(req.prompt)
-            n = min(self.chunk_prefill or plen - start, plen - start)
-            bucket = self._bucket(n)
-            with obs.span("serve/prefill_chunk", rid=req.rid, start=start,
-                          n=n) as sp:
-                toks = jnp.zeros((1, bucket), jnp.int32).at[0, :n].set(
-                    jnp.asarray(req.prompt[start:start + n], jnp.int32))
-                cache = self._slot_cache(s, start)
-                logits, new_cache = self._get_chunk(bucket)(
-                    self.params, toks, jnp.asarray(start, jnp.int32), cache)
-                # carry non-paged leaves (recurrent state) + index across
-                # chunks
-                self._scatter_slot(s, new_cache, start + n)
-                self._write_pages(s, new_cache, start, start + n)
-                pf["start"] = start + n
-            self.stats.prefill_s += sp.dur_s
-            if pf["start"] >= plen:
-                tok = int(jnp.argmax(logits[0, n - 1]))
-                self._prefilling = None
-                self._finish_admission(s, req, tok, plen)
-                return
-            if any(r is not None for r in self.active):
-                return

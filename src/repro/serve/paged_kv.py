@@ -1,6 +1,6 @@
 """Paged (block) KV cache: fixed-size pages as the unified protection unit.
 
-vLLM-style memory layout for the serving engines: every float KV-cache
+vLLM-style memory layout for the serving engine: every float KV-cache
 leaf gets a page pool ``[n_pages, repeats, page_size, features]`` (the
 leaf's tail flattened into features) and all
 leaves share ONE host-side page table ``[slots, pages_per_slot]`` mapping
@@ -10,8 +10,7 @@ so a page read through the table is exactly zero beyond every slot's
 write head (which the causal mask discards; see the decode-parity note
 below).
 
-The page is also the repo's ABFT unit for serving memory, replacing the
-per-slot fingerprints from PR 6 whose scrub unit was the whole slot:
+The page is also the repo's ABFT unit for serving memory:
 
   * **checksum-on-write, page granular** — every mutation re-arms exactly
     the pages it touched: a per-(leaf, page) float64 scalar fingerprint
@@ -64,14 +63,15 @@ Decode reads the pools in place: the compiled decode
 (`serve.engine.PagedServeEngine`) reads each layer's pages through the
 page table (`models.attention.paged_view`), sets the fresh token in that
 per-layer view only and returns the new K/V rows, which `write_batch`
-puts into the pools.  Decode parity: that view differs from the
-contiguous engine's cache only at causally-masked positions (zeros here,
-prefill pad garbage there); `_sdpa_dense` masks with ``where(mask, s,
-NEG_INF)`` before the softmax, so those positions carry exactly zero
-weight either way and the paged engine's decode logits are bit-identical
-(tests/test_traffic.py golden-parity, clean and drilled).  `gather()`
-builds the same view for every slot and layer at once: the tests' oracle
-and the chunked prefill's one-slot cache.
+puts into the pools.  Decode parity: that view differs from a dense
+contiguous cache only at causally-masked positions (zeros here, prefill
+pad garbage there); `_sdpa_dense` masks with ``where(mask, s, NEG_INF)``
+before the softmax, so those positions carry exactly zero weight either
+way and the decode logits equal the dense decode's bit for bit
+(tests/test_paged_decode.py; token streams: tests/test_traffic.py,
+clean and drilled).  `gather()` builds the same view for every slot and
+layer at once: the tests' oracle and the chunked prefill's one-slot
+cache.
 """
 from __future__ import annotations
 
@@ -99,7 +99,8 @@ register_surface(
              "float64 page sum (erasure solve over the live pages)",
     kinds=("dram_kv_cache",),
     note="the page is the unified scrub + DRAM-recovery + erasure-repair "
-         "unit for serving memory (PagedServeEngine); single-page fault "
+         "unit for serving memory (PagedServeEngine(scrub_every=N) sets "
+         "the verify cadence); single-page fault "
          "model per leaf, like f=1 diskless.  Checksums re-arm at page "
          "granularity on every write — a single-token decode write "
          "dirties exactly one page checksum per leaf")

@@ -1,4 +1,4 @@
-"""Traffic generation + SLO measurement for the serving engines.
+"""Traffic generation + SLO measurement for the serving engine.
 
 The load half of the SLO-under-fault story (ROADMAP "heavy-traffic
 serving"): build a deterministic request **trace** — Zipf-distributed
@@ -11,7 +11,7 @@ workload — wall-clock arrival jitter can't decorrelate the two runs) —
 then replay it and report p50/p99 TTFT, throughput, and the engine's
 fault accounting.
 
-`run_trace` drives any `ServeEngine`-compatible engine; `compare` turns a
+`run_trace` drives a `PagedServeEngine`; `compare` turns a
 clean + a drilled report into the first-class SLO-under-fault numbers
 (p99 TTFT degradation while SDCs are corrected mid-decode).
 `benchmarks/bench_traffic.py` is the CLI; the chaos campaign's `traffic`
@@ -138,10 +138,7 @@ def run_trace(engine, trace: List[TraceItem], *,
             it = items[i]
             req = Request(rid=it.rid, prompt=list(it.prompt),
                           max_new_tokens=it.max_new)
-            try:
-                eng.submit(req, priority=it.priority)
-            except TypeError:          # plain ServeEngine: no priorities
-                eng.submit(req)
+            eng.submit(req, priority=it.priority)
             i += 1
 
     def hook(eng, step):
@@ -164,10 +161,9 @@ def run_trace(engine, trace: List[TraceItem], *,
         wall = time.perf_counter() - t0
 
     s = engine.stats
-    rejected = list(getattr(engine, "rejected", []))
+    rejected = list(engine.rejected)
     ttfts = [r.ttft_s for r in finished if r.ttft_s is not None]
     total_tokens = sum(len(r.output) for r in finished)
-    kv = getattr(engine, "kv", None)
     return TrafficReport(
         n_requests=n,
         n_finished=len(finished),
@@ -186,7 +182,7 @@ def run_trace(engine, trace: List[TraceItem], *,
         sdc_corrected=sum(1 for e in s.events if e.corrected),
         scrub_checks=s.scrub_checks,
         scrub_repairs=sum(1 for e in s.scrub_events if e.repaired),
-        prefix_hits=kv.stats.prefix_hits if kv is not None else 0,
+        prefix_hits=engine.kv.stats.prefix_hits,
         outputs={r.rid: list(r.output) for r in finished},
     )
 

@@ -38,7 +38,7 @@ def test_registry_has_every_protection_domain():
             "kernels.flash_attention", "models.layers/layernorm",
             "models.layers/embedding_gather", "state.params_at_rest",
             "state.opt_state_at_rest",
-            "serve.engine/kv_cache_at_rest"} <= protected
+            "serve.paged_kv/pages"} <= protected
     for name in protected:
         assert reg[name].detector, name    # a protected domain names its
         #                                    detector or it is a lie
@@ -357,22 +357,22 @@ def test_invariant_checks_reject_deferred_grad_reduce():
 @pytest.mark.slow
 def test_serve_scrubber_repairs_kv_and_params():
     """Serve-side at-rest protection: a KV-cache flip is located to its
-    slot and rebuilt by the erasure solve; a params flip is restored from
-    the origin copy — both with the emitted token stream bit-identical to
-    the clean run."""
+    page and rebuilt by the pool's erasure sum; a params flip is restored
+    from the origin copy — both with the emitted token stream bit-identical
+    to the clean run."""
     res = _runner([
         FaultSpec(kind="dram_kv_cache", workload="serve", step=2, bit=30),
         FaultSpec(kind="dram_params", workload="serve", step=0, bit=30),
     ]).run(workloads=("serve",))
     by = {r.name: r for r in res.results if r.spec is not None}
     kv = by["serve:dram_kv_cache:s2"]
-    assert kv.outcome == "corrected" and kv.rung == "scrub:kv_repair"
+    assert kv.outcome == "corrected" and kv.rung == "scrub:page_repair"
     assert kv.end_state == "bit_identical"
     pp = by["serve:dram_params:s0"]
     assert pp.outcome == "corrected" and pp.rung == "scrub:restore"
     assert pp.end_state == "bit_identical"
     sweeps = [r for r in res.results if r.kind == "clean_sweep"
-              and r.surface == "serve.engine/kv_cache_at_rest"]
+              and r.surface == "serve.paged_kv/pages"]
     assert sweeps and all(s.outcome == "clean" for s in sweeps)
 
 

@@ -11,7 +11,7 @@ Four layers, matching ``src/repro/obs/``:
     trace_event schema subset we emit, the Prometheus text round-trips
     through `parse_prometheus`, the JSONL log round-trips events
     loss-free;
-  * the drilled-serve integration — an SDC drill through `ServeEngine`
+  * the drilled-serve integration — an SDC drill through `PagedServeEngine`
     with the bus on must tell the SAME story on the bus as in
     `EngineStats` (counts, locations, rungs), and `lifecycles` must fold
     the stream into a complete inject -> detect -> rung -> verdict.
@@ -387,13 +387,13 @@ def test_drilled_serve_bus_matches_engine_stats():
     from repro.configs.base import smoke_config
     from repro.ft.failures import SDCInjector, SDCPlan
     from repro.models import transformer as tf
-    from repro.serve.engine import Request, ServeEngine
+    from repro.serve.engine import PagedServeEngine, Request
 
     cfg = smoke_config("qwen2-0.5b")
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
-    eng = ServeEngine(cfg, params, slots=2, max_len=32,
-                      abft_reduce="correct",
-                      sdc=SDCInjector(SDCPlan(((2, 0, 1e4),))))
+    eng = PagedServeEngine(cfg, params, slots=2, max_len=32,
+                           abft_reduce="correct",
+                           sdc=SDCInjector(SDCPlan(((2, 0, 1e4),))))
     obs.reset_all()
     for i in range(2):
         eng.submit(Request(rid=i, prompt=[3 + i, 5, 7], max_new_tokens=4))

@@ -34,8 +34,8 @@ def _engine(setup, **kw):
 
 
 def _dense_view(eng, state, pos):
-    """The contiguous cache the parent's decode read: every paged leaf
-    gathered from the pools through the table, index = positions."""
+    """A dense contiguous cache: every paged leaf gathered from the pools
+    through the table, index = positions."""
     def leaf(path, x):
         key = jax.tree_util.keystr(path)
         if key in eng.kv.pools:
@@ -113,9 +113,12 @@ def test_paged_decode_logits_equal_dense_decode_over_gather(setup, mode):
     else:
         eng = _engine(setup, abft_reduce="correct",
                       sdc=SDCInjector(SDCPlan(((6, 0, 1e4), (17, 0, 1e4)))))
-        # the parent's decode program: the same core over a dense cache
-        core = jax.jit(lambda t, p, c, inj: eng._decode_core(
-            params, t, p, c, inj)[0])
+        # the dense decode with the same verified unembed
+        def dense(t, p, c, inj):
+            hidden, _ = tf.decode_step(params, t, p, c, cfg, abft=eng.abft,
+                                       return_hidden=True)
+            return eng._verified_unembed(params, hidden, inj)[0]
+        core = jax.jit(dense)
         oracle = lambda t, p, c, inject: core(t, p, c, inject)  # noqa
     calls = _record(eng, oracle)
     cows = eng.kv.stats.cow_copies

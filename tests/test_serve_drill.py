@@ -17,7 +17,7 @@ import pytest
 from repro.configs.base import smoke_config
 from repro.ft.failures import SDCInjector, SDCPlan
 from repro.models import transformer as tf
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import PagedServeEngine, Request
 
 DRILL_SCRIPT = r"""
 import os
@@ -27,7 +27,7 @@ from repro.dist.sharding import make_mesh
 from repro.configs.base import smoke_config
 from repro.ft.failures import SDCInjector, SDCPlan
 from repro.models import transformer as tf
-from repro.serve.engine import Request, ServeEngine
+from repro.serve.engine import PagedServeEngine, Request
 
 mesh = make_mesh((4, 2), ("data", "model"))
 cfg = smoke_config("qwen2-0.5b")
@@ -36,8 +36,8 @@ rs = np.random.RandomState(0)
 prompts = [rs.randint(0, cfg.vocab_size, 8).tolist() for _ in range(4)]
 
 def drive(sdc=None):
-    eng = ServeEngine(cfg, params, slots=4, max_len=48, mesh=mesh,
-                      abft_reduce="correct", sdc=sdc)
+    eng = PagedServeEngine(cfg, params, slots=4, max_len=48, mesh=mesh,
+                           abft_reduce="correct", sdc=sdc)
     for i, p in enumerate(prompts):
         eng.submit(Request(rid=i, prompt=p, max_new_tokens=5))
     fin = eng.run()
@@ -82,7 +82,7 @@ def test_clean_protected_engine_reports_zero_detections():
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
 
     def drive(**kw):
-        eng = ServeEngine(cfg, params, slots=2, max_len=48, **kw)
+        eng = PagedServeEngine(cfg, params, slots=2, max_len=48, **kw)
         for i in range(3):
             eng.submit(Request(rid=i, prompt=[5 + i, 6, 7],
                                max_new_tokens=4))
@@ -107,8 +107,8 @@ def test_engine_warm_and_reset_reuse_compiled_programs():
     cfg = smoke_config("qwen2-0.5b")
     params = tf.init_params(jax.random.PRNGKey(1), cfg)
     sdc = SDCInjector(SDCPlan(((1, 0, 1e4),)))
-    eng = ServeEngine(cfg, params, slots=2, max_len=48,
-                      abft_reduce="correct", sdc=sdc)
+    eng = PagedServeEngine(cfg, params, slots=2, max_len=48,
+                           abft_reduce="correct", sdc=sdc)
     eng.warm(prompt_len=8)
     assert eng.stats.decode_steps == 0          # stats reset after warm
     assert not sdc._fired                       # warm-up never fires drills

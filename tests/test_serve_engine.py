@@ -1,30 +1,16 @@
-"""Continuous-batching engine: outputs must equal sequential generation."""
+"""Continuous-batching engine: outputs must equal sequential generation
+(the `greedy_reference` fixture, tests/conftest.py)."""
 import jax
-import jax.numpy as jnp
 import numpy as np
 import pytest
 
 from repro.configs.base import smoke_config
 from repro.models import transformer as tf
-from repro.serve.engine import Request, ServeEngine
-
-
-def _sequential_generate(cfg, params, prompt, n_new):
-    """Reference: single-request greedy decode."""
-    cache = tf.init_cache(cfg, 1, 64)
-    toks = jnp.asarray([prompt], jnp.int32)
-    logits, cache, _ = tf.forward(params, toks, cfg, cache=cache)
-    out = [int(jnp.argmax(logits[0, -1]))]
-    for i in range(n_new - 1):
-        tok = jnp.asarray([[out[-1]]], jnp.int32)
-        logits, cache = tf.decode_step(
-            params, tok, jnp.asarray(len(prompt) + i), cache, cfg)
-        out.append(int(jnp.argmax(logits[0])))
-    return out
+from repro.serve.engine import PagedServeEngine, Request
 
 
 @pytest.mark.slow
-def test_engine_matches_sequential():
+def test_engine_matches_sequential(greedy_reference):
     cfg = smoke_config("qwen2-0.5b")
     params = tf.init_params(jax.random.PRNGKey(0), cfg)
     rs = np.random.RandomState(0)
@@ -32,7 +18,7 @@ def test_engine_matches_sequential():
                for _ in range(6)]
     n_new = 5
 
-    engine = ServeEngine(cfg, params, slots=2, max_len=64)
+    engine = PagedServeEngine(cfg, params, slots=2, max_len=64)
     for i, p in enumerate(prompts):
         engine.submit(Request(rid=i, prompt=p, max_new_tokens=n_new))
     finished = engine.run()
@@ -40,7 +26,7 @@ def test_engine_matches_sequential():
 
     by_rid = {r.rid: r.output for r in finished}
     for i, p in enumerate(prompts):
-        ref = _sequential_generate(cfg, params, p, n_new)
+        ref = greedy_reference(cfg, params, p, n_new)
         assert by_rid[i] == ref, (i, by_rid[i], ref)
 
 
@@ -48,7 +34,7 @@ def test_engine_matches_sequential():
 def test_engine_more_requests_than_slots_and_eos():
     cfg = smoke_config("gemma2-2b")   # local+global attention exercised
     params = tf.init_params(jax.random.PRNGKey(1), cfg)
-    engine = ServeEngine(cfg, params, slots=2, max_len=48)
+    engine = PagedServeEngine(cfg, params, slots=2, max_len=48)
     for i in range(5):
         engine.submit(Request(rid=i, prompt=[1 + i, 2, 3],
                               max_new_tokens=4))
@@ -63,8 +49,8 @@ def test_engine_abft_verify_identical():
     params = tf.init_params(jax.random.PRNGKey(2), cfg)
     outs = {}
     for mode in ("off", "verify"):
-        engine = ServeEngine(cfg, params, slots=2, max_len=48,
-                             abft_mode=mode)
+        engine = PagedServeEngine(cfg, params, slots=2, max_len=48,
+                                  abft_mode=mode)
         for i in range(3):
             engine.submit(Request(rid=i, prompt=[5, 6, 7], max_new_tokens=4))
         outs[mode] = {r.rid: r.output for r in engine.run()}

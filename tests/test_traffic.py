@@ -1,11 +1,12 @@
 """Golden decode-parity for the heavy-traffic serving layer.
 
-The load-bearing guarantee of PR 8: `PagedServeEngine` (paged KV +
-chunked prefill + prefix cache + SLO scheduler) emits token streams
-bit-identical to the contiguous `ServeEngine` on the same trace — both
-CLEAN and DRILLED (mid-decode SDCs corrected by the abft residual,
-page-granular DRAM corruption erasure-repaired by the per-page
-checksums).  Plus trace determinism and `compare()` accounting.
+The load-bearing guarantee: `PagedServeEngine` (paged KV + chunked
+prefill + prefix cache + SLO scheduler) emits, for every request of a
+trace, the token stream of single-request greedy decoding over a dense
+contiguous cache (the `greedy_reference` fixture) — both CLEAN and
+DRILLED (mid-decode SDCs corrected by the abft residual, page-granular
+DRAM corruption erasure-repaired by the per-page checksums).  Plus trace
+determinism and `compare()` accounting.
 
 Fault schedules index EXECUTED decode steps recorded from the clean
 paged replay (run_trace fast-forwards the decode-step clock over idle
@@ -48,14 +49,12 @@ def paged(setup, sdc=None, **kw):
     return e
 
 
-def contiguous(setup):
-    from repro.serve.engine import ServeEngine
-
+def contiguous(setup, greedy_reference, trace):
+    """Each request's greedy token stream over a dense contiguous cache,
+    keyed by rid."""
     cfg, params = setup
-    e = ServeEngine(cfg, params, slots=3, max_len=64)
-    e.warm(prompt_len=8, decode_steps=2)
-    e.reset()
-    return e
+    return {it.rid: greedy_reference(cfg, params, list(it.prompt),
+                                     it.max_new) for it in trace}
 
 
 def trace_cfg(**kw):
@@ -119,17 +118,17 @@ def test_compare_accounting():
 
 
 # ---------------------------------------------------------------------------
-# golden parity: paged == contiguous, clean and drilled
+# golden parity: paged == greedy over a contiguous cache, clean and drilled
 # ---------------------------------------------------------------------------
 
 
-def test_paged_matches_contiguous_clean(setup):
+def test_paged_matches_contiguous_clean(setup, greedy_reference):
     tr = make_trace(trace_cfg())
-    ref = run_trace(contiguous(setup), tr)
+    ref = contiguous(setup, greedy_reference, tr)
     got = run_trace(paged(setup), tr)
-    assert got.n_finished == ref.n_finished == len(tr)
-    assert got.outputs == ref.outputs, \
-        "paged engine must be bit-identical to contiguous decode"
+    assert got.n_finished == len(tr)
+    assert got.outputs == ref, \
+        "paged engine must be bit-identical to contiguous greedy decode"
     assert got.prefix_hits > 0, "shared 2-page prefix should hit the cache"
 
 
@@ -140,19 +139,20 @@ def test_unchunked_paged_matches_chunked(setup):
     assert a.outputs == b.outputs
 
 
-def test_paged_matches_contiguous_drilled(setup):
+def test_paged_matches_contiguous_drilled(setup, greedy_reference):
     """The same golden trace under live faults: two mid-decode SDCs on the
     logits reduction and two page-granular DRAM flips, all corrected
-    in-flight — the token streams still match the contiguous engine."""
+    in-flight — the token streams still match greedy decoding over a
+    contiguous cache."""
     from repro.ft.failures import SDCInjector, SDCPlan
 
     tr = make_trace(trace_cfg(seed=7))
-    ref = run_trace(contiguous(setup), tr)
+    ref = contiguous(setup, greedy_reference, tr)
 
     seen = []
     clean = run_trace(paged(setup), tr,
                       on_step=lambda e, s: seen.append(s))
-    assert clean.outputs == ref.outputs
+    assert clean.outputs == ref
     sdc_steps = (seen[len(seen) // 3], seen[len(seen) // 2])
     dram_steps = {seen[2 * len(seen) // 3], seen[(5 * len(seen)) // 6]}
 
@@ -169,8 +169,8 @@ def test_paged_matches_contiguous_drilled(setup):
 
     fault = run_trace(eng, tr, on_step=drill)
     assert len(fired) == len(dram_steps), "dram faults did not fire"
-    assert fault.outputs == ref.outputs, \
-        "drilled paged engine must still match contiguous bit-for-bit"
+    assert fault.outputs == ref, \
+        "drilled paged engine must still match contiguous greedy decode"
     assert fault.sdc_events == len(sdc_steps) == fault.sdc_corrected
     assert fault.scrub_repairs >= len(dram_steps)
     d = compare(clean, fault,
